@@ -60,14 +60,14 @@ def test_sequence_shards_see_full_context(engine):
 
     from jax.sharding import PartitionSpec as P
 
-    from vantage6_tpu.core.mesh import STATION_AXIS, shard_map
+    from vantage6_tpu.core.mesh import STATION_AXIS
 
     def logits_fn(params, toks):
         def body(params, tokens_block):
             out = FT.forward_local(params, tokens_block[0], cfg)
             return out[None]
 
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=engine.mesh,
             in_specs=(P(), P(STATION_AXIS, None, FT.SEQ_AXIS)),
@@ -101,18 +101,18 @@ class TestFlashAndMixedPrecision:
 
         from jax.sharding import PartitionSpec as P
 
-        from vantage6_tpu.core.mesh import _NO_VMA_KW, STATION_AXIS, shard_map
+        from vantage6_tpu.core.mesh import STATION_AXIS
 
         def logits_fn(cfg, toks):
             def body(params, tokens_block):
                 return FT.forward_local(params, tokens_block[0], cfg)[None]
 
-            return shard_map(
+            return jax.shard_map(
                 body,
                 mesh=eng.mesh,
                 in_specs=(P(), P(STATION_AXIS, None, FT.SEQ_AXIS)),
                 out_specs=P(STATION_AXIS, None, FT.SEQ_AXIS),
-                **_NO_VMA_KW,
+                check_vma=False,
             )(params, eng.shard_tokens(jnp.asarray(toks)))
 
         ring = np.asarray(logits_fn(cfg_ring, tokens))

@@ -70,27 +70,19 @@ class TestHostMode:
         frames = _frames("binomial")
         out = _fit_federated("binomial", frames)
         assert out["converged"]
-        # independent fit: the logistic-regression workload's federated GD
-        from vantage6_tpu.workloads import logistic_regression as LR
-
-        fed = federation_from_datasets(frames, {"v6-logreg": LR})
-        task = fed.create_task(
-            "v6-logreg",
-            {
-                "method": "central_logistic",
-                "kwargs": {
-                    "feature_cols": ["x0", "x1"], "label_col": "y",
-                    "n_iter": 4000, "lr": 2.0,
-                },
-            },
-            organizations=[0],
+        # independent fit: Newton-Raphson on the POOLED log-likelihood, in
+        # plain numpy (the exact MLE; federated IRLS must land on it)
+        pooled = pd.concat(frames)
+        X = np.column_stack(
+            [np.ones(len(pooled)), pooled[["x0", "x1"]].to_numpy()]
         )
-        lr_out = fed.wait_for_results(task.id)[0]
-        w = np.asarray(lr_out["w"]).ravel()
-        b = float(np.asarray(lr_out["b"]).ravel()[0])
-        np.testing.assert_allclose(
-            out["coefficients"], [b, *w], atol=5e-3
-        )
+        y = pooled["y"].to_numpy()
+        beta = np.zeros(3)
+        for _ in range(25):
+            mu = 1.0 / (1.0 + np.exp(-X @ beta))
+            hess = X.T @ (X * (mu * (1.0 - mu))[:, None])
+            beta = beta + np.linalg.solve(hess, X.T @ (y - mu))
+        np.testing.assert_allclose(out["coefficients"], beta, atol=1e-6)
 
     def test_poisson_score_equation_holds(self):
         frames = _frames("poisson")

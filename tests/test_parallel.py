@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from vantage6_tpu.core.mesh import shard_map
 from vantage6_tpu.parallel import (
     reference_attention,
     ring_attention,
@@ -48,7 +47,7 @@ class TestRingAttention:
 
         @jax.jit
         def loss(q, k, v):
-            out = shard_map(
+            out = jax.shard_map(
                 lambda q, k, v: ring_attention(q, k, v, "seq", causal=True),
                 mesh=mesh8, in_specs=(spec, spec, spec), out_specs=spec,
             )(q, k, v)
@@ -84,7 +83,7 @@ class TestTensorParallel:
         def body(x, w_up_l, w_down_l):
             return tp_mlp(x, w_up_l, w_down_l, "seq")
 
-        out = shard_map(
+        out = jax.shard_map(
             body,
             mesh=mesh8,
             in_specs=(P(), P(None, "seq"), P("seq", None)),

@@ -504,7 +504,7 @@ class TestTracerHygiene:
         result = run_fixture(tmp_path, {"m.py": """
             import time
 
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
 
             def body(x):
                 time.sleep(0.1)

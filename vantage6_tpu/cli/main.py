@@ -90,17 +90,39 @@ def _alive(pid: int) -> bool:
         return True  # EPERM: exists, owned by another user
 
 
+def _owns_chip(ctx) -> bool:
+    """Whether the instance is configured to use the accelerator: a node
+    that is a device-engine mesh member, or one that hands the chip to its
+    sandboxed algorithms (policies: {accelerator: true}). Servers, stores
+    and every other node are host-plane processes."""
+    if ctx.kind != "node":
+        return False
+    cfg = ctx.config
+    return cfg.get("device_engine") is not None or bool(
+        (cfg.get("policies") or {}).get("accelerator")
+    )
+
+
 def _start_detached(ctx, runner_arg: str) -> int:
     pidfile = _pid_file(ctx)
     if pidfile.exists() and _alive(_read_pid(pidfile)):
         raise click.ClickException(f"{ctx.kind} {ctx.name!r} already running")
     logfile = ctx.log_dir / "stdout.log"
+    # a chip belongs to ONE process at a time: every instance inherits this
+    # environment, so the first one to touch jax would take the chip and
+    # the rest fail or hang at start-up. Only an instance configured to own
+    # the chip keeps the environment's platform; all others are pinned to
+    # the CPU (docs/OPERATOR_GUIDE.md "One process for each chip").
+    env = dict(os.environ)
+    if not _owns_chip(ctx):
+        env["JAX_PLATFORMS"] = "cpu"
     with open(logfile, "ab") as out:
         proc = subprocess.Popen(
             [sys.executable, "-m", "vantage6_tpu.cli.main", runner_arg, ctx.name],
             stdout=out,
             stderr=subprocess.STDOUT,
             start_new_session=True,
+            env=env,
         )
     pidfile.write_text(str(proc.pid))
     return proc.pid
@@ -265,8 +287,10 @@ def _run_node_cmd(name: str) -> None:
 
 
 def _run_node(name: str) -> None:
+    from vantage6_tpu.core.compile_cache import enable_compile_cache
     from vantage6_tpu.node.daemon import NodeDaemon
 
+    enable_compile_cache()
     ctx = NodeContext(name)
     daemon = NodeDaemon.from_context(ctx)
     daemon.start(background=False)
@@ -830,9 +854,11 @@ def run_cmd(config: str, image: str, method: str, kwargs_json: str,
     path — no server/nodes; stations are mesh shards)."""
     import importlib
 
+    from vantage6_tpu.core.compile_cache import enable_compile_cache
     from vantage6_tpu.core.config import FederationConfig
     from vantage6_tpu.runtime.federation import Federation
 
+    enable_compile_cache()
     mod_path = module or BUILTIN_ALGORITHMS.get(image)
     if not mod_path:
         raise click.ClickException(

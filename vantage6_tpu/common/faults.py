@@ -15,9 +15,6 @@ tests/bench), probed from a handful of fixed injection points:
   burst of requests with an injected 5xx before touching the wire.
 - ``poison_labels``     — label-flip poisoning for a station's targets
   (anomalous_station food group); callers opt in at data-prep time.
-- ``wedge_seconds``     — `bench._run_worker`: wedge a named bench
-  operation (e.g. the TPU probe) so the per-leg budget/checkpoint
-  machinery can be exercised without real broken hardware.
 
 Spec grammar — semicolon-separated rules, ``kind:key=value,...``::
 
@@ -33,9 +30,6 @@ crash      prob, limit (default 1), after
 rest500    status (default 500), endpoint (substring filter), count
            (alias for limit, default 3), prob, after
 flip       station (int or ``*``), fraction (default 1.0)
-wedge      op (substring filter on the operation name, e.g. ``probe``),
-           seconds (float, required — how long the op hangs), prob,
-           limit (default 1), after
 =========  ==============================================================
 
 ``prob`` gates each opportunity through the rule's own ``random.Random``
@@ -62,7 +56,7 @@ log = logging.getLogger(__name__)
 
 ENV_VAR = "V6T_FAULTS"
 
-_KINDS = ("delay", "drop", "crash", "rest500", "flip", "wedge")
+_KINDS = ("delay", "drop", "crash", "rest500", "flip")
 
 # per-kind key coercions; unknown keys are a parse error
 _KEY_TYPES: dict[str, Any] = {
@@ -71,7 +65,6 @@ _KEY_TYPES: dict[str, Any] = {
     "status": int,
     "endpoint": str,
     "fraction": float,
-    "op": str,  # wedge: substring filter on the operation name
     "prob": float,
     "limit": int,
     "count": int,  # rest500 alias for limit
@@ -89,7 +82,6 @@ class FaultRule:
     seconds: float = 0.0
     status: int = 500
     endpoint: str = ""
-    op: str = ""
     fraction: float = 1.0
     prob: float = 1.0
     limit: int | None = None
@@ -113,7 +105,6 @@ class FaultRule:
 
     def fires(
         self, *, station: int | None = None, endpoint: str = "",
-        op: str = "",
     ) -> bool:
         """One opportunity: match filters, then after/limit/prob gates.
         Counters advance only on matched opportunities so `after` means
@@ -121,8 +112,6 @@ class FaultRule:
         if not self.matches_station(station):
             return False
         if self.endpoint and self.endpoint not in endpoint:
-            return False
-        if self.op and self.op not in op:
             return False
         self.seen += 1
         if self.seen <= self.after:
@@ -161,10 +150,6 @@ def _parse_rule(chunk: str, plan_seed: int) -> FaultRule:
         kw["limit"] = 3  # a *burst*, not a permanent outage
     if kind == "crash" and "limit" not in kw:
         kw["limit"] = 1  # crash once by default
-    if kind == "wedge":
-        if kw.get("seconds", 0.0) <= 0.0:
-            raise ValueError(f"wedge rule needs seconds>0: {chunk!r}")
-        kw.setdefault("limit", 1)  # wedge once by default
     return FaultRule(**kw)
 
 
@@ -204,13 +189,6 @@ class FaultPlan:
     def rest_status(self, endpoint: str) -> int | None:
         rule = self._fire("rest500", endpoint=endpoint)
         return rule.status if rule else None
-
-    def wedge_seconds(self, op: str) -> float:
-        """Seconds the named bench operation should hang (0.0 = no
-        wedge). `op` is matched as a substring against the rule's
-        ``op`` filter — an empty filter wedges every probed op."""
-        rule = self._fire("wedge", op=op)
-        return rule.seconds if rule else 0.0
 
     def flip_fraction(self, station: int | None) -> float:
         with self._lock:
@@ -280,15 +258,6 @@ class FaultInjector:
         if not self.active:
             return None
         return self._plan.rest_status(endpoint)
-
-    def wedge_seconds(self, op: str) -> float:
-        """How long the named bench operation should hang (0.0 = run
-        normally). The CALLER sleeps — usually inside the wedged worker
-        subprocess — so the parent's per-leg timeout machinery sees a
-        realistic hang, not an instant failure."""
-        if not self.active:
-            return 0.0
-        return self._plan.wedge_seconds(op)
 
     def poison_labels(self, y: Any, station: int | None) -> Any:
         """Sign-flip a deterministic `fraction` of labels when a ``flip``

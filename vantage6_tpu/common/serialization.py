@@ -255,9 +255,7 @@ def _encode_v1(obj: Any) -> Any:
             "dtype": obj.dtype.str,
             "data": base64.b64encode(obj.tobytes()).decode("ascii"),
         }
-    if isinstance(obj, np.ndarray) or (
-        hasattr(jax, "Array") and isinstance(obj, jax.Array)
-    ):
+    if isinstance(obj, (np.ndarray, jax.Array)):
         arr = np.asarray(obj)
         buf = io.BytesIO()
         np.save(buf, arr, allow_pickle=False)
@@ -346,9 +344,7 @@ def _encode_v2(obj: Any, buffers: list[Any]) -> Any:
             "dtype": obj.dtype.str,
             "data": base64.b64encode(obj.tobytes()).decode("ascii"),
         }
-    if isinstance(obj, np.ndarray) or (
-        hasattr(jax, "Array") and isinstance(obj, jax.Array)
-    ):
+    if isinstance(obj, (np.ndarray, jax.Array)):
         arr = np.asarray(obj)
         _check_binary_dtype(arr.dtype)
         if not arr.flags.c_contiguous:

@@ -193,8 +193,9 @@ KNOWN_METRICS: list[tuple[str, str, str]] = [
      "statics (the fused program's n_rounds) — planned executables, "
      "excluded from the retrace series"),
     ("v6t_jit_fallbacks_total", "counter",
-     "observed dispatches that fell back to plain jax.jit (tracer args, "
-     "sharding mismatch, AOT-unloweable call)"),
+     "observed dispatches forwarded to plain jax.jit because the call "
+     "could not be keyed (unhashable static); a compiler refusal or an "
+     "argument mismatch raises instead"),
     ("v6t_jit_cache_evictions_total", "counter",
      "compiled executables evicted from observed functions' bounded "
      "signature caches"),

@@ -26,34 +26,8 @@ import dataclasses
 from typing import Any, Callable, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 top-level; older: experimental
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-# fed_map requires variance checking OFF (see the comment at the call site:
-# with it on, jax auto-psums gradients of replicated inputs across the mesh,
-# silently breaking per-station gradient isolation). Resolve the flag name
-# once here; if a future jax renames it again, fail LOUDLY — running with
-# the check enabled would corrupt federated semantics without any error.
-import inspect as _inspect
-
-_SHARD_MAP_PARAMS = _inspect.signature(shard_map).parameters
-if "check_vma" in _SHARD_MAP_PARAMS:
-    _NO_VMA_KW = {"check_vma": False}
-elif "check_rep" in _SHARD_MAP_PARAMS:  # pragma: no cover - older jax
-    _NO_VMA_KW = {"check_rep": False}
-else:  # pragma: no cover
-    raise RuntimeError(
-        "cannot disable shard_map variance checking (no check_vma/check_rep "
-        "parameter in this jax version) — fed_map's per-station gradient "
-        "isolation would silently break; pin a compatible jax or update "
-        "vantage6_tpu.core.mesh"
-    )
 
 STATION_AXIS = "station"
 DEVICE_AXIS = "device"
@@ -66,9 +40,9 @@ def station_shard_map(mesh: "FederationMesh", fn: Callable[..., Any],
     code (``fed.collectives`` scattered primitives) that needs
     ``psum_scatter``/``all_gather`` with named-axis control instead of
     leaving the reduction to GSPMD."""
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh.mesh, in_specs=in_specs, out_specs=out_specs,
-        **_NO_VMA_KW,
+        check_vma=False,
     )
 
 
@@ -146,13 +120,14 @@ class FederationMesh:
 
     def shard_stacked(self, tree: Any) -> Any:
         """Place a pytree of stacked ``[S, ...]`` arrays onto the mesh,
-        station axis sharded. Works for numpy or jax inputs."""
-        sh = self.station_sharding()
-        return jax.tree.map(lambda x: jax.device_put(jnp.asarray(x), sh), tree)
+        station axis sharded. Works for numpy or jax inputs; a host array
+        goes shard by shard to its slot's device (never whole through
+        device 0), an array already placed so is returned as is."""
+        return jax.device_put(tree, self.station_sharding())
 
     def replicate(self, tree: Any) -> Any:
-        sh = self.replicated_sharding()
-        return jax.tree.map(lambda x: jax.device_put(jnp.asarray(x), sh), tree)
+        """Place a pytree on every device of the mesh (same contract)."""
+        return jax.device_put(tree, self.replicated_sharding())
 
     # ------------------------------------------------------------- execution
     def fed_map(
@@ -188,12 +163,12 @@ class FederationMesh:
         # into the cross-station sum (breaking the federated privacy/
         # isolation contract, not just numerics). All cross-station reduction
         # happens explicitly, outside fed_map, via fed.collectives.
-        return shard_map(
+        return jax.shard_map(
             block_fn,
             mesh=self.mesh,
             in_specs=in_specs,
             out_specs=self.station_spec(),
-            **_NO_VMA_KW,
+            check_vma=False,
         )(*stacked_args, *replicated_args)
 
     def fingerprint(self) -> tuple:

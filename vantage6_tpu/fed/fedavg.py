@@ -92,9 +92,8 @@ class FedAvgSpec:
     # unrolled, no while loop). Semantics and RNG streams are identical at
     # any value — a pure compilation-strategy knob. XLA:CPU runs
     # convolutions inside while-loop bodies ~6x slower than in straight-
-    # line code (measured, docs/device_speed.md), so CPU callers of the
-    # fused path want True; on TPU the scan form compiles faster and runs
-    # at the same speed, so the default stays 1.
+    # line code (measured on CPU, docs/device_speed.md), so CPU callers of
+    # the fused path want True. On the TPU: not measured.
     local_unroll: int | bool = 1
 
 
@@ -184,9 +183,11 @@ class FedAvg:
             static_argnames=("n_rounds", "unroll"),
             sweep_statics=("n_rounds", "unroll"),
         )
-        # run_rounds IS the multi-round fast path: donating params,
-        # opt_state and the key lets XLA update the scan carry in place
-        # instead of double-buffering model + moments for the whole run.
+        # run_rounds IS the multi-round fast path: donating params and
+        # opt_state lets XLA update the scan carry in place instead of
+        # double-buffering model + moments for the whole run. (The key is
+        # not donated: it is split inside and no output has its type, so
+        # XLA could never reuse the buffer.)
         # Kept as a SEPARATE executable so run_rounds(donate=False) (and
         # AOT callers compiling self._run directly) never consume caller
         # buffers.
@@ -194,7 +195,7 @@ class FedAvg:
             "fedavg.run_rounds_donating", self._run_impl,
             static_argnames=("n_rounds", "unroll"),
             sweep_statics=("n_rounds", "unroll"),
-            donate_argnums=(0, 1, 6),  # params, opt_state, key
+            donate_argnums=(0, 1),  # params, opt_state
         )
         # fused buffered-async runner: staleness rides the scan carry so K
         # async rounds (accept masks + FedBuff discounting) are one
@@ -208,7 +209,7 @@ class FedAvg:
             "fedavg.run_rounds_async_donating", self._run_async_impl,
             static_argnames=("n_rounds",),
             sweep_statics=("n_rounds",),
-            donate_argnums=(0, 1, 6, 8),  # params, opt_state, key, staleness
+            donate_argnums=(0, 1, 8),  # params, opt_state, staleness
         )
 
     # ------------------------------------------------------------ local step
@@ -412,22 +413,62 @@ class FedAvg:
         if self.spec.shard_server_update:
             flat = flatten_tree(params)
             n_pad = padded_flat_size(flat.size, self.mesh.station_axis_size)
-            flat = jnp.pad(flat, (0, n_pad - flat.size))
-            state = self.server_opt.init(flat)
-            state = jax.tree.map(
-                lambda x: jax.device_put(x, self.mesh.station_sharding())
-                if getattr(x, "ndim", 0) == 1 and x.shape == (n_pad,)
-                else x,
-                state,
-            )
+            state = self.server_opt.init(jnp.pad(flat, (0, n_pad - flat.size)))
         else:
             state = self.server_opt.init(params)
         if self._compressing:
             ef = jnp.zeros(
-                (self.mesh.n_stations, flat_size(params)), jnp.float32
+                (self.mesh.n_stations, flat_size(params)), jnp.float32,
+                device=self.mesh.station_sharding(),
             )
-            return {"server": state, "ef": self.mesh.shard_stacked(ef)}
-        return state
+            state = {"server": state, "ef": ef}
+        return self._place_state(state, flat_size(params))
+
+    def _place_state(self, state: Any, n_flat: int) -> Any:
+        """Commit an optimizer state to the shardings a round returns it
+        in: ZeRO-1 flat moments and error-feedback rows over the station
+        axis, every other leaf on all devices. A state that came out of a
+        round is returned as is; one from ``optax`` or a checkpoint is
+        placed here, once."""
+        mesh = self.mesh
+        if self._compressing:
+            return {
+                "server": self._place_server_state(state["server"], n_flat),
+                "ef": mesh.shard_stacked(state["ef"]),
+            }
+        return self._place_server_state(state, n_flat)
+
+    def _place_server_state(self, state: Any, n_flat: int) -> Any:
+        mesh = self.mesh
+        if not self.spec.shard_server_update:
+            return mesh.replicate(state)
+        n_pad = padded_flat_size(n_flat, mesh.station_axis_size)
+        scattered, whole = mesh.station_sharding(), mesh.replicated_sharding()
+        return jax.tree.map(
+            lambda x: jax.device_put(
+                x, scattered if jnp.shape(x) == (n_pad,) else whole
+            ),
+            state,
+        )
+
+    def _place(
+        self, params: Pytree, opt_state: Any, counts: Any, mask: Any,
+        key: jax.Array,
+    ) -> tuple[Pytree, Any, jax.Array, jax.Array, jax.Array]:
+        """Commit what the round program carries or broadcasts to the
+        mesh, at the engine's entry. jit (and the observatory with it)
+        keys on committed shardings: fresh unplaced params and the same
+        params as a round returns them are two signatures, and the second
+        would be a second full compile of the fused program on every cold
+        start. Placed here, the first signature is the steady-state one."""
+        rep = self.mesh.replicate
+        return (
+            rep(params),
+            self._place_state(opt_state, flat_size(params)),
+            rep(jnp.asarray(counts)),
+            rep(jnp.asarray(mask)),
+            rep(key),
+        )
 
     def round(
         self,
@@ -447,6 +488,9 @@ class FedAvg:
         the anomalous-station watchdog rules."""
         if mask is None:
             mask = jnp.ones_like(counts)
+        params, opt_state, counts, mask, key = self._place(
+            params, opt_state, counts, mask, key
+        )
         self._record_wire(params)
         out = self._round(
             params, opt_state, stacked_x, stacked_y, counts, mask, key
@@ -547,8 +591,8 @@ class FedAvg:
         FedAdam etc. without resetting server-optimizer moments); omitted, a
         fresh optimizer state is initialized.
 
-        DONATION: by default ``params``, ``opt_state`` and ``key`` buffers
-        are donated — XLA updates the scan carry in place instead of
+        DONATION: by default the ``params`` and ``opt_state`` buffers are
+        donated — XLA updates the scan carry in place instead of
         double-buffering model + moments, but the caller's input arrays are
         CONSUMED and must not be touched again (use the returned values).
         Pass ``donate=False`` to keep the inputs alive (e.g. ablations
@@ -559,14 +603,16 @@ class FedAvg:
         no while loop) — a pure compilation-strategy knob with identical
         semantics at any value. Combine with ``FedAvgSpec.local_unroll``
         on CPU, where XLA runs convolutions inside while-loop bodies ~6x
-        slower than straight-line (docs/device_speed.md "K-selection");
-        leave both at 1 on TPU, where the scan form compiles much faster
-        at the same execution speed.
+        slower than straight-line (docs/device_speed.md "K-selection").
+        On the TPU the two forms have not been compared: not measured.
         """
         if mask is None:
             mask = jnp.ones_like(counts)
         if opt_state is None:
             opt_state = self.init(params)
+        params, opt_state, counts, mask, key = self._place(
+            params, opt_state, counts, mask, key
+        )
         self._record_wire(params, n_rounds=n_rounds)
         self._record_fused(n_rounds)
         run = self._run_donating if donate else self._run
@@ -609,12 +655,16 @@ class FedAvg:
             staleness = jnp.zeros_like(counts, dtype=jnp.float32)
         if opt_state is None:
             opt_state = self.init(params)
+        params, opt_state, counts, mask, key = self._place(
+            params, opt_state, counts, mask, key
+        )
         self._record_wire(params, n_rounds=n_rounds)
         self._record_fused(n_rounds)
         run = self._run_async_donating if donate else self._run_async
         out = run(
             params, opt_state, stacked_x, stacked_y, counts, mask, key,
-            accept_masks, jnp.asarray(staleness, jnp.float32),
+            accept_masks,
+            self.mesh.replicate(jnp.asarray(staleness, jnp.float32)),
             jnp.float32(spec.staleness_discount), n_rounds=n_rounds,
         )
         self._record_history(out[3], out[4], rounds_per_dispatch=n_rounds)

@@ -7,10 +7,11 @@ at memory bandwidth instead of interpreter speed. The on-pod path never
 comes here (XLA collectives); nodes use this before uploading results to a
 remote control plane.
 
-`lib()` compiles `secureagg.cpp` on first use with g++ (cached next to the
-package); every entry point transparently falls back to numpy when no
-compiler is available, and the two implementations are bit-identical (tested
-against each other and the RFC 8439 vector).
+`lib()` compiles `secureagg.cpp` on first use with g++ (cached per user,
+outside the tree, under a name keyed by a hash of the source); every entry
+point transparently falls back to numpy when no compiler is available, and
+the two implementations are bit-identical (tested against each other and the
+RFC 8439 vector).
 """
 from __future__ import annotations
 
@@ -45,8 +46,11 @@ def lib() -> ctypes.CDLL | None:
     cache_dir = Path(os.environ.get("V6T_NATIVE_CACHE", default_cache))
     cache_dir.mkdir(parents=True, exist_ok=True)
     os.chmod(cache_dir, 0o700)
-    so_path = cache_dir / "libv6t_secureagg.so"
-    if not so_path.exists() or so_path.stat().st_mtime < _SRC.stat().st_mtime:
+    # named by a hash of the SOURCE: a library built from another checkout's
+    # secureagg.cpp (same cache dir, newer mtime) is never loaded for this one
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    so_path = cache_dir / f"libv6t_secureagg-{digest}.so"
+    if not so_path.exists():
         # build to a unique temp name, then atomically publish: concurrent
         # daemons must never CDLL a half-linked file
         fd, tmp_so = tempfile.mkstemp(suffix=".so", dir=cache_dir)

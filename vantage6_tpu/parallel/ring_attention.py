@@ -26,8 +26,6 @@ from jax import lax
 
 from jax.sharding import Mesh, PartitionSpec as P
 
-from vantage6_tpu.core.mesh import shard_map  # version-portable resolution
-
 
 NEG_INF = -1e30
 
@@ -157,7 +155,7 @@ def ring_attention_sharded(
     directly inside its own shard_map.
     """
     spec = P(None, axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(ring_attention, axis_name=axis_name, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -15,13 +15,7 @@ from typing import Any
 
 import jax
 import numpy as np
-
-try:
-    import orbax.checkpoint as ocp
-
-    _HAS_ORBAX = True
-except ImportError:  # pragma: no cover
-    _HAS_ORBAX = False
+import orbax.checkpoint as ocp
 
 
 @dataclasses.dataclass
@@ -67,8 +61,6 @@ class CheckpointManager:
     """Thin wrapper over orbax CheckpointManager keyed by round index."""
 
     def __init__(self, directory: str | Path, max_to_keep: int = 3):
-        if not _HAS_ORBAX:  # pragma: no cover
-            raise RuntimeError("orbax-checkpoint is not installed")
         self.directory = Path(directory).absolute()
         self.directory.mkdir(parents=True, exist_ok=True)
         self._mgr = ocp.CheckpointManager(

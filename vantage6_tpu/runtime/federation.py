@@ -285,7 +285,15 @@ class Federation:
                 per = [
                     self.station_data(i, label) for i in range(self.n_stations)
                 ]
-                stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *per)
+                # host data is stacked ON THE HOST and goes shard by shard
+                # to its slot's device; jnp.stack would first build the
+                # whole [S, ...] array on device 0
+                stacked = jax.tree.map(
+                    lambda *xs: np.stack(xs)
+                    if all(isinstance(x, (np.ndarray, np.generic)) for x in xs)
+                    else jnp.stack(xs),
+                    *per,
+                )
                 self._stacked_cache[label] = self.mesh.shard_stacked(stacked)
             return self._stacked_cache[label]
 

@@ -38,10 +38,16 @@ attribution layer for everything below `jax.jit`:
 Dispatch semantics: an observed function behaves exactly like its
 ``jax.jit`` twin. Called under an outer trace (leaves are tracers) it
 inlines like any jitted function; called with a known signature it
-dispatches straight to the cached executable; anything the AOT path
-cannot express (sharding mismatch, exotic pytree) falls back to the
-plain jitted callable — counted, never fatal. Disable the whole layer
-with ``V6T_DEVICE_OBS=0`` (calls forward to ``jax.jit`` untouched).
+dispatches straight to the cached executable. The signature agrees with
+jit's own cache (shape, dtype, weak type, committed sharding), so one
+signature is one executable and nothing is ever compiled twice: a
+``lower()``/``compile()`` failure (a Mosaic refusal, a compile OOM) and
+an executable that rejects its arguments both RAISE, exactly as the jit
+twin would — the device's refusal is never hidden behind a second
+compile. Only a call the observatory cannot key at all (an unhashable
+static) forwards to plain jit, counted in ``v6t_jit_fallbacks_total``.
+Disable the whole layer with ``V6T_DEVICE_OBS=0`` (calls forward to
+``jax.jit`` untouched).
 """
 from __future__ import annotations
 
@@ -50,7 +56,7 @@ import threading
 import time
 import weakref
 from collections import OrderedDict, deque
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 
@@ -69,23 +75,44 @@ __all__ = [
 ]
 
 
-def _abstractify(leaf: Any) -> Any:
-    """Hashable abstract signature of one leaf — jax's own retrace key
-    (shape, dtype, weak_type) when the leaf is array-like, a type tag
-    otherwise (an exotic leaf must not crash the observatory)."""
-    try:
-        from jax.api_util import shaped_abstractify
+class _LeafSig(NamedTuple):
+    """What ``jax.jit``'s own cache keys one argument on: shape, dtype,
+    weak type and — for a COMMITTED array — its sharding. An uncommitted
+    array (fresh ``jnp`` output, numpy, python scalar) carries ``None``:
+    jit is free to place it, so where it happens to live is not part of
+    the signature."""
 
-        return shaped_abstractify(leaf)
-    except Exception:
+    shape: tuple
+    dtype: Any
+    weak_type: bool
+    sharding: Any
+
+    def str_short(self) -> str:
+        out = f"{self.dtype.name}[{','.join(map(str, self.shape))}]"
+        if self.sharding is not None:
+            out += f"@{getattr(self.sharding, 'spec', self.sharding)}"
+        return out
+
+
+def _abstractify(leaf: Any) -> Any:
+    """Hashable signature of one leaf, in agreement with jit's cache
+    (:class:`_LeafSig`); a type tag for a leaf jax cannot type (an exotic
+    leaf must not crash the observatory — jit raises its own error)."""
+    try:
+        aval = jax.typeof(leaf)
+    except TypeError:
         return ("opaque", type(leaf).__name__)
+    sharding = (
+        leaf.sharding
+        if isinstance(leaf, jax.Array) and leaf.committed else None
+    )
+    return _LeafSig(
+        aval.shape, aval.dtype, getattr(aval, "weak_type", False), sharding
+    )
 
 
 def _leaf_str(aval: Any) -> str:
-    try:
-        return aval.str_short()
-    except Exception:
-        return str(aval)
+    return aval.str_short() if isinstance(aval, _LeafSig) else str(aval)
 
 
 def _signature_diff(
@@ -272,8 +299,8 @@ class ObservedFunction:
             key = (avals, treedef, statics)
             hash(key)
         except TypeError:
-            # unhashable static (a list-valued kwarg, ...): observe
-            # nothing rather than crash the call
+            # unhashable static (a list-valued kwarg, ...): nothing to
+            # key on — forward to jit, which raises its own error
             self.fallbacks += 1
             REGISTRY.counter("v6t_jit_fallbacks_total").inc()
             return self._jit(*args, **kwargs)
@@ -284,21 +311,7 @@ class ObservedFunction:
         if compiled is None:
             compiled = self._compile(key, args, kwargs, avals, dyn_args,
                                      dyn_kwargs)
-            if compiled is None:  # AOT path unavailable — plain jit
-                return self._jit(*args, **kwargs)
-        try:
-            return compiled(*dyn_args, **dyn_kwargs)
-        except (TypeError, ValueError):
-            # sharding/pytree mismatch the abstract key couldn't see —
-            # raised while PROCESSING arguments, before any buffer is
-            # donated, so retrying via jit's own dispatch is safe.
-            # Execution failures (XlaRuntimeError: OOM mid-scan, ...)
-            # propagate: a retry would re-run the whole computation, and
-            # with donated inputs would mask the real error behind
-            # "Array has been deleted".
-            self.fallbacks += 1
-            REGISTRY.counter("v6t_jit_fallbacks_total").inc()
-            return self._jit(*args, **kwargs)
+        return compiled(*dyn_args, **dyn_kwargs)
 
     def _compile(
         self, key: tuple, args: tuple, kwargs: dict, avals: tuple,
@@ -372,13 +385,11 @@ class ObservedFunction:
                 compiled = lowered.compile()
                 t2 = time.perf_counter()
             except Exception as e:
-                # an AOT-unloweable call (e.g. a jax version quirk):
-                # record the failure, let the caller use plain jit
-                sp.set_status("error")
+                # the compiler refused the program: name it on the span
+                # and let the caller see it — retrying through plain jit
+                # would only compile (and fail, or OOM) a second time
                 sp.set_attr(error=repr(e))
-                self.fallbacks += 1
-                REGISTRY.counter("v6t_jit_fallbacks_total").inc()
-                return None
+                raise
             lower_s, compile_s = t1 - t0, t2 - t1
             mem = _memory_summary(compiled)
             cost = _cost_summary(compiled)

@@ -25,12 +25,7 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from vantage6_tpu.core.mesh import (
-    _NO_VMA_KW,
-    STATION_AXIS,
-    _largest_divisor_leq,
-    shard_map,
-)
+from vantage6_tpu.core.mesh import STATION_AXIS, _largest_divisor_leq
 from vantage6_tpu.fed import collectives
 from vantage6_tpu.ops.flash_attention import (
     flash_attention,
@@ -58,8 +53,8 @@ class TransformerConfig:
     # make_engine); `flash_interpret` runs it in interpret mode on CPU.
     # "recompute": flash-memory attention WITHOUT pallas (blockwise jnp
     # forward + recompute backward; ops.recompute_attention) — same
-    # seq_devices == 1 constraint; the long-context choice on runtimes
-    # where compiled pallas is unavailable.
+    # seq_devices == 1 constraint. "flash" runs the kernel or raises; it
+    # never gives way to "recompute".
     attention: str = "ring"
     flash_interpret: bool = False
     # Rematerialization: drop every layer's activations on the forward pass
@@ -201,10 +196,12 @@ class FedTransformer:
     optimizer: Any
 
     def init(self, key: jax.Array) -> tuple[Any, Any]:
-        params = init_params(key, self.cfg)
+        # params AND the whole optimizer state are committed to the mesh:
+        # optax's step count is born unplaced, and a round returns it
+        # placed — left so, the second round() would be a second compile
         rep = NamedSharding(self.mesh, P())
-        params = jax.tree.map(lambda x: jax.device_put(x, rep), params)
-        return params, self.optimizer.init(params)
+        params = jax.device_put(init_params(key, self.cfg), rep)
+        return params, jax.device_put(self.optimizer.init(params), rep)
 
     def shard_tokens(self, tokens: np.ndarray | jax.Array) -> jax.Array:
         """[S, B, T] -> sharded (station, none, device)."""
@@ -250,12 +247,12 @@ class FedTransformer:
         # works around the pallas-interpret + VMA interaction that rejects
         # the flash kernel inside a checked shard_map (jax 0.9 asks for
         # exactly this workaround).
-        losses, grads = shard_map(
+        losses, grads = jax.shard_map(
             station_body,
             mesh=self.mesh,
             in_specs=(P(), P(STATION_AXIS, None, SEQ_AXIS)),
             out_specs=(P(STATION_AXIS), P(STATION_AXIS)),
-            **_NO_VMA_KW,
+            check_vma=False,
         )(params, tokens)
         # explicit cross-station aggregation: the ONLY place station data mixes
         g_mean = collectives.fed_mean(grads, mask=mask)

@@ -88,15 +88,29 @@ def make_federated_data(
     noise: float = 0.7,
 ):
     """MNIST-shaped data (REAL MNIST when a local copy exists — see
-    utils.datasets.load_mnist — synthetic templates otherwise), Dirichlet
-    non-iid across stations, padded + stacked (+ sharded with a mesh).
-    ``noise`` hardens the synthetic task (see utils.datasets.image_classes)."""
+    utils.datasets.load_mnist — synthetic templates otherwise), federated
+    by :func:`federate`. ``noise`` hardens the synthetic task (see
+    utils.datasets.image_classes)."""
     x, y = image_classes(n_stations * n_per_station, seed=seed, noise=noise)
+    return federate(x, y, n_stations, alpha=alpha, seed=seed, mesh=mesh)
+
+
+def federate(
+    x: np.ndarray,
+    y: np.ndarray,
+    n_stations: int,
+    alpha: float = 0.5,
+    seed: int = 0,
+    mesh: FederationMesh | None = None,
+):
+    """Examples -> (stacked x, stacked y, counts): Dirichlet non-iid across
+    stations, padded + stacked, and with a mesh placed on it (shards over
+    the station axis, counts on every device)."""
     shards = partition_dirichlet(x, y, n_stations, alpha=alpha, seed=seed)
     sx, sy, counts = pad_shards(shards)
-    if mesh is not None:
-        sx, sy = mesh.shard_stacked(sx), mesh.shard_stacked(sy)
-    return sx, sy, jnp.asarray(counts)
+    if mesh is None:
+        return sx, sy, jnp.asarray(counts)
+    return mesh.shard_stacked(sx), mesh.shard_stacked(sy), mesh.replicate(counts)
 
 
 def train_fedavg(

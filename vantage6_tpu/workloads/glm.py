@@ -113,12 +113,8 @@ def partial_glm_stats(
         else np.ones_like(y)
     )
     # host mode matches the reference's float64 IRLS exactly; enable_x64 is
-    # scoped so the process-wide x32 default (TPU path) is untouched.
-    # jax.experimental.enable_x64 is the supported spelling — the bare
-    # `jax.enable_x64` alias was removed from the top-level namespace
-    # (AttributeError since jax 0.4.3x), which is what kept these 8 tests
-    # red since PR 1.
-    with jax.experimental.enable_x64():
+    # scoped so the process-wide x32 default (TPU path) is untouched
+    with jax.enable_x64(True):
         b = jnp.asarray(beta, jnp.float64)
         eta = jnp.asarray(x) @ b
         _, z, w, dev = _irls_pieces(
