@@ -88,8 +88,14 @@ class TestObservedJit:
         spans = compile_spans(root.context.trace_id)
         assert len(spans) == 1
         sp = spans[0]
-        # parented INSIDE the active trace, not a floating root
-        assert sp["parent_id"] == root.context.span_id
+        # parented INSIDE the active trace, not a floating root: under the
+        # device.launch that compiled, which is the root's child
+        launch = next(
+            s for s in TRACER.drain(root.context.trace_id)
+            if s["span_id"] == sp["parent_id"]
+        )
+        assert launch["name"] == "device.launch"
+        assert launch["parent_id"] == root.context.span_id
         a = sp["attrs"]
         assert a["function"] == "t.intro"
         assert a["retrace"] is False

@@ -1,0 +1,323 @@
+"""The round path under the tracer: `engine.call` and `device.launch` spans
+around `FedAvg` and `FedTransformer`, the same spans as host events of a
+profiler session, and the `jax.named_scope` names on the device's
+operations. CPU, tiny sizes."""
+from __future__ import annotations
+
+import contextlib
+import os
+import re
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from perfbench import trace as bench_trace
+from vantage6_tpu.core.mesh import FederationMesh
+from vantage6_tpu.fed.compression import CompressorSpec
+from vantage6_tpu.fed.fedavg import FedAvg, FedAvgSpec
+from vantage6_tpu.runtime.profiling import DEVICE_SCOPES, observed_jit
+from vantage6_tpu.runtime.tracing import TRACER
+from vantage6_tpu.workloads import fed_transformer as FT
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(autouse=True)
+def _tracer_on():
+    TRACER.configure(enabled=True, sample=1.0)
+    TRACER.clear()
+    yield
+    TRACER.configure(enabled=True, sample=1.0)
+
+
+# ------------------------------------------------------------ tiny engines
+def _transformer(attention="recompute"):
+    ring = attention == "ring"
+    cfg = FT.TransformerConfig(
+        vocab=97, d_model=32, n_heads=4, n_layers=2, max_len=16,
+        attention=attention, flash_interpret=True, remat=ring)
+    engine = FT.make_engine(4, 2 if ring else 1, cfg,
+                            devices=jax.devices()[: 8 if ring else 1])
+    params, opt_state = engine.init(jax.random.key(0))
+    tokens = engine.shard_tokens(FT.make_federated_tokens(4, 2, 16, 97))
+    return engine, (params, opt_state, tokens, jnp.ones(4))
+
+
+def _nll(p, x, y, w):
+    z = x @ p["w"] + p["b"]
+    return jnp.sum(w * (jnp.logaddexp(0.0, z) - y * z)) / jnp.sum(w)
+
+
+def _fedavg(**spec):
+    mesh = FederationMesh(8)
+    engine = FedAvg(mesh, FedAvgSpec(
+        loss_fn=_nll, local_steps=2, batch_size=8, **spec))
+    rng = np.random.default_rng(0)
+    x = mesh.shard_stacked(jnp.asarray(rng.normal(size=(8, 16, 5)),
+                                       jnp.float32))
+    y = mesh.shard_stacked(jnp.asarray(rng.integers(0, 2, (8, 16)),
+                                       jnp.float32))
+    params = {"w": jnp.zeros(5), "b": jnp.zeros(())}
+    return engine, params, (x, y, jnp.full((8,), 16.0))
+
+
+def _fedavg_lowered(engine, params, data):
+    x, y, counts = data
+    placed = engine._place(params, engine.init(params), counts,
+                           jnp.ones(8), jax.random.key(1))
+    p, state, counts, mask, key = placed
+    return engine._run.lower(p, state, x, y, counts, mask, key,
+                             n_rounds=3, unroll=1)
+
+
+# ------------------------------------------------------------------ scopes
+TRANSFORMER_SCOPES = {"local_train", "embed", "attention", "mlp",
+                      "lm_head_loss", "aggregate", "server_update"}
+FEDAVG_SCOPES = {"local_train", "gather", "loss_grad", "learning_stats",
+                 "aggregate", "server_update"}
+PROGRAMS = {
+    "transformer-recompute": (lambda: _transformer("recompute"),
+                              TRANSFORMER_SCOPES),
+    "transformer-ring": (lambda: _transformer("ring"), TRANSFORMER_SCOPES),
+    "transformer-flash": (lambda: _transformer("flash"), TRANSFORMER_SCOPES),
+    "fedavg-fused": (lambda: _fedavg(), FEDAVG_SCOPES),
+    "fedavg-compressed-zero1": (
+        lambda: _fedavg(
+            compressor=CompressorSpec(topk_ratio=0.5, int8=True),
+            shard_server_update=True, server_optimizer=optax.adam(1e-2)),
+        FEDAVG_SCOPES | {"compress"}),
+}
+
+
+def _lowered(built):
+    if isinstance(built[0], FT.FedTransformer):
+        engine, args = built
+        return engine._round.lower(engine, *args)
+    return _fedavg_lowered(*built)
+
+
+def _scopes_in(lowered) -> set[str]:
+    """Scope names as the compiled operations' metadata carries them, which
+    is what a device trace shows: an element of `op_name`'s path
+    (`.../attention/...`) or, backward, `transpose(jvp(attention))`. An
+    operation's own name (`.../gather`) ends the path and is not one."""
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    return {s for s in DEVICE_SCOPES
+            if any(re.search(rf"[/(]{s}[/)]", n) for n in names)}
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_the_compiled_operations_carry_each_scope_name(program):
+    build, expected = PROGRAMS[program]
+    assert _scopes_in(_lowered(build())) == expected
+
+
+def test_every_scope_of_the_tuple_is_opened_by_some_program():
+    assert set().union(*(s for _, s in PROGRAMS.values())) == set(
+        DEVICE_SCOPES)
+    assert len(set(DEVICE_SCOPES)) == len(DEVICE_SCOPES)
+
+
+@pytest.mark.parametrize("program", ["transformer-recompute",
+                                     "fedavg-compressed-zero1"])
+def test_scopes_are_metadata_and_nothing_else(program, monkeypatch):
+    """The program as XLA gets it (the text without locations, which is
+    what the persistent cache keys on) is the same without the scopes."""
+    build, _ = PROGRAMS[program]
+    with_scopes = _lowered(build())
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    without = _lowered(build())
+    assert with_scopes.as_text() == without.as_text()
+    assert not _scopes_in(without)
+
+
+# ------------------------------------------------------------------- spans
+def _call_transformer():
+    engine, args = _transformer()
+    out = engine.round(*args)
+    return out, "fed_transformer.round", 1, len(jax.tree.leaves(args))
+
+
+def _call_run_rounds():
+    engine, params, (x, y, counts) = _fedavg()
+    out = engine.run_rounds(params, x, y, counts, jax.random.key(1), 3)
+    # params, the empty sgd state, x, y, counts, mask, key
+    return out, "fedavg.run_rounds", 3, len(jax.tree.leaves(params)) + 5
+
+
+def _call_fedavg_round():
+    engine, params, (x, y, counts) = _fedavg()
+    state = engine.init(params)
+    out = engine.round(params, state, x, y, counts, jax.random.key(1))
+    return out, "fedavg.round", 1, len(jax.tree.leaves(params)) + 5
+
+
+ENGINES = {"fed_transformer.round": _call_transformer,
+           "fedavg.run_rounds": _call_run_rounds,
+           "fedavg.round": _call_fedavg_round}
+
+
+def _named(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+@pytest.mark.parametrize("caller", ["rooted", "joined"])
+@pytest.mark.parametrize("engine", ENGINES)
+def test_one_engine_call_with_one_launch_under_it(engine, caller):
+    if caller == "joined":
+        with TRACER.span("researcher.step") as outer:
+            _, name, rounds, n_buffers = ENGINES[engine]()
+    else:
+        _, name, rounds, n_buffers = ENGINES[engine]()
+    spans = TRACER.drain()
+    (call,) = _named(spans, "engine.call")
+    assert call["kind"] == "engine"
+    assert call["attrs"] == {"engine": name, "rounds": rounds}
+    (launch,) = [s for s in _named(spans, "device.launch")
+                 if s["parent_id"] == call["span_id"]]
+    assert launch["kind"] == "device"
+    assert launch["trace_id"] == call["trace_id"]
+    assert launch["attrs"]["n_buffers"] == n_buffers
+    assert launch["attrs"]["function"].startswith(name)
+    assert 0 < launch["dur"] <= call["dur"]
+    if caller == "joined":
+        assert call["parent_id"] == outer.context.span_id
+        assert call["trace_id"] == outer.context.trace_id
+    else:
+        assert call["parent_id"] is None
+
+
+def test_a_launch_that_compiles_has_the_compile_under_it():
+    fn = observed_jit("t.launch", lambda x: x * 2.0)
+    fn(jnp.ones(4))
+    fn(jnp.ones(4))
+    spans = TRACER.drain()
+    first, second = _named(spans, "device.launch")
+    assert first["attrs"] == {"function": "t.launch", "n_buffers": 1}
+    (compiled,) = _named(spans, "device.compile")
+    assert compiled["parent_id"] == first["span_id"]
+    assert compiled["dur"] <= first["dur"]
+    assert not [s for s in spans if s["parent_id"] == second["span_id"]]
+
+
+def test_under_an_outer_jit_an_observed_function_opens_no_launch():
+    inner = observed_jit("t.inner", lambda x: x + 1.0)
+    jax.jit(lambda x: inner(x) * 2.0)(jnp.ones(4))
+    assert not _named(TRACER.drain(), "device.launch")
+
+
+@pytest.mark.parametrize("engine", ["fed_transformer.round",
+                                    "fedavg.run_rounds"])
+def test_with_the_tracer_off_nothing_is_recorded_and_nothing_changes(engine):
+    traced = jax.device_get(ENGINES[engine]()[0])
+    assert _named(TRACER.drain(), "engine.call")
+    TRACER.configure(enabled=False)
+    TRACER.clear()
+    plain = jax.device_get(ENGINES[engine]()[0])
+    assert TRACER.drain() == []
+    for a, b in zip(jax.tree.leaves(traced), jax.tree.leaves(plain)):
+        np.testing.assert_array_equal(a, b)
+
+
+# ------------------------------------------------------ the profiler's clock
+def _host_events(log_dir):
+    from jax.profiler import ProfileData
+
+    path = bench_trace.find_xplane(str(log_dir))
+    plain = bench_trace.load_xplane(path)
+    events = {}
+    for plane in plain["planes"]:
+        if plane["name"].startswith("/device:"):
+            continue
+        for line in plane["lines"]:
+            for name, start, dur in line["events"]:
+                events.setdefault(name, []).append((start, start + dur))
+    ids = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for event in line.events:
+                if event.name in ("engine.call", "device.launch"):
+                    ids[event.name] = dict(event.stats).get("span_id")
+    return events, ids
+
+
+def test_a_span_is_a_host_event_of_a_profiler_session(tmp_path):
+    engine, args = _transformer()
+    engine.round(*args)  # compiled before the session
+    TRACER.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(engine.round(*args))
+        TRACER.configure(sample=0.0)
+        with TRACER.span("unsampled.root"):
+            pass
+        TRACER.configure(enabled=False)
+        with TRACER.span("disabled.span"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    events, ids = _host_events(tmp_path)
+    (call,), (launch,) = events["engine.call"], events["device.launch"]
+    assert call[0] <= launch[0] and launch[1] <= call[1]
+    # jax's own launch event lies inside the program's, on the same clock
+    inside = [name for name, spans in events.items()
+              if bench_trace.host_kind(name) == "dispatch"
+              and any(launch[0] <= a and b <= launch[1] for a, b in spans)]
+    assert inside, sorted(events)[:40]
+    spans = {s["name"]: s for s in TRACER.drain()}
+    for name in ("engine.call", "device.launch"):
+        # the profiler reads an id of digits alone as a number
+        assert str(ids[name]).lstrip("0") == spans[name]["span_id"].lstrip("0")
+    assert "unsampled.root" not in events and "disabled.span" not in events
+
+
+def test_a_process_that_cannot_import_jax_still_records_spans():
+    code = f"""
+import sys, types
+sys.modules["jax"] = None  # `import jax` now raises ImportError
+package = types.ModuleType("vantage6_tpu")  # its __init__ imports jax
+package.__path__ = [{os.path.join(ROOT, "vantage6_tpu")!r}]
+sys.modules["vantage6_tpu"] = package
+from vantage6_tpu.runtime.tracing import TRACER
+TRACER.configure(enabled=True, sample=1.0)
+with TRACER.span("client.task_create") as outer:
+    with TRACER.span("rest"):
+        pass
+names = [s["name"] for s in TRACER.drain(outer.context.trace_id)]
+assert names == ["rest", "client.task_create"], names
+assert "jax.profiler" not in sys.modules
+print("recorded", len(names))
+"""
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.strip() == "recorded 2"
+
+
+# ------------------------------------------- the names the benchmark reads
+# what `perfbench/trace.py::host_kind` makes of each span name of the round
+# and task paths: a name may hold one of its marks only if it is of that kind
+SPAN_KINDS = {
+    "engine.call": None, "device.launch": None, "device.compile": "compile",
+    "device.profile": None, "device.step": None, "device.compress": None,
+    "device.decompress": None, "server.dispatch": "dispatch",
+    "fused.rounds": None, "runner.exec": None, "aggregate": None,
+    "learning.round": None,
+}
+
+
+@pytest.mark.parametrize("name", SPAN_KINDS)
+def test_a_span_name_reads_as_the_kind_it_is(name):
+    assert bench_trace.host_kind(name) == SPAN_KINDS[name]
+
+
+def test_the_round_path_records_no_span_name_outside_that_table():
+    for call in ENGINES.values():
+        call()
+    assert {s["name"] for s in TRACER.drain()} <= set(SPAN_KINDS)

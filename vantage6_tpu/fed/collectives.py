@@ -95,10 +95,11 @@ def _weighted_leaf_sum(x: jax.Array, w: jax.Array) -> jax.Array:
 def fed_sum(stacked: Pytree, mask: jax.Array | None = None) -> Pytree:
     """Sum each leaf over the station axis. Parity: the `sum` half of
     v6-average's central step."""
-    if mask is None:
-        return jax.tree.map(lambda x: jnp.sum(x, axis=0), stacked)
-    m = jnp.asarray(mask)
-    return jax.tree.map(lambda x: _weighted_leaf_sum(x, m), stacked)
+    with jax.named_scope("aggregate"):
+        if mask is None:
+            return jax.tree.map(lambda x: jnp.sum(x, axis=0), stacked)
+        m = jnp.asarray(mask)
+        return jax.tree.map(lambda x: _weighted_leaf_sum(x, m), stacked)
 
 
 def fed_mean(
@@ -116,14 +117,15 @@ def fed_mean(
     ``_norm_weights`` for the full numerics contract) — use
     ``fed_mean_scattered`` when f32 accumulation over bf16 leaves matters.
     """
-    n = _station_count(stacked)
-    w = _norm_weights(n, weights, mask)
-    total = jnp.sum(w)
-    # Guard the all-dropped edge: return zeros rather than NaN.
-    denom = jnp.where(total > 0, total, 1.0)
-    return jax.tree.map(
-        lambda x: _weighted_leaf_sum(x, w) / jnp.asarray(denom, x.dtype), stacked
-    )
+    with jax.named_scope("aggregate"):
+        n = _station_count(stacked)
+        w = _norm_weights(n, weights, mask)
+        total = jnp.sum(w)
+        # Guard the all-dropped edge: return zeros rather than NaN.
+        denom = jnp.where(total > 0, total, 1.0)
+        return jax.tree.map(
+            lambda x: _weighted_leaf_sum(x, w) / jnp.asarray(denom, x.dtype), stacked
+        )
 
 
 def fed_weighted_stats(
@@ -370,7 +372,6 @@ def fed_sum_scattered(
         raise ValueError(
             f"stacked has {n} stations but mesh federates {mesh.n_stations}"
         )
-    w = _norm_weights(n, weights, mask)
     d = mesh.station_axis_size
     n_flat = flat_size(jax.tree.map(lambda x: x[0], stacked))
     pad = padded_flat_size(n_flat, d) - n_flat
@@ -396,7 +397,8 @@ def fed_sum_scattered(
             out_specs=P(STATION_AXIS),
         ),
     )
-    return runner(stacked, w)
+    with jax.named_scope("aggregate"):
+        return runner(stacked, _norm_weights(n, weights, mask))
 
 
 def fed_mean_scattered(
@@ -414,13 +416,14 @@ def fed_mean_scattered(
     the scatter, so the all-dropped guard and dropped-station debiasing
     match ``fed_mean`` exactly.
     """
-    n = _station_count(stacked)
-    w = _norm_weights(n, weights, mask)
-    total = jnp.sum(w)
-    denom = jnp.where(total > 0, total, 1.0)
-    s = fed_sum_scattered(mesh, stacked, weights=weights, mask=mask,
-                          comm_dtype=comm_dtype)
-    return s / denom
+    with jax.named_scope("aggregate"):
+        n = _station_count(stacked)
+        w = _norm_weights(n, weights, mask)
+        total = jnp.sum(w)
+        denom = jnp.where(total > 0, total, 1.0)
+        s = fed_sum_scattered(mesh, stacked, weights=weights, mask=mask,
+                              comm_dtype=comm_dtype)
+        return s / denom
 
 
 def all_gather_stations(mesh: "FederationMesh", flat: jax.Array) -> jax.Array:
@@ -454,13 +457,14 @@ def fed_mean_scattered_tree(
     all-reduce, but with a bf16-narrowable reduce half); result leaves are
     float32 cast back to each leaf's dtype.
     """
-    flat = all_gather_stations(
-        mesh,
-        fed_mean_scattered(mesh, stacked, weights=weights, mask=mask,
-                           comm_dtype=comm_dtype),
-    )
-    template = jax.tree.map(lambda x: x[0], stacked)
-    return unflatten_like(template, flat)
+    with jax.named_scope("aggregate"):
+        flat = all_gather_stations(
+            mesh,
+            fed_mean_scattered(mesh, stacked, weights=weights, mask=mask,
+                               comm_dtype=comm_dtype),
+        )
+        template = jax.tree.map(lambda x: x[0], stacked)
+        return unflatten_like(template, flat)
 
 
 # --------------------------------------------------------------------------

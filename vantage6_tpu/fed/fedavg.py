@@ -47,7 +47,7 @@ from vantage6_tpu.fed.compression import (
     compress_stacked,
     record_round_telemetry,
 )
-from vantage6_tpu.runtime.profiling import observed_jit
+from vantage6_tpu.runtime.profiling import engine_call, observed_jit
 
 Pytree = Any
 # loss_fn(params, batch_x, batch_y, example_weights) -> scalar mean loss
@@ -230,13 +230,15 @@ class FedAvg:
         safe_count = jnp.maximum(count.astype(jnp.int32), 1)
 
         def sgd_step(p: Pytree, step_key: jax.Array):
-            idx = jax.random.randint(
-                step_key, (spec.batch_size,), 0, safe_count
-            )
-            bx = jnp.take(x, idx, axis=0)
-            by = jnp.take(y, idx, axis=0)
+            with jax.named_scope("gather"):
+                idx = jax.random.randint(
+                    step_key, (spec.batch_size,), 0, safe_count
+                )
+                bx = jnp.take(x, idx, axis=0)
+                by = jnp.take(y, idx, axis=0)
             w = jnp.ones((spec.batch_size,), jnp.float32)
-            loss, grads = jax.value_and_grad(spec.loss_fn)(p, bx, by, w)
+            with jax.named_scope("loss_grad"):
+                loss, grads = jax.value_and_grad(spec.loss_fn)(p, bx, by, w)
             p = jax.tree.map(lambda a, g: a - spec.local_lr * g, p, grads)
             return p, loss
 
@@ -272,14 +274,15 @@ class FedAvg:
         round_key: jax.Array,
     ):
         station_ids = jnp.arange(self.mesh.n_stations)
-        deltas, losses = self.mesh.fed_map(
-            self._local_update,
-            stacked_x,
-            stacked_y,
-            counts,
-            station_ids,
-            replicated_args=(params, round_key),
-        )
+        with jax.named_scope("local_train"):
+            deltas, losses = self.mesh.fed_map(
+                self._local_update,
+                stacked_x,
+                stacked_y,
+                counts,
+                station_ids,
+                replicated_args=(params, round_key),
+            )
         weights = counts * mask
         # Gradient compression at the delta-exchange boundary: the
         # aggregation below consumes the DECOMPRESSED per-station deltas —
@@ -290,9 +293,10 @@ class FedAvg:
         flat = None
         if self._compressing:
             server_state = opt_state["server"]
-            deltas, ef, flat = self._compress_deltas(
-                deltas, opt_state["ef"], round_key, mask
-            )
+            with jax.named_scope("compress"):
+                deltas, ef, flat = self._compress_deltas(
+                    deltas, opt_state["ef"], round_key, mask
+                )
         else:
             server_state = opt_state
         # learning-plane stats at the flat-pack seam, BEFORE the server
@@ -303,21 +307,24 @@ class FedAvg:
         # compressing, the flat matrix from the compression pass is reused.
         stats: dict[str, Any] = {}
         if self.spec.learning_stats:
-            if flat is None:
-                flat = flatten_stacked(deltas)
-            stats = station_update_stats(flat, weights=weights, ef=ef)
+            with jax.named_scope("learning_stats"):
+                if flat is None:
+                    flat = flatten_stacked(deltas)
+                stats = station_update_stats(flat, weights=weights, ef=ef)
         if self.spec.shard_server_update:
-            params, server_state = self._sharded_server_update(
-                params, server_state, deltas, weights
-            )
+            with jax.named_scope("server_update"):
+                params, server_state = self._sharded_server_update(
+                    params, server_state, deltas, weights
+                )
         else:
             mean_delta = fed_mean(deltas, weights=weights)
-            # Server update on the pseudo-gradient (negative mean delta).
-            pseudo_grad = jax.tree.map(lambda d: -d, mean_delta)
-            updates, server_state = self.server_opt.update(
-                pseudo_grad, server_state, params
-            )
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("server_update"):
+                # Server update on the pseudo-gradient (negative mean delta).
+                pseudo_grad = jax.tree.map(lambda d: -d, mean_delta)
+                updates, server_state = self.server_opt.update(
+                    pseudo_grad, server_state, params
+                )
+                params = optax.apply_updates(params, updates)
         round_loss = fed_mean(losses, weights=weights)
         new_state = (
             {"server": server_state, "ef": ef}
@@ -486,17 +493,18 @@ class FedAvg:
         ``spec.learning_stats`` is off); feed it to a
         ``runtime.learning.RoundHistory`` to arm convergence tracking and
         the anomalous-station watchdog rules."""
-        if mask is None:
-            mask = jnp.ones_like(counts)
-        params, opt_state, counts, mask, key = self._place(
-            params, opt_state, counts, mask, key
-        )
-        self._record_wire(params)
-        out = self._round(
-            params, opt_state, stacked_x, stacked_y, counts, mask, key
-        )
-        self._record_history(out[2], out[3], rounds_per_dispatch=1)
-        return out
+        with engine_call("fedavg.round", 1):
+            if mask is None:
+                mask = jnp.ones_like(counts)
+            params, opt_state, counts, mask, key = self._place(
+                params, opt_state, counts, mask, key
+            )
+            self._record_wire(params)
+            out = self._round(
+                params, opt_state, stacked_x, stacked_y, counts, mask, key
+            )
+            self._record_history(out[2], out[3], rounds_per_dispatch=1)
+            return out
 
     def async_round(
         self,
@@ -606,22 +614,23 @@ class FedAvg:
         slower than straight-line (docs/device_speed.md "K-selection").
         On the TPU the two forms have not been compared: not measured.
         """
-        if mask is None:
-            mask = jnp.ones_like(counts)
-        if opt_state is None:
-            opt_state = self.init(params)
-        params, opt_state, counts, mask, key = self._place(
-            params, opt_state, counts, mask, key
-        )
-        self._record_wire(params, n_rounds=n_rounds)
-        self._record_fused(n_rounds)
-        run = self._run_donating if donate else self._run
-        out = run(
-            params, opt_state, stacked_x, stacked_y, counts, mask, key,
-            n_rounds=n_rounds, unroll=unroll,
-        )
-        self._record_history(out[2], out[3], rounds_per_dispatch=n_rounds)
-        return out
+        with engine_call("fedavg.run_rounds", n_rounds):
+            if mask is None:
+                mask = jnp.ones_like(counts)
+            if opt_state is None:
+                opt_state = self.init(params)
+            params, opt_state, counts, mask, key = self._place(
+                params, opt_state, counts, mask, key
+            )
+            self._record_wire(params, n_rounds=n_rounds)
+            self._record_fused(n_rounds)
+            run = self._run_donating if donate else self._run
+            out = run(
+                params, opt_state, stacked_x, stacked_y, counts, mask, key,
+                n_rounds=n_rounds, unroll=unroll,
+            )
+            self._record_history(out[2], out[3], rounds_per_dispatch=n_rounds)
+            return out
 
     def run_rounds_async(
         self,
@@ -649,26 +658,27 @@ class FedAvg:
         continues into the next fused dispatch, exactly like the host
         bookkeeping it replaces."""
         spec.validate()
-        if mask is None:
-            mask = jnp.ones_like(counts)
-        if staleness is None:
-            staleness = jnp.zeros_like(counts, dtype=jnp.float32)
-        if opt_state is None:
-            opt_state = self.init(params)
-        params, opt_state, counts, mask, key = self._place(
-            params, opt_state, counts, mask, key
-        )
-        self._record_wire(params, n_rounds=n_rounds)
-        self._record_fused(n_rounds)
-        run = self._run_async_donating if donate else self._run_async
-        out = run(
-            params, opt_state, stacked_x, stacked_y, counts, mask, key,
-            accept_masks,
-            self.mesh.replicate(jnp.asarray(staleness, jnp.float32)),
-            jnp.float32(spec.staleness_discount), n_rounds=n_rounds,
-        )
-        self._record_history(out[3], out[4], rounds_per_dispatch=n_rounds)
-        return out
+        with engine_call("fedavg.run_rounds_async", n_rounds):
+            if mask is None:
+                mask = jnp.ones_like(counts)
+            if staleness is None:
+                staleness = jnp.zeros_like(counts, dtype=jnp.float32)
+            if opt_state is None:
+                opt_state = self.init(params)
+            params, opt_state, counts, mask, key = self._place(
+                params, opt_state, counts, mask, key
+            )
+            self._record_wire(params, n_rounds=n_rounds)
+            self._record_fused(n_rounds)
+            run = self._run_async_donating if donate else self._run_async
+            out = run(
+                params, opt_state, stacked_x, stacked_y, counts, mask, key,
+                accept_masks,
+                self.mesh.replicate(jnp.asarray(staleness, jnp.float32)),
+                jnp.float32(spec.staleness_discount), n_rounds=n_rounds,
+            )
+            self._record_history(out[3], out[4], rounds_per_dispatch=n_rounds)
+            return out
 
     def _record_fused(self, n_rounds: int) -> None:
         """Fused-program telemetry (host-side, metadata only): how many

@@ -1,9 +1,9 @@
-"""Structured per-round metrics + profiling hooks.
+"""Structured per-round metrics.
 
 The reference's observability is logs only (SURVEY.md §5); this adds the
 structured layer the BASELINE methodology needs: JSONL round metrics
-(rounds/sec, per-round step time, loss) and optional jax profiler traces
-(perfetto) around chosen rounds.
+(rounds/sec, per-round step time, loss). A profiler session is started by
+`runtime.profiling.profile_window` and nowhere else.
 """
 from __future__ import annotations
 
@@ -287,34 +287,6 @@ def _tolerant(obj: Any) -> Any:
     if isinstance(obj, jax.Array):
         return obj.tolist()
     return str(obj)
-
-
-@contextlib.contextmanager
-def profile_trace(log_dir: str | Path, enabled: bool = True) -> Iterator[None]:
-    """jax profiler trace (view in perfetto / tensorboard).
-
-    Wrap a round or a run_rounds call; no-op when disabled so call sites can
-    leave it in place unconditionally.
-
-    When the caller is inside a distributed trace (runtime.tracing), the
-    profiler session is recorded as a `device.profile` span carrying the
-    log dir — the join point between a federated round's trace and its
-    on-device XLA Perfetto session (same trace_id on both sides).
-    """
-    if not enabled:
-        yield
-        return
-    from vantage6_tpu.runtime.tracing import TRACER
-
-    with TRACER.span(
-        "device.profile", kind="device",
-        attrs={"log_dir": str(log_dir)}, require_parent=True,
-    ):
-        jax.profiler.start_trace(str(log_dir))
-        try:
-            yield
-        finally:
-            jax.profiler.stop_trace()
 
 
 def read_jsonl(path: str | Path) -> list[dict[str, Any]]:

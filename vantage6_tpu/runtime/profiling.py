@@ -14,7 +14,13 @@ attribution layer for everything below `jax.jit`:
   one) carrying lowering and compile wall time plus the compiled
   program's ``memory_analysis()`` (temp/argument/output bytes) and
   ``cost_analysis()`` (flops, bytes accessed), and counted in the
-  ``v6t_jit_*`` telemetry series.
+  ``v6t_jit_*`` telemetry series. Every launch is a ``device.launch``
+  span (``function``, ``n_buffers`` = the array leaves handed over) around
+  signature keying and the call into the executable, and nothing else; a
+  launch that compiles has its ``device.compile`` span as a child.
+- **Device scopes** — :data:`DEVICE_SCOPES` lists the ``jax.named_scope``
+  names the round programs open at their layer boundaries, so that a
+  device operation's metadata says which layer it belongs to.
 - **Retrace registry** — a *retrace* is the same function name compiling
   against an abstract signature it has NEVER seen. The observatory names
   the differing leaf (shape/dtype before → after) in the compile span, a
@@ -66,13 +72,54 @@ from vantage6_tpu.runtime.tracing import TRACER
 
 __all__ = [
     "DEVICE_OBS",
+    "DEVICE_SCOPES",
     "ObservedFunction",
     "ProfileBusyError",
     "RunnerCache",
+    "device_launch",
     "engine_cache_event",
+    "engine_call",
     "observed_jit",
     "profile_window",
 ]
+
+
+# The ``jax.named_scope`` names of the round programs, outermost first: every
+# device operation's metadata carries ``.../<scope>/...`` (a backward
+# operation ``transpose(jvp(<scope>))``). Metadata only: the compiled program
+# and the persistent cache's key are the same with and without them.
+# fed/fedavg.py opens local_train (with gather and loss_grad inside it),
+# compress, learning_stats and server_update; fed/collectives.py aggregate;
+# workloads/fed_transformer.py local_train (with embed, attention, mlp and
+# lm_head_loss inside it) and server_update.
+DEVICE_SCOPES = (
+    "local_train", "gather", "loss_grad", "embed", "attention", "mlp",
+    "lm_head_loss", "compress", "learning_stats", "aggregate",
+    "server_update",
+)
+
+
+def engine_call(engine: str, rounds: int):
+    """The ``engine.call`` span: the whole host side of one call into a
+    round engine (``fedavg.run_rounds``, ``fed_transformer.round``, ...),
+    entry to return; the program is then enqueued, not done. It roots a
+    trace when the caller is in none and joins the caller's otherwise. Its
+    self time, less the ``device.launch`` under it, is the engine's own host
+    work: placement, telemetry, history."""
+    return TRACER.span(
+        "engine.call", kind="engine",
+        attrs={"engine": engine, "rounds": rounds},
+    )
+
+
+def device_launch(function: str, n_buffers: int):
+    """The ``device.launch`` span: the call into a compiled program and
+    nothing else. ``n_buffers`` is the array leaves of the dynamic
+    arguments, counted as arrays, not as per-chip shards."""
+    return TRACER.span(
+        "device.launch", kind="device",
+        attrs={"function": function, "n_buffers": n_buffers},
+    )
 
 
 class _LeafSig(NamedTuple):
@@ -294,24 +341,25 @@ class ObservedFunction:
             # called inside an outer trace: inline like any jitted fn —
             # the OUTER entry point owns this compile's attribution
             return self._jit(*args, **kwargs)
-        avals = tuple(_abstractify(leaf) for leaf in leaves)
-        try:
-            key = (avals, treedef, statics)
-            hash(key)
-        except TypeError:
-            # unhashable static (a list-valued kwarg, ...): nothing to
-            # key on — forward to jit, which raises its own error
-            self.fallbacks += 1
-            REGISTRY.counter("v6t_jit_fallbacks_total").inc()
-            return self._jit(*args, **kwargs)
-        self.dispatches += 1
-        REGISTRY.counter("v6t_jit_dispatches_total").inc()
-        with self._lock:
-            compiled = self._sigs.get(key)
-        if compiled is None:
-            compiled = self._compile(key, args, kwargs, avals, dyn_args,
-                                     dyn_kwargs)
-        return compiled(*dyn_args, **dyn_kwargs)
+        with device_launch(self.name, len(leaves)):
+            avals = tuple(_abstractify(leaf) for leaf in leaves)
+            try:
+                key = (avals, treedef, statics)
+                hash(key)
+            except TypeError:
+                # unhashable static (a list-valued kwarg, ...): nothing to
+                # key on — forward to jit, which raises its own error
+                self.fallbacks += 1
+                REGISTRY.counter("v6t_jit_fallbacks_total").inc()
+                return self._jit(*args, **kwargs)
+            self.dispatches += 1
+            REGISTRY.counter("v6t_jit_dispatches_total").inc()
+            with self._lock:
+                compiled = self._sigs.get(key)
+            if compiled is None:
+                compiled = self._compile(key, args, kwargs, avals, dyn_args,
+                                         dyn_kwargs)
+            return compiled(*dyn_args, **dyn_kwargs)
 
     def _compile(
         self, key: tuple, args: tuple, kwargs: dict, avals: tuple,

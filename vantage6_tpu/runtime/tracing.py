@@ -25,10 +25,13 @@ latency went. This module is that attribution layer:
   reads as one timeline; `summarize` is the per-hop p50/p95 table behind
   `tools/trace_view.py`.
 
-Device work links in through `runtime.metrics.profile_trace`, which
-records a `device.profile` span carrying the jax-profiler log dir, so a
-Perfetto session of XLA execution is joinable to its federated trace by
-trace_id.
+- **One clock with the device**: in a process that has loaded jax, every
+  recorded span is also a `jax.profiler.TraceAnnotation` of the same name
+  around the same body, carrying the `span_id`. In any profiler session
+  (`runtime.profiling.profile_window`, a benchmark's traced run) the
+  program's spans therefore lie in the host planes on the clock of the
+  device's operations, and join this module's records by `span_id`. With
+  no session open an annotation is a flag test.
 """
 from __future__ import annotations
 
@@ -38,6 +41,7 @@ import os
 import random
 import re
 import secrets
+import sys
 import threading
 import time
 from collections import deque
@@ -176,6 +180,13 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 _UNSET = object()
+
+
+def _profiler_annotation() -> Any:
+    """`jax.profiler.TraceAnnotation` where this process has loaded jax,
+    else None. Looked up, never imported: client and server processes have
+    no use for jax, and a profiler session can only be open where jax is."""
+    return getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
 
 
 class Tracer:
@@ -348,6 +359,11 @@ class Tracer:
         )
         if attrs:
             sp.attrs.update(attrs)
+        annotation = _profiler_annotation()
+        mark = None
+        if annotation is not None:
+            mark = annotation(name, span_id=span_id)
+            mark.__enter__()
         t0 = time.perf_counter()
         try:
             yield sp
@@ -356,6 +372,8 @@ class Tracer:
             raise
         finally:
             sp.dur = time.perf_counter() - t0
+            if mark is not None:
+                mark.__exit__(None, None, None)
             stack.pop()
             self._record(sp)
 

@@ -32,6 +32,7 @@ from vantage6_tpu.ops.flash_attention import (
     recompute_attention,
 )
 from vantage6_tpu.parallel.ring_attention import ring_attention
+from vantage6_tpu.runtime.profiling import device_launch, engine_call
 
 SEQ_AXIS = "device"  # sequence parallelism rides the within-station axis
 
@@ -117,10 +118,11 @@ def forward_local(
     def cast(w: jax.Array) -> jax.Array:
         return w.astype(cfg.dtype)
 
-    x = cast(params["embed"])[tokens_local]
-    x = x + cast(
-        lax.dynamic_slice_in_dim(params["pos"], offset, t_local, 0)
-    )[None]
+    with jax.named_scope("embed"):
+        x = cast(params["embed"])[tokens_local]
+        x = x + cast(
+            lax.dynamic_slice_in_dim(params["pos"], offset, t_local, 0)
+        )[None]
 
     def layer_block(x, layer):
         layer = jax.tree.map(cast, layer)
@@ -142,26 +144,30 @@ def forward_local(
                 {"interpret": cfg.flash_interpret}
                 if cfg.attention == "flash" else {}
             )
-            attn = impl(
-                q.transpose(0, 2, 1, 3),
-                k.transpose(0, 2, 1, 3),
-                v.transpose(0, 2, 1, 3),
-                q_offset=offset,
-                k_offset=offset,
-                causal=True,
-                **kw,
-            ).transpose(0, 2, 1, 3)
+            with jax.named_scope("attention"):
+                attn = impl(
+                    q.transpose(0, 2, 1, 3),
+                    k.transpose(0, 2, 1, 3),
+                    v.transpose(0, 2, 1, 3),
+                    q_offset=offset,
+                    k_offset=offset,
+                    causal=True,
+                    **kw,
+                ).transpose(0, 2, 1, 3)
         else:
-            attn = ring_attention(q, k, v, axis_name, causal=True)
+            with jax.named_scope("attention"):
+                attn = ring_attention(q, k, v, axis_name, causal=True)
         x = x + attn.reshape(b, t_local, cfg.d_model) @ layer["proj"]
-        h = _ln(x)
-        return x + jax.nn.gelu(h @ layer["w_up"]) @ layer["w_down"]
+        with jax.named_scope("mlp"):
+            h = _ln(x)
+            return x + jax.nn.gelu(h @ layer["w_up"]) @ layer["w_down"]
 
     if cfg.remat:
         layer_block = jax.checkpoint(layer_block)
     for layer in params["layers"]:
         x = layer_block(x, layer)
-    return _ln(x) @ cast(params["embed"]).T
+    with jax.named_scope("lm_head_loss"):
+        return _ln(x) @ cast(params["embed"]).T
 
 
 def loss_local(
@@ -177,11 +183,12 @@ def loss_local(
     predictions per shard — negligible at scale, exact bookkeeping here).
     """
     logits = forward_local(params, tokens_local, cfg, axis_name)
-    targets = tokens_local[:, 1:]
-    logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    local_sum = jnp.sum(nll)
-    local_cnt = jnp.asarray(nll.size, jnp.float32)
+    with jax.named_scope("lm_head_loss"):
+        targets = tokens_local[:, 1:]
+        logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        local_sum = jnp.sum(nll)
+        local_cnt = jnp.asarray(nll.size, jnp.float32)
     total = lax.psum(local_sum, axis_name)
     count = lax.psum(local_cnt, axis_name)
     return total / count
@@ -215,7 +222,6 @@ class FedTransformer:
         sh = NamedSharding(self.mesh, P(STATION_AXIS, None, SEQ_AXIS))
         return jax.device_put(jnp.asarray(tokens), sh)
 
-    @partial(jax.jit, static_argnums=0)
     def round(
         self,
         params: Any,
@@ -223,8 +229,23 @@ class FedTransformer:
         tokens: jax.Array,  # [S, B, T] sharded (station, None, device)
         mask: jax.Array,  # [S] participation
     ) -> tuple[Any, Any, jax.Array]:
-        """One federated round: per-station grads (sp inside), FedAvg, step."""
+        """One federated round: per-station grads (sp inside), FedAvg, step.
 
+        Recorded as an ``engine.call`` span over the whole host side of the
+        call and, under it, a ``device.launch`` span around the call into
+        the jitted program and nothing else (``n_buffers`` = the array
+        leaves handed over)."""
+        args = (params, opt_state, tokens, mask)
+        with engine_call("fed_transformer.round", 1):
+            n_buffers = len(jax.tree.leaves(args))
+            with device_launch("fed_transformer.round", n_buffers):
+                return self._round(*args)
+
+    @partial(jax.jit, static_argnums=0)
+    def _round(
+        self, params: Any, opt_state: Any, tokens: jax.Array,
+        mask: jax.Array,
+    ) -> tuple[Any, Any, jax.Array]:
         def station_body(params, tokens_block):
             # tokens_block: [S/D_s, B, T/P] — the inner vmap walks the
             # stations PACKED into this mesh slot (stations_per_slot > 1
@@ -239,7 +260,8 @@ class FedTransformer:
                 loss = lax.pmean(loss, SEQ_AXIS)
                 return loss, grads
 
-            return jax.vmap(one_station)(tokens_block)
+            with jax.named_scope("local_train"):
+                return jax.vmap(one_station)(tokens_block)
 
         # Variance checking OFF, same stance (and reason) as fed_map: the
         # station body is a purely local program whose only cross-device
@@ -256,8 +278,11 @@ class FedTransformer:
         )(params, tokens)
         # explicit cross-station aggregation: the ONLY place station data mixes
         g_mean = collectives.fed_mean(grads, mask=mask)
-        updates, opt_state = self.optimizer.update(g_mean, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("server_update"):
+            updates, opt_state = self.optimizer.update(
+                g_mean, opt_state, params
+            )
+            params = optax.apply_updates(params, updates)
         loss = collectives.fed_mean(losses, mask=mask)
         return params, opt_state, loss
 
