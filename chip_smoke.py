@@ -65,6 +65,7 @@ TOL = {
     "flash_fwd_abs": 5e-2,        # |kernel - f32 reference|, outputs O(1)
     "flash_grad_rel": 5e-2,       # max |g - g_ref| / max |g_ref|
     "fused_vs_rounds_abs": 1e-2,  # run_rounds(K) vs K x round(), params
+    "packed_vs_separate_abs": 1e-2,  # one gather vs two, several devices
     "scattered_vs_replicated_abs": 1e-2,
     "bf16_vs_replicated_abs": 5e-2,
     "one_chip_vs_replicated_abs": 1e-2,
@@ -203,6 +204,7 @@ def _cnn_keys():
 
 
 def phase_fedavg_cnn(sz, meter, shared) -> dict[str, Any]:
+    import bench
     import jax
     import numpy as np
     from vantage6_tpu.common.telemetry import REGISTRY
@@ -232,6 +234,23 @@ def phase_fedavg_cnn(sz, meter, shared) -> dict[str, Any]:
     diff = _max_abs_diff(p1, p_seq)
     loss_diff = float(np.max(np.abs(np.asarray(losses1) - seq_losses)))
     shared["cnn_replicated"] = jax.device_get(p1)  # before p1 is donated
+
+    # which gather the engine built (one over rows that carry their label,
+    # or the two of x and y), and the other one beside it: a second engine
+    # steered to the separate path here, since the program has no option
+    gather_path = engine.gather_path(sx, sy)
+    separate = W.make_engine(
+        mesh, local_steps=sz["cnn"]["local_steps"],
+        batch_size=sz["cnn"]["batch"], local_lr=bench.LR,
+    )
+    separate.gather_path = lambda x, y: "separate"
+    p_sep, _, losses_sep, _ = separate.run_rounds(
+        W.init_params(pkey), sx, sy, counts, rkey, k
+    )
+    gather_diff = _max_abs_diff(p1, p_sep)
+    gather_loss_diff = float(
+        np.max(np.abs(np.asarray(losses1) - np.asarray(losses_sep)))
+    )
 
     # ... and K again from the returned state: the steady state
     run = engine._run_donating
@@ -263,6 +282,16 @@ def phase_fedavg_cnn(sz, meter, shared) -> dict[str, Any]:
         "fused_matches_rounds":
             diff <= TOL["fused_vs_rounds_abs"]
             and loss_diff <= TOL["fused_vs_rounds_abs"],
+        "fused_matches_rounds_bit_for_bit": diff == 0.0 and loss_diff == 0.0,
+        "packed_gather_built": gather_path == "packed",
+        # the two programs feed loss_fn the same bits; on one chip they came
+        # out identical, on four one ulp apart after one round (3e-8) and
+        # 1.1e-3 after five (PERF.md section 6, PR 27): exact on one device,
+        # a tolerance across devices
+        "packed_matches_separate":
+            max(gather_diff, gather_loss_diff) <= (
+                0.0 if len(jax.devices()) == 1
+                else TOL["packed_vs_separate_abs"]),
         "one_compile_of_fused_program": st["compiles"] == 1,
         "no_compile_after_first_dispatch": after == before,
         "no_retrace":
@@ -287,6 +316,9 @@ def phase_fedavg_cnn(sz, meter, shared) -> dict[str, Any]:
         "accuracy": round(acc, 4),
         "fused_vs_rounds_max_abs_diff": diff,
         "fused_vs_rounds_loss_max_abs_diff": loss_diff,
+        "gather": gather_path,
+        "packed_vs_separate_max_abs_diff": gather_diff,
+        "packed_vs_separate_loss_max_abs_diff": gather_loss_diff,
     }
 
 

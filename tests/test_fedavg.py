@@ -1,5 +1,6 @@
 """FedAvg engine + flagship workload on the fake pod."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -93,3 +94,152 @@ def test_reference_shaped_central_fedavg():
     )
     (res,) = client.result.get(task["id"])
     assert res["losses"][-1] < res["losses"][0]
+
+
+# ----------------------------------------------- one row gather per local step
+# (ISSUE 27) A local step fetches its minibatch with ONE gather over a table
+# whose rows carry their label, built once per dispatch; where x's and y's
+# elements differ in width the two gathers stay. `gather_path` reads shapes
+# and dtypes; a test steers it on the engine it built, the program has no
+# option for it.
+def _nll(p, x, y, w):
+    z = x @ p["w"] + p["b"]
+    return jnp.sum(w * (jnp.logaddexp(0.0, z) - y * z)) / jnp.sum(w)
+
+
+def _logreg(mesh, y_dtype=jnp.float32, counts=None, pad_value=None):
+    """(engine, params, x, y, counts): 8 stations x 16 rows x 5 features;
+    rows at or beyond a station's count hold ``pad_value``."""
+    from vantage6_tpu.fed.fedavg import FedAvg, FedAvgSpec
+
+    engine = FedAvg(mesh, FedAvgSpec(loss_fn=_nll, local_steps=3,
+                                     batch_size=8, local_lr=0.1))
+    rng = np.random.default_rng(27)
+    x = rng.normal(size=(8, 16, 5)).astype(np.float32)
+    y = rng.integers(0, 2, (8, 16)).astype(np.float32)
+    counts = np.full(8, 16.0, np.float32) if counts is None else counts
+    if pad_value is not None:
+        padded = np.arange(16)[None, :] >= counts[:, None]
+        x[padded] = pad_value
+        y[padded] = pad_value
+    params = {"w": jnp.full((5,), 0.01), "b": jnp.zeros(())}
+    return (engine, params, mesh.shard_stacked(jnp.asarray(x)),
+            mesh.shard_stacked(jnp.asarray(y, y_dtype)), jnp.asarray(counts))
+
+
+def _cnn(mesh, fed_data):
+    sx, sy, counts = fed_data
+    assert sy.dtype == jnp.int32 and sx.dtype == jnp.float32
+    engine = W.make_engine(mesh, local_steps=2, batch_size=16, local_lr=0.1)
+    return engine, W.init_params(jax.random.key(4)), sx, sy, counts
+
+
+def _three_rounds(engine, call, params, x, y, counts):
+    """(params, losses, stats) after 3 rounds from ``params``, by one fused
+    dispatch or by three `round()` calls over the same keys."""
+    key = jax.random.key(9)
+    if call == "run_rounds":
+        p, _, losses, stats = engine.run_rounds(
+            params, x, y, counts, key, 3, donate=False)
+        return jax.device_get((p, losses, stats))
+    p, state, out = params, engine.init(params), []
+    for k in jax.random.split(key, 3):
+        p, state, loss, stats = engine.round(p, state, x, y, counts, k)
+        out.append((loss, stats))
+    return jax.device_get((p, [o[0] for o in out], [o[1] for o in out]))
+
+
+def _same_bits(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb) and la
+    for u, v in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(u), np.asarray(v))
+
+
+def _separate(engine, monkeypatch):
+    monkeypatch.setattr(engine, "gather_path", lambda x, y: "separate")
+    return engine
+
+
+@pytest.mark.parametrize("call", ["run_rounds", "round"])
+@pytest.mark.parametrize("model", ["logreg-f32-labels", "cnn-int32-labels"])
+def test_packed_gather_is_bit_for_bit_the_two_gathers(
+        mesh, fed_data, monkeypatch, model, call):
+    def build():
+        return (_logreg(mesh) if model.startswith("logreg")
+                else _cnn(mesh, fed_data))
+
+    engine, *args = build()
+    assert engine.gather_path(args[1], args[2]) == "packed"
+    packed = _three_rounds(engine, call, *args)
+    engine, *args = build()
+    separate = _three_rounds(_separate(engine, monkeypatch), call, *args)
+    _same_bits(packed, separate)
+    assert np.all(np.isfinite(np.asarray(packed[1])))
+
+
+@pytest.mark.parametrize("y_dtype", [jnp.int8, jnp.float16, jnp.bool_])
+def test_labels_of_another_width_keep_the_two_gathers(mesh, y_dtype):
+    """int8 / float16 / bool labels beside float32 features cannot ride in
+    a float32 column: the separate path, and the result it gives today (the
+    loss promotes a 0/1 label of any type to the same float32)."""
+    engine, params, x, y, counts = _logreg(mesh, y_dtype=y_dtype)
+    assert engine.gather_path(x, y) == "separate"
+    lowered = _lower_run(engine, params, x, y, counts)
+    assert lowered.as_text().count('"stablehlo.gather"') == 2
+    assert "tensor<8x16x6x" not in lowered.as_text()  # no table of 5 + 1
+    narrow = _three_rounds(engine, "run_rounds", params, x, y, counts)
+    engine, params, x, y, counts = _logreg(mesh)
+    _same_bits(narrow, _three_rounds(engine, "run_rounds", params, x, y,
+                                     counts))
+
+
+def _lower_run(engine, params, x, y, counts, n_rounds=3):
+    p, state, counts, mask, key = engine._place(
+        params, engine.init(params), counts, jnp.ones(8), jax.random.key(1))
+    return engine._run.lower(p, state, x, y, counts, mask, key,
+                             n_rounds=n_rounds, unroll=1)
+
+
+@pytest.mark.parametrize("n_rounds", [1, 3, 5])
+def test_one_gather_in_the_local_step_and_the_pack_outside_the_rounds(
+        mesh, n_rounds):
+    engine, params, x, y, counts = _logreg(mesh)
+    text = _lower_run(engine, params, x, y, counts, n_rounds).as_text()
+    # one gather in the whole program: the local step's, over rows of 5 + 1
+    assert text.count('"stablehlo.gather"') == 1
+    assert "tensor<1x16x6xui32>" in text.split('"stablehlo.gather"')[1]
+    # the pack: once, in the entry function, before the loop over rounds
+    main = text.split("func.func private")[0]
+    join = "(tensor<8x16x5xui32>, tensor<8x16x1xui32>) -> tensor<8x16x6xui32>"
+    assert text.count(join) == 1
+    assert main.index(join) < main.index("stablehlo.while")
+
+
+@pytest.mark.parametrize("path", ["packed", "separate"])
+def test_no_padded_row_is_ever_drawn(mesh, monkeypatch, path):
+    """Ragged stations: rows at or beyond a station's count are padding.
+    They hold NaN here, so one draw of one of them would poison the round;
+    and the result does not depend on what they hold."""
+    counts = np.array([16, 5, 1, 9, 16, 2, 12, 7], np.float32)
+    results = []
+    for pad_value in (np.nan, 1e6):
+        engine, *args = _logreg(mesh, counts=counts, pad_value=pad_value)
+        if path == "separate":
+            _separate(engine, monkeypatch)
+        results.append(_three_rounds(engine, "run_rounds", *args))
+    for leaf in jax.tree.leaves(results[0]):
+        assert np.all(np.isfinite(leaf))
+    _same_bits(*results)
+
+
+def test_the_packed_table_must_fit_beside_the_table(mesh, monkeypatch):
+    """Where the backend reports its memory, table and packed copy together
+    may take half of a device's; the CPU reports none and always packs."""
+    engine, _, x, y, _ = _logreg(mesh)
+    assert engine._bytes_limit is None
+    per_device = 4 * (x.size + y.size) // mesh.station_axis_size
+    monkeypatch.setattr(engine, "_bytes_limit", 4 * per_device)
+    assert engine.gather_path(x, y) == "packed"
+    monkeypatch.setattr(engine, "_bytes_limit", 4 * per_device - 2)
+    assert engine.gather_path(x, y) == "separate"

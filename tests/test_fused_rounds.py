@@ -5,7 +5,7 @@ whole K-round FedAvg loop as ONE device program (lax.scan over rounds,
 zero host round-trips) and must be fp32-IDENTICAL to K sequential
 `round()` dispatches over the same split key stream — dense, compressed
 (error-feedback carry), scattered ZeRO-1 and masked/async variants alike.
-Bitwise, not allclose: the fused body is the very `_round_impl` the
+Bitwise, not allclose: the fused body is the very `_one_round` the
 per-round path jits, so ANY drift is a real seam leak (mask plumbing, EF
 carry, staleness bookkeeping), never fp noise.
 

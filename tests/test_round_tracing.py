@@ -53,15 +53,14 @@ def _nll(p, x, y, w):
     return jnp.sum(w * (jnp.logaddexp(0.0, z) - y * z)) / jnp.sum(w)
 
 
-def _fedavg(**spec):
+def _fedavg(y_dtype=jnp.float32, **spec):
     mesh = FederationMesh(8)
     engine = FedAvg(mesh, FedAvgSpec(
         loss_fn=_nll, local_steps=2, batch_size=8, **spec))
     rng = np.random.default_rng(0)
     x = mesh.shard_stacked(jnp.asarray(rng.normal(size=(8, 16, 5)),
                                        jnp.float32))
-    y = mesh.shard_stacked(jnp.asarray(rng.integers(0, 2, (8, 16)),
-                                       jnp.float32))
+    y = mesh.shard_stacked(jnp.asarray(rng.integers(0, 2, (8, 16)), y_dtype))
     params = {"w": jnp.zeros(5), "b": jnp.zeros(())}
     return engine, params, (x, y, jnp.full((8,), 16.0))
 
@@ -78,8 +77,8 @@ def _fedavg_lowered(engine, params, data):
 # ------------------------------------------------------------------ scopes
 TRANSFORMER_SCOPES = {"local_train", "embed", "attention", "mlp",
                       "lm_head_loss", "aggregate", "server_update"}
-FEDAVG_SCOPES = {"local_train", "gather", "loss_grad", "learning_stats",
-                 "aggregate", "server_update"}
+FEDAVG_SCOPES = {"pack_table", "local_train", "gather", "loss_grad",
+                 "learning_stats", "aggregate", "server_update"}
 PROGRAMS = {
     "transformer-recompute": (lambda: _transformer("recompute"),
                               TRANSFORMER_SCOPES),
@@ -117,6 +116,24 @@ def test_the_compiled_operations_carry_each_scope_name(program):
     assert _scopes_in(_lowered(build())) == expected
 
 
+def test_the_pack_lies_outside_local_train_and_the_gather_inside_it():
+    """`pack_table` is paid once per dispatch, `gather` once per local
+    step: an operation's path holds one of them, never both, and only the
+    gather's lies under `local_train`."""
+    names = re.findall(r'op_name="([^"]*)"',
+                       _lowered(PROGRAMS["fedavg-fused"][0]()).compile().as_text())
+    packs = [n for n in names if "/pack_table/" in n]
+    gathers = [n for n in names if re.search(r"/gather/.*gather", n)]
+    assert packs and gathers
+    assert not [n for n in packs if "local_train" in n or "while" in n]
+    assert all("/local_train/" in n and "/while/" in n for n in gathers)
+
+
+def test_with_labels_of_another_width_no_pack_is_on_the_device():
+    lowered = _fedavg_lowered(*_fedavg(y_dtype=jnp.int8))
+    assert _scopes_in(lowered) == FEDAVG_SCOPES - {"pack_table"}
+
+
 def test_every_scope_of_the_tuple_is_opened_by_some_program():
     assert set().union(*(s for _, s in PROGRAMS.values())) == set(
         DEVICE_SCOPES)
@@ -144,15 +161,15 @@ def _call_transformer():
     return out, "fed_transformer.round", 1, len(jax.tree.leaves(args))
 
 
-def _call_run_rounds():
-    engine, params, (x, y, counts) = _fedavg()
+def _call_run_rounds(**kw):
+    engine, params, (x, y, counts) = _fedavg(**kw)
     out = engine.run_rounds(params, x, y, counts, jax.random.key(1), 3)
     # params, the empty sgd state, x, y, counts, mask, key
     return out, "fedavg.run_rounds", 3, len(jax.tree.leaves(params)) + 5
 
 
-def _call_fedavg_round():
-    engine, params, (x, y, counts) = _fedavg()
+def _call_fedavg_round(**kw):
+    engine, params, (x, y, counts) = _fedavg(**kw)
     state = engine.init(params)
     out = engine.round(params, state, x, y, counts, jax.random.key(1))
     return out, "fedavg.round", 1, len(jax.tree.leaves(params)) + 5
@@ -161,6 +178,10 @@ def _call_fedavg_round():
 ENGINES = {"fed_transformer.round": _call_transformer,
            "fedavg.run_rounds": _call_run_rounds,
            "fedavg.round": _call_fedavg_round}
+# what an engine says of the program it launches, beside engine and rounds
+ENGINE_ATTRS = {"fed_transformer.round": {},
+                "fedavg.run_rounds": {"gather": "packed"},
+                "fedavg.round": {"gather": "packed"}}
 
 
 def _named(spans, name):
@@ -178,7 +199,8 @@ def test_one_engine_call_with_one_launch_under_it(engine, caller):
     spans = TRACER.drain()
     (call,) = _named(spans, "engine.call")
     assert call["kind"] == "engine"
-    assert call["attrs"] == {"engine": name, "rounds": rounds}
+    assert call["attrs"] == {"engine": name, "rounds": rounds,
+                             **ENGINE_ATTRS[engine]}
     (launch,) = [s for s in _named(spans, "device.launch")
                  if s["parent_id"] == call["span_id"]]
     assert launch["kind"] == "device"
@@ -191,6 +213,18 @@ def test_one_engine_call_with_one_launch_under_it(engine, caller):
         assert call["trace_id"] == outer.context.trace_id
     else:
         assert call["parent_id"] is None
+
+
+@pytest.mark.parametrize("y_dtype,path", [(jnp.float32, "packed"),
+                                          (jnp.int8, "separate")])
+@pytest.mark.parametrize("engine", ["fedavg.run_rounds", "fedavg.round"])
+def test_the_engine_call_says_which_gather_its_program_was_built_with(
+        engine, y_dtype, path):
+    """The counter that the packed gather engaged: a string on the span
+    that exists, the same rule the traced program read."""
+    ENGINES[engine](y_dtype=y_dtype)
+    (call,) = _named(TRACER.drain(), "engine.call")
+    assert call["attrs"]["gather"] == path
 
 
 def test_a_launch_that_compiles_has_the_compile_under_it():

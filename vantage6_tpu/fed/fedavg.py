@@ -21,6 +21,8 @@ Semantics kept from the reference world:
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Any, Callable
 
 import jax
@@ -52,6 +54,24 @@ from vantage6_tpu.runtime.profiling import engine_call, observed_jit
 Pytree = Any
 # loss_fn(params, batch_x, batch_y, example_weights) -> scalar mean loss
 LossFn = Callable[[Pytree, jax.Array, jax.Array, jax.Array], jax.Array]
+
+
+def _viewable(dtype: Any) -> bool:
+    """An element type whose bits ``lax.bitcast_convert_type`` carries into
+    another of its width and back: integers and floats, not bool."""
+    return jnp.issubdtype(dtype, jnp.integer) or jnp.issubdtype(
+        dtype, jnp.floating
+    )
+
+
+def _device_bytes_limit(device: Any) -> int | None:
+    """The device's memory as its backend reports it, or None (the CPU
+    backend reports nothing; a described device has no client to ask)."""
+    try:
+        stats = device.memory_stats()
+    except Exception:
+        return None
+    return (stats or {}).get("bytes_limit")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -162,6 +182,8 @@ class FedAvg:
             spec.compressor is not None and not spec.compressor.identity
         )
         self.server_opt = spec.server_optimizer or optax.sgd(1.0)
+        # what gather_path() weighs the packed table against
+        self._bytes_limit = _device_bytes_limit(mesh.mesh.devices.flat[0])
         # optional learning-plane sink (attach_history): when set, every
         # round()/run_rounds() host-records its stats into it
         self.history: Any = None
@@ -213,17 +235,93 @@ class FedAvg:
         )
 
     # ------------------------------------------------------------ local step
+    def gather_path(self, stacked_x: Any, stacked_y: Any) -> str:
+        """How a local step fetches its minibatch from these tables:
+        ``"packed"`` (one gather per step over rows that carry their label)
+        or ``"separate"`` (a gather of ``x`` and one of ``y``). Read from
+        what the program sees when it is traced, and from nothing else:
+        shapes, dtypes and, where the backend reports one, the device's
+        ``bytes_limit``. The packed table is a second copy of the data for
+        the length of a dispatch, so it is built only where table and copy
+        together take at most half of a device's memory (the other half is
+        the step's); and only where ``y``'s elements are as wide as
+        ``x``'s, so that labels and features ride side by side as unsigned
+        integers of that width, bit for bit. The gather costs per index,
+        not per byte (docs/device_speed.md "One gather per local step")."""
+        xd, yd = jnp.dtype(stacked_x.dtype), jnp.dtype(stacked_y.dtype)
+        if not (_viewable(xd) and _viewable(yd) and xd.itemsize == yd.itemsize):
+            return "separate"
+        if self._bytes_limit is not None:
+            # a device's share of the table, and as much again for the copy
+            table = xd.itemsize * (stacked_x.size + stacked_y.size)
+            if 2 * (table // self.mesh.station_axis_size) > self._bytes_limit // 2:
+                return "separate"
+        return "packed"
+
+    def _minibatch_source(
+        self, stacked_x: jax.Array, stacked_y: jax.Array
+    ) -> tuple[tuple[jax.Array, ...], Callable[..., tuple[jax.Array, jax.Array]]]:
+        """``(tables, take)``: the stacked ``[S, n_pad, ...]`` arrays a
+        local step gathers from, and ``take(*station_tables, idx) -> (bx,
+        by)`` for one station's share of them. Packed: one table ``[S,
+        n_pad, width + label_width]`` built here (once per dispatch: the
+        callers stand outside the scan over rounds) and one gather, whose
+        rows are split and viewed back. The values ``loss_fn`` receives are
+        bit for bit those of the two gathers."""
+        if self.gather_path(stacked_x, stacked_y) == "separate":
+            return (stacked_x, stacked_y), lambda x, y, idx: (
+                jnp.take(x, idx, axis=0), jnp.take(y, idx, axis=0)
+            )
+        x_row, y_row = stacked_x.shape[2:], stacked_y.shape[2:]
+        x_dtype, y_dtype = stacked_x.dtype, stacked_y.dtype
+        # Features and labels ride as unsigned integers of their width:
+        # nothing on the way rounds, flushes or canonicalises an integer.
+        # (Float columns do not keep an int32 label on the TPU: it joins
+        # columns with a float maximum, and labels 0..9, denormals when
+        # viewed as float32, came back as 0.)
+        carrier = jnp.dtype(f"uint{8 * x_dtype.itemsize}")
+        s, n_pad = stacked_x.shape[:2]
+        width, label_width = math.prod(x_row), math.prod(y_row)
+        with jax.named_scope("pack_table"):
+            table = jnp.concatenate([
+                jax.lax.bitcast_convert_type(a, carrier).reshape(s, n_pad, -1)
+                for a in (stacked_x, stacked_y)
+            ], axis=-1)
+
+        def take(rows: jax.Array, idx: jax.Array):
+            # idx < safe_count <= n_pad by construction: no row can be out
+            # of range, so the gather is told not to guard against it
+            batch = jnp.take(rows, idx, axis=0, mode="clip")
+            if label_width == 1:
+                # the one label column read as a reduction over the row,
+                # a pass like the loss's own (0.7 ms a step on the v5e):
+                # sliced off, a column one element wide is first spread
+                # over the lanes and then copied together (2.3 ms)
+                lane = jax.lax.broadcasted_iota(jnp.int32, batch.shape, 1)
+                by = jnp.max(jnp.where(lane == width, batch, 0), axis=1)
+            else:
+                by = batch[:, width:]
+            return (
+                jax.lax.bitcast_convert_type(batch[:, :width], x_dtype)
+                .reshape(-1, *x_row),
+                jax.lax.bitcast_convert_type(by, y_dtype).reshape(-1, *y_row),
+            )
+
+        return (table,), take
+
     def _local_update(
         self,
-        x: jax.Array,          # [n_pad, ...] this station's (padded) examples
-        y: jax.Array,          # [n_pad, ...]
+        tables: tuple[jax.Array, ...],  # this station's [n_pad, ...] share
         count: jax.Array,      # [] true example count
         station_id: jax.Array, # [] index for per-station RNG
         params: Pytree,        # replicated global model
         round_key: jax.Array,  # replicated per-round RNG key
+        *,
+        take: Callable[..., tuple[jax.Array, jax.Array]],
     ) -> tuple[Pytree, jax.Array]:
         """`local_steps` of minibatch SGD from the global params; returns
-        (delta, mean loss). Runs per-station inside fed_map."""
+        (delta, mean loss). Runs per-station inside fed_map, on the tables
+        and the ``take`` of ``_minibatch_source``."""
         spec = self.spec
         key = jax.random.fold_in(round_key, station_id)
         # Sampling bound: padded rows are never drawn because idx < count.
@@ -234,8 +332,7 @@ class FedAvg:
                 idx = jax.random.randint(
                     step_key, (spec.batch_size,), 0, safe_count
                 )
-                bx = jnp.take(x, idx, axis=0)
-                by = jnp.take(y, idx, axis=0)
+                bx, by = take(*tables, idx)
             w = jnp.ones((spec.batch_size,), jnp.float32)
             with jax.named_scope("loss_grad"):
                 loss, grads = jax.value_and_grad(spec.loss_fn)(p, bx, by, w)
@@ -273,12 +370,27 @@ class FedAvg:
         mask: jax.Array,        # [S] participation (1.0 = in this round)
         round_key: jax.Array,
     ):
+        """``round()``'s program: the minibatch source, then one round."""
+        tables, take = self._minibatch_source(stacked_x, stacked_y)
+        return self._one_round(
+            params, opt_state, tables, take, counts, mask, round_key
+        )
+
+    def _one_round(
+        self,
+        params: Pytree,
+        opt_state: Any,
+        tables: tuple[jax.Array, ...],  # of _minibatch_source, with its take
+        take: Callable[..., tuple[jax.Array, jax.Array]],
+        counts: jax.Array,
+        mask: jax.Array,
+        round_key: jax.Array,
+    ):
         station_ids = jnp.arange(self.mesh.n_stations)
         with jax.named_scope("local_train"):
             deltas, losses = self.mesh.fed_map(
-                self._local_update,
-                stacked_x,
-                stacked_y,
+                functools.partial(self._local_update, take=take),
+                tables,
                 counts,
                 station_ids,
                 replicated_args=(params, round_key),
@@ -493,7 +605,9 @@ class FedAvg:
         ``spec.learning_stats`` is off); feed it to a
         ``runtime.learning.RoundHistory`` to arm convergence tracking and
         the anomalous-station watchdog rules."""
-        with engine_call("fedavg.round", 1):
+        with engine_call(
+            "fedavg.round", 1, gather=self.gather_path(stacked_x, stacked_y)
+        ):
             if mask is None:
                 mask = jnp.ones_like(counts)
             params, opt_state, counts, mask, key = self._place(
@@ -527,7 +641,7 @@ class FedAvg:
         Implemented entirely at the participation-mask seam — the
         effective mask is ``mask * accept_mask * discount`` and feeds the
         SAME jitted round program as :meth:`round` (``weights = counts *
-        mask`` inside ``_round_impl``), so nothing retraces and
+        mask`` inside ``_one_round``), so nothing retraces and
         compression EF / learning stats compose unchanged. A fractional
         mask weights the aggregation; EF-wait and stats participation key
         on ``mask != 0``, which is exactly "the station shipped an
@@ -614,7 +728,10 @@ class FedAvg:
         slower than straight-line (docs/device_speed.md "K-selection").
         On the TPU the two forms have not been compared: not measured.
         """
-        with engine_call("fedavg.run_rounds", n_rounds):
+        with engine_call(
+            "fedavg.run_rounds", n_rounds,
+            gather=self.gather_path(stacked_x, stacked_y),
+        ):
             if mask is None:
                 mask = jnp.ones_like(counts)
             if opt_state is None:
@@ -658,7 +775,10 @@ class FedAvg:
         continues into the next fused dispatch, exactly like the host
         bookkeeping it replaces."""
         spec.validate()
-        with engine_call("fedavg.run_rounds_async", n_rounds):
+        with engine_call(
+            "fedavg.run_rounds_async", n_rounds,
+            gather=self.gather_path(stacked_x, stacked_y),
+        ):
             if mask is None:
                 mask = jnp.ones_like(counts)
             if staleness is None:
@@ -731,12 +851,14 @@ class FedAvg:
         # a [K, S] matrix gives each fused round its own roster — same
         # executable either way (rank is static), zero host round-trips
         masks = per_round_masks(mask, n_rounds)
+        # once per dispatch, outside the loop over rounds
+        tables, take = self._minibatch_source(stacked_x, stacked_y)
 
         def body(carry, xs):
             round_key, m = xs
             p, s = carry
-            p, s, loss, stats = self._round_impl(
-                p, s, stacked_x, stacked_y, counts, m, round_key
+            p, s, loss, stats = self._one_round(
+                p, s, tables, take, counts, m, round_key
             )
             return (p, s), (loss, stats)
 
@@ -772,13 +894,14 @@ class FedAvg:
         masks = per_round_masks(mask, n_rounds)
         accepts = per_round_masks(accept_masks, n_rounds)
         disc = jnp.asarray(discount, jnp.float32)
+        tables, take = self._minibatch_source(stacked_x, stacked_y)
 
         def body(carry, xs):
             p, s, stale = carry
             round_key, m, accept = xs
             eff = accept * jnp.power(disc, stale) * m
-            p, s, loss, stats = self._round_impl(
-                p, s, stacked_x, stacked_y, counts, eff, round_key
+            p, s, loss, stats = self._one_round(
+                p, s, tables, take, counts, eff, round_key
             )
             # accepted stations reset; everyone else ages one round —
             # the same bookkeeping Federation.run_buffered does host-side

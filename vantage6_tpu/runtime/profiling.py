@@ -88,27 +88,30 @@ __all__ = [
 # device operation's metadata carries ``.../<scope>/...`` (a backward
 # operation ``transpose(jvp(<scope>))``). Metadata only: the compiled program
 # and the persistent cache's key are the same with and without them.
-# fed/fedavg.py opens local_train (with gather and loss_grad inside it),
-# compress, learning_stats and server_update; fed/collectives.py aggregate;
+# fed/fedavg.py opens pack_table (once per dispatch), local_train (with
+# gather and loss_grad inside it), compress, learning_stats and
+# server_update; fed/collectives.py aggregate;
 # workloads/fed_transformer.py local_train (with embed, attention, mlp and
 # lm_head_loss inside it) and server_update.
 DEVICE_SCOPES = (
-    "local_train", "gather", "loss_grad", "embed", "attention", "mlp",
-    "lm_head_loss", "compress", "learning_stats", "aggregate",
+    "pack_table", "local_train", "gather", "loss_grad", "embed", "attention",
+    "mlp", "lm_head_loss", "compress", "learning_stats", "aggregate",
     "server_update",
 )
 
 
-def engine_call(engine: str, rounds: int):
+def engine_call(engine: str, rounds: int, **attrs: Any):
     """The ``engine.call`` span: the whole host side of one call into a
     round engine (``fedavg.run_rounds``, ``fed_transformer.round``, ...),
     entry to return; the program is then enqueued, not done. It roots a
     trace when the caller is in none and joins the caller's otherwise. Its
     self time, less the ``device.launch`` under it, is the engine's own host
-    work: placement, telemetry, history."""
+    work: placement, telemetry, history. ``attrs`` are what the engine knows
+    of the program it is about to launch (``FedAvg``: ``gather``, the
+    minibatch path its executable was built with)."""
     return TRACER.span(
         "engine.call", kind="engine",
-        attrs={"engine": engine, "rounds": rounds},
+        attrs={"engine": engine, "rounds": rounds, **attrs},
     )
 
 
