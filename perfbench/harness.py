@@ -63,6 +63,35 @@ class Run:
     def idle_percent(self) -> float | None:
         return None if self.trace is None else 100.0 * self.trace.idle_share
 
+    def scope_round_s(self, name: str) -> float | None:
+        """Device seconds per traced round under the program's
+        ``jax.named_scope(name)`` (`trace.Reduced.scope_s`), mean over the
+        chips. Nothing where the trace holds no mark (its rounds are then
+        not the window's), where its operations carry no path at all (an
+        executable compiled without the names), or where none lies under
+        ``name``."""
+        if self.traced_round_s() is None:
+            return None
+        under = self.trace.scope_s(name)
+        return None if under is None else under / self.traced_rounds
+
+    def scope_ms(self, name: str) -> float | None:
+        under = self.scope_round_s(name)
+        return None if under is None else 1e3 * under
+
+    def scope_share_of_peak(
+        self, name: str, per_round: float | None, peak: str,
+    ) -> float | None:
+        """``per_round`` (the operations or bytes one round needs under
+        ``name``, from the configuration's shapes:
+        ``run.cell.reference_module()`` has the functions) over the device
+        time under that scope, the chips and the peak, in percent: a
+        kernel's share of its roofline."""
+        under = self.scope_round_s(name)
+        if under is None or self.peaks is None or per_round is None:
+            return None
+        return 100.0 * per_round / (under * self.peaks[peak] * self.cell.chips)
+
 
 def key_from_seed(seed: int):
     """A key from any whole number up to 2**64: both halves are used."""
@@ -87,20 +116,34 @@ def find_devices(chips: int, require_chip: bool) -> list:
     return devices[:chips]
 
 
-def enable_compile_cache() -> None:
+def enable_compile_cache(traced: bool = False) -> None:
     """jax's persistent cache at a fixed path inside the checkout, unless
-    the environment already places it."""
+    the environment already places it. The names a program gives its
+    operations (scopes, source lines) are not in the cache's key, so a cached
+    executable carries the names of the tree that compiled it: a traced run,
+    which reads those names, keys its programs by them too. It then loads
+    only what a traced run of a tree with the same names compiled, and an
+    untraced run's keys and entries stay as they are."""
     import jax
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir",
                           str(cells.ROOT / ".jax_cache"))
+    if traced:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
 
 
-def memory_peak(devices: list) -> int:
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-             for d in devices]
-    return int(max(peaks))
+def memory_peaks(devices: list) -> dict[str, int]:
+    """The fullest chip's peak of the bytes in use, and of the bytes
+    reserved, which counts a running program's temporaries too."""
+    stats = [d.memory_stats() or {} for d in devices]
+    return {
+        "memory_peak_bytes": int(max(
+            s.get("peak_bytes_in_use", 0) for s in stats)),
+        "memory_reserved_bytes": int(max(
+            s.get("peak_bytes_reserved", 0) for s in stats)),
+    }
 
 
 def run_cell(
@@ -114,7 +157,7 @@ def run_cell(
 
     devices = find_devices(cell.chips, require_chip)
     if require_chip:  # the tests, on the CPU, keep no cache
-        enable_compile_cache()
+        enable_compile_cache(traced=trace)
     meter = CompileMeter()
     config, traffic = cell.config, cell.traffic
     reference_module = cell.reference_module()
@@ -158,7 +201,7 @@ def run_cell(
     if trace and not traced_marks:  # the window closed before the nth
         traced_marks.append(len(window.dispatch_s))
         jax.profiler.stop_trace()
-    peak_bytes = memory_peak(devices)
+    memory = memory_peaks(devices)
     program.drop_state()
     del program
     gc.collect()
@@ -185,7 +228,7 @@ def run_cell(
         if first.platform == "tpu" else None,
     )
     device = {"platform": first.platform, "kind": first.device_kind,
-              "count": len(devices), "memory_peak_bytes": peak_bytes}
+              "count": len(devices), **memory}
     result: dict[str, Any] = {
         "correct": correct,
         "attempted": window.rounds + n_steps,
@@ -202,6 +245,13 @@ def run_cell(
         result["device"] = device
         result["breakdown"] = {"device_ops": run.trace.device_ops,
                                "idle_gaps": run.trace.idle_gaps}
+        # the by-scope table's size, its sum (every leaf operation lies in
+        # one row, so it comes to busy_s) and its largest rows
+        by_scope = sorted(((path, row["s"]) for path, row in
+                           run.trace.scopes.items()), key=lambda r: -r[1])
+        result["scopes"] = {"rows": len(by_scope),
+                            "sum_s": sum(s for _, s in by_scope),
+                            "top": [list(r) for r in by_scope[:10]]}
     else:
         result["metrics"] = cells.read_metrics(cell.end_to_end, run)
         result["device"] = device

@@ -1,31 +1,51 @@
-"""Cells cut to a size the CPU holds, for the tests of the benchmark."""
+"""Cells cut to a size the CPU holds, for the tests of the benchmark.
+
+What a cell is cut to is data, found by name like everything else of a cell:
+`data/tiny/config.<configuration>.json` and `data/tiny/traffic.<mix>.json`
+hold the sizes laid over the shipped files (under ``"sizes"``), and
+`data/tiny/limits.<cell>.json` the limits of `correct` at that size (under
+``"limits"``, with how they were set). A new cell, mix or configuration
+brings its file; nothing here names one.
+"""
 from __future__ import annotations
 
 import json
 from pathlib import Path
+from typing import Any
 
 from perfbench import cells
 
 ROOT = Path(__file__).resolve().parents[2]
+TINY = Path(__file__).parent / "data" / "tiny"
 BENCHMARK = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELL_NAMES = [w["name"] for w in BENCHMARK["workloads"]]
 
-TINY_CONFIG = dict(n_embd=64, n_layer=2, n_head=4, n_positions=32, n_ctx=32,
-                   vocab_size=97, rows_per_station=512, n_features=10)
-TINY_TRAFFIC = dict(seq_len=32, batch=2, batch_size=64, local_steps=2)
-# the cells' own limits are for their own size on the chip; at the tiny size
-# the same rule gives these (data/tiny_limits.json says how)
-TINY_LIMITS = json.loads(
-    (Path(__file__).parent / "data" / "tiny_limits.json").read_text())
+
+def lay_over(shipped: dict[str, Any], kind: str, name: str, key: str) -> None:
+    """`data/tiny/<kind>.<name>.json`'s ``key`` over ``shipped``. A missing
+    file is an error that says which to add; so is a key the shipped file
+    does not have, since a renamed key would leave the cell at full size."""
+    path = TINY / f"{kind}.{name}.json"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"the tests cut every cell to a size the CPU holds: add {path} "
+            f'({{"how": ..., "{key}": {{...}}}}; perfbench/README.md, '
+            '"Adding things")')
+    over = json.loads(path.read_text())[key]
+    unknown = sorted(set(over) - set(shipped))
+    if unknown:
+        raise KeyError(
+            f"{path} names {unknown}, which the shipped {kind} {name!r} does "
+            f"not have (it has {sorted(shipped)})")
+    shipped.update(over)
 
 
 def tiny_cell(name: str) -> cells.Cell:
-    """The cell as shipped (entry, reference, limits, layout), with only the
-    sizes cut. Keys a configuration does not have are not added."""
+    """The cell as shipped (entry, reference, layout), with only its sizes
+    cut and its limits those of that size."""
     cell = cells.load_cell(name)
-    cell.config.update(
-        {k: v for k, v in TINY_CONFIG.items() if k in cell.config})
-    cell.traffic.update(
-        {k: v for k, v in TINY_TRAFFIC.items() if k in cell.traffic})
-    cell.limits = TINY_LIMITS[name]
+    entry = next(w for w in BENCHMARK["workloads"] if w["name"] == name)
+    lay_over(cell.config, "config", entry["config"], "sizes")
+    lay_over(cell.traffic, "traffic", entry["traffic"], "sizes")
+    lay_over(cell.limits, "limits", name, "limits")
     return cell

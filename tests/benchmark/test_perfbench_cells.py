@@ -7,8 +7,10 @@ from __future__ import annotations
 import dataclasses
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from perfbench import compare, harness
@@ -118,23 +120,88 @@ def test_the_control_rounds_operands_forward_and_cotangents_backward():
         rounder("float4")
 
 
+@pytest.mark.parametrize("name", CELL_NAMES)
+def test_a_traced_runs_line(name, monkeypatch, capsys):
+    """The traced branch of a run, which the CPU cannot reach by itself (it
+    has no device plane): the tiny cell runs under the profiler, and the
+    trace read back is one recorded on the chip."""
+    from perfbench import trace
+
+    recorded = str(Path(__file__).parent / "data" / "engine_v5e_cut.xplane.pb")
+    monkeypatch.setattr(trace, "find_xplane", lambda log_dir: recorded)
+    result = harness.run_cell(tiny_cell(name), SEED, 0.05, trace=True,
+                              require_chip=False)
+    assert result["correct"], result["checks"]
+    reported = {m["name"] for m in tiny_cell(name).per_layer}
+    assert set(result["metrics"]) <= reported
+    device = result["device"]
+    assert 0 < device["busy_s"] <= device["window_s"]
+    assert {"memory_peak_bytes", "memory_reserved_bytes"} <= set(device)
+    assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
+    scopes = result["scopes"]
+    assert scopes["rows"] >= len(scopes["top"]) > 1
+    assert scopes["sum_s"] <= device["busy_s"]
+    harness.print_result(result)
+    assert list(result)[-1] == "checks"
+    assert capsys.readouterr().out.strip().splitlines()[-1].startswith(
+        '{"correct": true')
+    assert not harness.TRACE_DIR.exists()
+
+
 # ------------------------------------------------------------------ faults
 def _unchanged_state(monkeypatch):
+    """The round runs and its state comes back as it went in: a copy taken
+    before the call, since a round that donates its state deletes what it
+    was handed."""
     from vantage6_tpu.fed.fedavg import FedAvg
     from vantage6_tpu.workloads.fed_transformer import FedTransformer
+
+    def copy(tree):
+        return jax.tree.map(jnp.copy, tree)
 
     whole_round = FedTransformer.round
 
     def round_(self, params, opt_state, tokens, mask):
-        return params, opt_state, whole_round(
-            self, params, opt_state, tokens, mask)[2]
+        kept = copy((params, opt_state))
+        return *kept, whole_round(self, params, opt_state, tokens, mask)[2]
 
     whole_run = FedAvg.run_rounds
 
     def run_rounds(self, params, *a, opt_state=None, **kw):
-        out = whole_run(self, params, *a, opt_state=opt_state,
-                        **{**kw, "donate": False})
-        return (params, opt_state) + tuple(out[2:])
+        kept = copy((params, opt_state))
+        return *kept, *whole_run(self, params, *a, opt_state=opt_state,
+                                 **kw)[2:]
+
+    monkeypatch.setattr(FedTransformer, "round", round_)
+    monkeypatch.setattr(FedAvg, "run_rounds", run_rounds)
+
+
+def _donated_state(monkeypatch):
+    """What `donate_argnums` on the state does to a caller: once the round
+    has returned, the buffers it was handed are gone."""
+    from vantage6_tpu.fed.fedavg import FedAvg
+    from vantage6_tpu.workloads.fed_transformer import FedTransformer
+
+    def delete(tree):
+        for leaf in jax.tree.leaves(tree):
+            if not leaf.is_deleted():
+                leaf.delete()
+
+    whole_round = FedTransformer.round
+
+    def round_(self, params, opt_state, tokens, mask):
+        out = jax.block_until_ready(
+            whole_round(self, params, opt_state, tokens, mask))
+        delete((params, opt_state))
+        return out
+
+    whole_run = FedAvg.run_rounds
+
+    def run_rounds(self, params, *a, opt_state=None, **kw):
+        out = jax.block_until_ready(
+            whole_run(self, params, *a, opt_state=opt_state, **kw))
+        delete((params, opt_state))
+        return out
 
     monkeypatch.setattr(FedTransformer, "round", round_)
     monkeypatch.setattr(FedAvg, "run_rounds", run_rounds)
@@ -177,6 +244,19 @@ def test_a_broken_timed_path_is_not_correct(name, fault, monkeypatch):
     assert not result["correct"], (fault, result["checks"])
 
 
+@pytest.mark.parametrize("name", CELL_NAMES)
+def test_an_unchanged_state_reads_not_correct_where_the_round_donates_it(
+        name, monkeypatch):
+    """The fault hands back a copy taken before the call, so it is read as
+    `correct: false`, and not as a deleted buffer, once the program donates
+    `params` and `opt_state`."""
+    _donated_state(monkeypatch)
+    assert _run(name)["correct"]      # the harness touches no donated buffer
+    _unchanged_state(monkeypatch)
+    result = _run(name)
+    assert not result["correct"], result["checks"]
+
+
 # ---------------------------------------------------------------- no chip
 def test_a_run_that_finds_no_tpu_fails_and_prints_no_metric():
     proc = subprocess.run(
@@ -215,3 +295,67 @@ def test_alone_in_a_directory_the_command_fails(tmp_path):
 def test_seeds_beyond_32_bits_make_different_keys():
     a, b = harness.key_from_seed(5), harness.key_from_seed(2**32 + 5)
     assert not (jax.random.key_data(a) == jax.random.key_data(b)).all()
+
+
+# ------------------------------------------- the cache and a traced run's names
+def test_a_traced_run_never_loads_a_program_compiled_under_other_names(
+        tmp_path, monkeypatch):
+    """The scopes are in no cache key, so an untraced run loads what a tree
+    with other scope names compiled, names and all; a traced run, whose
+    per-layer metrics read those names, keys its programs by them and
+    compiles its own."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from perfbench import cells
+    from perfbench.meter import CompileMeter
+
+    def program(scope):  # two trees' programs: alike but for a scope's name
+        def f(x):
+            with jax.named_scope(scope):
+                return jnp.sin(x) * 2.0 + 1.0
+        return jax.jit(f)
+
+    x = jnp.arange(8.0)
+
+    def loaded_from_the_cache(*scopes):
+        """Each program in turn, from one line: with the names in the key,
+        the lines of the calling frames are in it too."""
+        found = []
+        for scope in scopes:
+            jax.clear_caches()
+            before = meter.compiles, meter.cache_hits
+            program(scope)(x).block_until_ready()
+            assert meter.compiles - before[0] == 1   # built or loaded: once
+            found.append(meter.cache_hits - before[1] == 1)
+        return found
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(cells, "ROOT", tmp_path)
+    kept = {name: getattr(jax.config, name) for name in (
+        "jax_compilation_cache_dir",
+        "jax_compilation_cache_include_metadata_in_key",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    meter = CompileMeter()
+    try:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        compilation_cache.reset_cache()
+        harness.enable_compile_cache()
+        other = "attention_of_another_tree"
+        # untraced: the other tree's program is served under stale names
+        assert loaded_from_the_cache("attention", "attention", other) == [
+            False, True, True]
+        harness.enable_compile_cache(traced=True)
+        # traced: nothing an untraced run left, then its own entry, and
+        # never the other tree's
+        assert loaded_from_the_cache(
+            "attention", "attention", other, "attention") == [
+            False, True, False, True]
+        assert (tmp_path / ".jax_cache").is_dir()
+    finally:
+        meter.close()
+        for name, value in kept.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+        jax.clear_caches()

@@ -1,14 +1,18 @@
-"""BENCHMARK.json against the files it names, and the harness against the
-rule that it names no cell, configuration or metric in its code."""
+"""BENCHMARK.json against the files it names, the harness against the rule
+that it names no cell, configuration, mix, metric or configuration key in
+its code, and the tests' tiny sizes against the rule that they are files
+found by name."""
 from __future__ import annotations
 
+import json
 import re
 from pathlib import Path
 
 import pytest
 
+import perfbench_tiny
 from perfbench import cells
-from perfbench_tiny import BENCHMARK, CELL_NAMES, ROOT
+from perfbench_tiny import BENCHMARK, CELL_NAMES, ROOT, tiny_cell
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 ALL_METRICS = BENCHMARK["end_to_end"] + BENCHMARK["per_layer"]
@@ -74,14 +78,61 @@ def test_contract_shape():
 
 
 def test_the_harness_names_no_cell_configuration_or_metric():
+    """Nor a mix or a configuration's key (its sizes: the numbers of its
+    file), in the harness or in the code that cuts the cells for the tests:
+    a new cell arrives as files."""
     names = set(CELL_NAMES)
     names |= {c["name"] for c in BENCHMARK["configs"]}
     names |= {w["traffic"] for w in BENCHMARK["workloads"]}
     names |= {m["name"] for m in ALL_METRICS} - {"setup_s"}
-    for path in cells.HERE.glob("*.py"):
+    for c in BENCHMARK["configs"]:
+        names |= {key for key, value in cells.load_json(ROOT / c["file"]).items()
+                  if isinstance(value, (int, float))}
+    code_files = list(cells.HERE.glob("*.py")) + [Path(perfbench_tiny.__file__)]
+    for path in code_files:
         code = path.read_text()
         for name in names:
             assert name not in code, f"{path.name} names {name}"
+
+
+# ------------------------------------------------- the tests' tiny sizes
+@pytest.mark.parametrize("name", CELL_NAMES)
+def test_a_tiny_cell_is_the_shipped_cell_with_its_overlays_laid_over(name):
+    shipped, tiny = cells.load_cell(name), tiny_cell(name)
+    entry = next(w for w in BENCHMARK["workloads"] if w["name"] == name)
+    for kind, whose, key, before, after in (
+            ("config", entry["config"], "sizes", shipped.config, tiny.config),
+            ("traffic", entry["traffic"], "sizes", shipped.traffic,
+             tiny.traffic),
+            ("limits", name, "limits", shipped.limits, tiny.limits)):
+        held = cells.load_json(perfbench_tiny.TINY / f"{kind}.{whose}.json")
+        assert held["how"] and held[key], (kind, whose)
+        assert after == {**before, **held[key]}
+        assert list(after) == list(before)       # no key added, none moved
+
+
+def test_an_overlay_key_the_shipped_file_lacks_is_an_error(tmp_path,
+                                                           monkeypatch):
+    monkeypatch.setattr(perfbench_tiny, "TINY", tmp_path)
+    (tmp_path / "config.some.json").write_text(json.dumps(
+        {"how": "a renamed key", "sizes": {"width": 8, "depth_renamed": 2}}))
+    shipped = {"width": 1024, "depth": 24}
+    with pytest.raises(KeyError, match="depth_renamed"):
+        perfbench_tiny.lay_over(shipped, "config", "some", "sizes")
+    assert shipped == {"width": 1024, "depth": 24}  # nothing half laid over
+    (tmp_path / "config.some.json").write_text(json.dumps(
+        {"how": "the keys it has", "sizes": {"width": 8}}))
+    perfbench_tiny.lay_over(shipped, "config", "some", "sizes")
+    assert shipped == {"width": 8, "depth": 24}
+
+
+def test_a_missing_overlay_names_the_file_to_add(tmp_path, monkeypatch):
+    monkeypatch.setattr(perfbench_tiny, "TINY", tmp_path)
+    with pytest.raises(FileNotFoundError) as e:
+        tiny_cell(CELL_NAMES[0])
+    config = next(w for w in BENCHMARK["workloads"]
+                  if w["name"] == CELL_NAMES[0])["config"]
+    assert str(tmp_path / f"config.{config}.json") in str(e.value)
 
 
 def test_no_tpu_topology_is_described_on_import():
