@@ -48,6 +48,22 @@ def _transformer(attention="recompute"):
     return engine, (params, opt_state, tokens, jnp.ones(4))
 
 
+def _transformer_experts():
+    """A block with a router and an expert layer (chip 1 of 2 holds 2 of
+    the 4 experts) where the dense one has its MLP."""
+    cfg = FT.TransformerConfig(
+        vocab=97, d_model=32, n_heads=4, n_layers=2, max_len=16,
+        attention="recompute", flash_interpret=True, remat=True,
+        norm="rmsnorm", n_kv_heads=2, positions="rotary",
+        rope_layout=(0, 1), window=8, window_layout=(0, 1), ffn="experts",
+        n_experts=4, top_k=2, d_expert=16, experts_held=(2, 3),
+        tie_head=False)
+    engine = FT.make_engine(4, 1, cfg, devices=jax.devices()[:1])
+    params, opt_state = engine.init(jax.random.key(0))
+    tokens = engine.shard_tokens(FT.make_federated_tokens(4, 2, 16, 97))
+    return engine, (params, opt_state, tokens, jnp.ones(4))
+
+
 def _nll(p, x, y, w):
     z = x @ p["w"] + p["b"]
     return jnp.sum(w * (jnp.logaddexp(0.0, z) - y * z)) / jnp.sum(w)
@@ -84,6 +100,9 @@ PROGRAMS = {
                               TRANSFORMER_SCOPES),
     "transformer-ring": (lambda: _transformer("ring"), TRANSFORMER_SCOPES),
     "transformer-flash": (lambda: _transformer("flash"), TRANSFORMER_SCOPES),
+    "transformer-experts": (
+        _transformer_experts,
+        TRANSFORMER_SCOPES - {"mlp"} | {"router", "experts"}),
     "fedavg-fused": (lambda: _fedavg(), FEDAVG_SCOPES),
     "fedavg-compressed-zero1": (
         lambda: _fedavg(

@@ -166,6 +166,10 @@ def flash_attention(
     applies the standard softmax-attention VJP in jnp — see
     ``_attention_bwd``."""
     d = q.shape[-1]
+    if k.shape[1] != q.shape[1]:
+        raise ValueError(
+            f"the flash kernel takes as many key/value heads as query heads "
+            f"(got {k.shape[1]} and {q.shape[1]}): use recompute_attention")
     if scale is None:
         scale = 1.0 / (d**0.5)
     fn = _flash_vjp(causal, float(scale), block_q, block_k, interpret)
@@ -220,7 +224,9 @@ def recompute_attention(
     k_offset: jax.Array | int = 0,
     causal: bool = False,
     scale: float | None = None,
-    block_k: int = 128,
+    block_k: int | None = None,  # None: 128, or the tiled path's 512
+    window: int | None = None,
+    block_q: int | None = None,
 ) -> jax.Array:
     """Flash-MEMORY attention without a Pallas kernel: a blockwise
     (lax.scan over key blocks) online-softmax forward in plain jnp/XLA plus
@@ -230,11 +236,36 @@ def recompute_attention(
     residuals are just (q, k, v, o) — the [Tq, Tk] probabilities that a
     naive XLA attention saves for backward (the memory wall for long
     context) never exist. Which of the two is faster on the chip, and
-    from what sequence length: not measured (ROADMAP A4)."""
+    from what sequence length: not measured (ROADMAP A4).
+
+    That path meets every query with every key block and masks. The TILED
+    path (`_tiled_forward` / `_tiled_bwd`) also tiles the queries
+    (``block_q``) and walks, for each query block, only the key blocks that
+    hold a visible key: none above the causal diagonal and, with
+    ``window``, none wholly outside ``(i - window, i]``. It is taken when
+    ``block_q`` is given, when a ``window`` is, or when ``k``/``v`` carry
+    fewer heads than ``q`` (``[B, H_kv, T, D]`` beside ``[B, H_q, T, D]``,
+    ``H_q % H_kv == 0``: query head ``h`` reads kv head ``h // (H_q //
+    H_kv)``; the repeated heads are never materialised, forward or
+    backward). With none of the three the program is the one it was."""
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / (d**0.5)
-    fn = _recompute_vjp(causal, float(scale), block_k)
+    if window is not None or block_q is not None or k.shape[1] != q.shape[1]:
+        if window is not None and not causal:
+            raise ValueError("a window is a causal window: pass causal=True")
+        if q.shape[1] % k.shape[1]:
+            raise ValueError(
+                f"{q.shape[1]} query heads do not divide over "
+                f"{k.shape[1]} key/value heads")
+        fn = _tiled_vjp(causal, float(scale), window,
+                        block_q or TILED_BLOCK, block_k or TILED_BLOCK)
+        return fn(
+            q, k, v,
+            jnp.asarray(q_offset, jnp.int32),
+            jnp.asarray(k_offset, jnp.int32),
+        )
+    fn = _recompute_vjp(causal, float(scale), block_k or 128)
     return fn(
         q, k, v,
         jnp.asarray(q_offset, jnp.int32),
@@ -299,6 +330,210 @@ def _blockwise_forward(q, k, v, q_offset, k_offset, *, causal, scale,
     )
     denom = jnp.where(l > 0, l, 1.0)[..., None]
     return (acc / denom).astype(q.dtype)
+
+
+# ------------------------------------------------------------- tiled path
+TILED_BLOCK = 512  # query and key blocks where the caller names none
+
+
+def _key_block_range(i, block_q, block_k, n_kblocks, t_k, q_offset, k_offset,
+                     causal, window):
+    """The key blocks ``[lo, hi)`` that hold a key some query of query block
+    ``i`` may see: local key index ``j`` is visible to global query position
+    ``p`` iff ``k_offset + j <= p`` (causal) and ``k_offset + j > p -
+    window``. Traced scalars; an empty range reads ``hi <= lo``."""
+    first_q = q_offset + i * block_q
+    hi = n_kblocks
+    if causal:
+        last_key = jnp.clip(first_q + block_q - k_offset, 0, t_k)  # exclusive
+        hi = (last_key + block_k - 1) // block_k
+    lo = 0
+    if window is not None:
+        lo = jnp.clip(first_q - window + 1 - k_offset, 0, t_k) // block_k
+    return lo, hi
+
+
+def _tile_scores(q_i, k_j, q_pos, k_idx, k_offset, t_k, causal, window,
+                 scale):
+    """Masked scores of one (query block, key block) tile, f32:
+    ``q_i`` [B, Hkv, G, bq, D], ``k_j`` [B, Hkv, bk, D]."""
+    s = jnp.einsum(
+        "bhgqd,bhkd->bhgqk", q_i, k_j, preferred_element_type=jnp.float32
+    ) * scale
+    valid = (k_idx < t_k)[None, :]
+    if causal:
+        k_pos = (k_offset + k_idx)[None, :]
+        valid = valid & (q_pos[:, None] >= k_pos)
+        if window is not None:
+            valid = valid & (k_pos > q_pos[:, None] - window)
+    return jnp.where(valid[None, None, None], s, NEG_INF)
+
+
+def _tiles(q, k, v, block_q, block_k):
+    """Pad to whole blocks and group the query heads by their kv head:
+    q -> [n_q, B, Hkv, G, bq, D] (scan input), k, v -> [B, Hkv, Tk_p, D]."""
+    b, h_q, t_q, d = q.shape
+    h_kv, t_k = k.shape[1], k.shape[2]
+    block_q, block_k = min(block_q, t_q), min(block_k, t_k)
+    pad_q, pad_k = (-t_q) % block_q, (-t_k) % block_k
+    if pad_q:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
+    if pad_k:
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+    n_q = (t_q + pad_q) // block_q
+    qb = jnp.moveaxis(
+        q.reshape(b, h_kv, h_q // h_kv, n_q, block_q, d), 3, 0)
+    return qb, k, v, block_q, block_k
+
+
+def _tiled_forward(q, k, v, q_offset, k_offset, *, causal, scale, window,
+                   block_q, block_k):
+    """Online-softmax forward over (query block, visible key block) tiles.
+    Returns ``o`` [B, Hq, Tq, D] and the row statistics ``L = m + log l``
+    [B, Hq, Tq] (f32), which the backward reads instead of a first pass."""
+    b, h_q, t_q, d = q.shape
+    h_kv, t_k = k.shape[1], k.shape[2]
+    g = h_q // h_kv
+    qb, k, v, block_q, block_k = _tiles(q, k, v, block_q, block_k)
+    n_kblocks = k.shape[2] // block_k
+    q_off, k_off = jnp.reshape(q_offset, ()), jnp.reshape(k_offset, ())
+
+    def one_query_block(i, q_i):
+        q_pos = q_off + i * block_q + jnp.arange(block_q)
+        lo, hi = _key_block_range(i, block_q, block_k, n_kblocks, t_k,
+                                  q_off, k_off, causal, window)
+
+        def step(j, carry):
+            m, l, acc = carry
+            k_j = lax.dynamic_slice_in_dim(k, j * block_k, block_k, 2)
+            v_j = lax.dynamic_slice_in_dim(v, j * block_k, block_k, 2)
+            s = _tile_scores(q_i, k_j, q_pos, j * block_k
+                             + jnp.arange(block_k), k_off, t_k, causal,
+                             window, scale)
+            m_new = jnp.maximum(m, jnp.maximum(jnp.max(s, -1), -1e20))
+            corr = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            l = l * corr + jnp.sum(p, -1)
+            acc = acc * corr[..., None] + jnp.einsum(
+                "bhgqk,bhkd->bhgqd", p.astype(v_j.dtype), v_j,
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, l, acc
+
+        m0 = jnp.full((b, h_kv, g, block_q), NEG_INF, jnp.float32)
+        m, l, acc = lax.fori_loop(lo, hi, step, (
+            m0, jnp.zeros_like(m0),
+            jnp.zeros((b, h_kv, g, block_q, d), jnp.float32)))
+        safe_l = jnp.where(l > 0, l, 1.0)
+        return (acc / safe_l[..., None]).astype(q.dtype), m + jnp.log(safe_l)
+
+    def scan_body(i, q_i):
+        return i + 1, one_query_block(i, q_i)
+
+    _, (ob, lb) = lax.scan(scan_body, jnp.int32(0), qb)
+    o = jnp.moveaxis(ob, 0, 3).reshape(b, h_q, -1, d)[:, :, :t_q]
+    big_l = jnp.moveaxis(lb, 0, 3).reshape(b, h_q, -1)[:, :, :t_q]
+    return o, big_l
+
+
+def _tiled_bwd(q, k, v, o, big_l, do, q_offset, k_offset, *, causal, scale,
+               window, block_q, block_k):
+    """The softmax-attention VJP over the same tiles: for each query block
+    the visible key blocks only, ``P = exp(S - L)`` recomputed per tile,
+    ``dK``/``dV`` accumulated in place in f32 (a kv head sums over its
+    group of query heads inside the product), products in the inputs'
+    dtype with f32 accumulation."""
+    b, h_q, t_q, d = q.shape
+    h_kv, t_k = k.shape[1], k.shape[2]
+    g = h_q // h_kv
+    d_term = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+    qb, k_p, v_p, block_q, block_k = _tiles(q, k, v, block_q, block_k)
+    n_q = qb.shape[0]
+    pad_q = n_q * block_q - t_q
+    n_kblocks = k_p.shape[2] // block_k
+    q_off, k_off = jnp.reshape(q_offset, ()), jnp.reshape(k_offset, ())
+
+    def rows(x):  # [B, Hq, Tq, ...] -> [n_q, B, Hkv, G, bq, ...]
+        if pad_q:
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, pad_q))
+                        + ((0, 0),) * (x.ndim - 3))
+        x = x.reshape((b, h_kv, g, n_q, block_q) + x.shape[3:])
+        return jnp.moveaxis(x, 3, 0)
+
+    def scan_body(carry, blk):
+        i, dk, dv = carry
+        q_i, do_i, l_i, d_i = blk
+        q_pos = q_off + i * block_q + jnp.arange(block_q)
+        lo, hi = _key_block_range(i, block_q, block_k, n_kblocks, t_k,
+                                  q_off, k_off, causal, window)
+
+        def step(j, carry):
+            dq_i, dk, dv = carry
+            k_j = lax.dynamic_slice_in_dim(k_p, j * block_k, block_k, 2)
+            v_j = lax.dynamic_slice_in_dim(v_p, j * block_k, block_k, 2)
+            s = _tile_scores(q_i, k_j, q_pos, j * block_k
+                             + jnp.arange(block_k), k_off, t_k, causal,
+                             window, scale)
+            p = jnp.exp(s - l_i[..., None])
+            dv_j = jnp.einsum(
+                "bhgqk,bhgqd->bhkd", p.astype(do_i.dtype), do_i,
+                preferred_element_type=jnp.float32)
+            dp = jnp.einsum(
+                "bhgqd,bhkd->bhgqk", do_i, v_j,
+                preferred_element_type=jnp.float32)
+            ds = (p * (dp - d_i[..., None])).astype(q_i.dtype)
+            dq_i = dq_i + jnp.einsum(
+                "bhgqk,bhkd->bhgqd", ds, k_j,
+                preferred_element_type=jnp.float32) * scale
+            dk_j = jnp.einsum(
+                "bhgqk,bhgqd->bhkd", ds, q_i,
+                preferred_element_type=jnp.float32) * scale
+            at = j * block_k
+            dk = lax.dynamic_update_slice_in_dim(
+                dk, lax.dynamic_slice_in_dim(dk, at, block_k, 2) + dk_j,
+                at, 2)
+            dv = lax.dynamic_update_slice_in_dim(
+                dv, lax.dynamic_slice_in_dim(dv, at, block_k, 2) + dv_j,
+                at, 2)
+            return dq_i, dk, dv
+
+        dq_i, dk, dv = lax.fori_loop(lo, hi, step, (
+            jnp.zeros((b, h_kv, g, block_q, d), jnp.float32), dk, dv))
+        return (i + 1, dk, dv), dq_i.astype(q.dtype)
+
+    zeros = jnp.zeros(k_p.shape, jnp.float32)
+    (_, dk, dv), dqb = lax.scan(
+        scan_body, (jnp.int32(0), zeros, zeros),
+        (qb, rows(do), rows(big_l), rows(d_term)))
+    dq = jnp.moveaxis(dqb, 0, 3).reshape(b, h_q, -1, d)[:, :, :t_q]
+    return (dq, dk[:, :, :t_k].astype(k.dtype),
+            dv[:, :, :t_k].astype(v.dtype))
+
+
+@functools.lru_cache(maxsize=None)
+def _tiled_vjp(causal, scale, window, block_q, block_k):
+    """custom_vjp of the tiled path, one callable per static config. The
+    residuals are (q, k, v, o, L): L is [B, Hq, Tq] f32, so the backward
+    needs no first pass over the keys for the softmax statistics."""
+    kw = dict(causal=causal, scale=scale, window=window, block_q=block_q,
+              block_k=block_k)
+
+    @jax.custom_vjp
+    def fa(q, k, v, qoff, koff):
+        return _tiled_forward(q, k, v, qoff, koff, **kw)[0]
+
+    def fwd(q, k, v, qoff, koff):
+        o, big_l = _tiled_forward(q, k, v, qoff, koff, **kw)
+        return o, (q, k, v, o, big_l, qoff, koff)
+
+    def bwd(res, do):
+        q, k, v, o, big_l, qoff, koff = res
+        return (*_tiled_bwd(q, k, v, o, big_l, do, qoff, koff, **kw),
+                None, None)
+
+    fa.defvjp(fwd, bwd)
+    return fa
 
 
 def _pad_and_flatten(q, k, v, block_q: int, block_k: int):
@@ -534,16 +769,23 @@ def _attention_bwd(
 def reference(
     q: jax.Array, k: jax.Array, v: jax.Array,
     q_offset: int = 0, k_offset: int = 0, causal: bool = False,
-    scale: float | None = None,
+    scale: float | None = None, window: int | None = None,
 ) -> jax.Array:
-    """jnp oracle in the same [B, H, T, D] layout."""
+    """jnp oracle in the same [B, H, T, D] layout: dense masked softmax,
+    the kv heads repeated where they are fewer than the query heads."""
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / (d**0.5)
+    if k.shape[1] != q.shape[1]:
+        k, v = (jnp.repeat(x, q.shape[1] // k.shape[1], axis=1)
+                for x in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
         q_pos = q_offset + jnp.arange(q.shape[2])
         k_pos = k_offset + jnp.arange(k.shape[2])
-        s = jnp.where(q_pos[:, None] >= k_pos[None, :], s, NEG_INF)
+        seen = q_pos[:, None] >= k_pos[None, :]
+        if window is not None:
+            seen = seen & (k_pos[None, :] > q_pos[:, None] - window)
+        s = jnp.where(seen, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)

@@ -92,11 +92,12 @@ __all__ = [
 # gather and loss_grad inside it), compress, learning_stats and
 # server_update; fed/collectives.py aggregate;
 # workloads/fed_transformer.py local_train (with embed, attention, mlp and
-# lm_head_loss inside it) and server_update.
+# lm_head_loss inside it; a block with experts opens router, before
+# attention, and experts where the dense one opens mlp) and server_update.
 DEVICE_SCOPES = (
     "pack_table", "local_train", "gather", "loss_grad", "embed", "attention",
-    "mlp", "lm_head_loss", "compress", "learning_stats", "aggregate",
-    "server_update",
+    "mlp", "router", "experts", "lm_head_loss", "compress", "learning_stats",
+    "aggregate", "server_update",
 )
 
 

@@ -14,6 +14,7 @@ primitives as the tabular workloads.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 from functools import partial
 from typing import Any
@@ -27,12 +28,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from vantage6_tpu.core.mesh import STATION_AXIS, _largest_divisor_leq
 from vantage6_tpu.fed import collectives
+from vantage6_tpu.models import experts
 from vantage6_tpu.ops.flash_attention import (
     flash_attention,
     recompute_attention,
 )
 from vantage6_tpu.parallel.ring_attention import ring_attention
 from vantage6_tpu.runtime.profiling import device_launch, engine_call
+from vantage6_tpu.runtime.tracing import TRACER
 
 SEQ_AXIS = "device"  # sequence parallelism rides the within-station axis
 
@@ -68,31 +71,141 @@ class TransformerConfig:
     # numerically identical to f32 rounding (XLA may fuse differently
     # across the checkpoint boundary — measured ~1 ULP on the loss).
     remat: bool = False
+    # ---- the block, as data. The defaults are the block this file always
+    # ran (parameter-free LayerNorm, a learned position table at the input,
+    # as many kv heads as query heads of d_model / n_heads, full causal
+    # attention, a 4x GELU MLP, the head tied to the embedding): with them
+    # `init_params` and `forward_local` produce what they produced, bit for
+    # bit, and `_round` lowers to the same program.
+    # "layernorm": no learned scale or bias; "rmsnorm": x / rms(x) * g with
+    # a learned g per norm (and a final one before the head).
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    head_dim: int | None = None  # None: d_model // n_heads
+    n_kv_heads: int | None = None  # None: n_heads; fewer: grouped queries
+    # "learned": a [max_len, d_model] table added at the input. "rotary":
+    # none at the input; per layer, `rope_layout[i]` 1 rotates q and k
+    # (rotate-half, `rope_theta`), 0 gives the layer no positions at all.
+    positions: str = "learned"
+    rope_layout: tuple[int, ...] | None = None  # None: every layer rotates
+    rope_theta: float = 10000.0
+    # `window_layout[i]` 1: layer i sees keys i - window < j <= i; 0: every
+    # earlier key. None with a `window`: every layer is windowed.
+    window: int | None = None
+    window_layout: tuple[int, ...] | None = None
+    # "mlp": 4x GELU. "experts": a router BEFORE attention (on the block's
+    # input) over `n_experts`, `top_k` a token, and ReGLU experts of width
+    # `d_expert`, of which this chip holds `experts_held` (their ids):
+    # models/experts.py. No shared expert, no dense feed-forward.
+    ffn: str = "mlp"
+    n_experts: int = 0
+    top_k: int = 0
+    d_expert: int = 0
+    experts_held: tuple[int, ...] = ()
+    tie_head: bool = True  # False: an output head of its own, [d_model, vocab]
 
-    @property
-    def head_dim(self) -> int:
-        assert self.d_model % self.n_heads == 0
-        return self.d_model // self.n_heads
+    def __post_init__(self):
+        if self.head_dim is None:
+            assert self.d_model % self.n_heads == 0
+            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.n_kv_heads is None:
+            object.__setattr__(self, "n_kv_heads", self.n_heads)
+        for name, layout in (("rope_layout", self.rope_layout),
+                             ("window_layout", self.window_layout)):
+            if layout is not None:
+                if len(layout) != self.n_layers:
+                    raise ValueError(
+                        f"{name} has {len(layout)} entries for "
+                        f"{self.n_layers} layers")
+                object.__setattr__(self, name, tuple(layout))
+        object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"no such norm: {self.norm!r}")
+        if self.positions not in ("learned", "rotary"):
+            raise ValueError(f"no such positions: {self.positions!r}")
+        if self.ffn not in ("mlp", "experts"):
+            raise ValueError(f"no such ffn: {self.ffn!r}")
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(
+                f"{self.n_heads} query heads do not divide over "
+                f"{self.n_kv_heads} key/value heads")
+        if self.positions == "rotary" and self.head_dim % 2:
+            raise ValueError("rotary positions need an even head_dim")
+        if self.ffn == "experts" and not (
+                self.experts_held and self.top_k and self.d_expert
+                and max(self.experts_held) < self.n_experts):
+            raise ValueError(
+                "ffn='experts' needs n_experts, top_k, d_expert and the ids "
+                "of the experts_held here")
+        needs_tiles = (self.n_kv_heads != self.n_heads
+                       or self.window is not None)
+        if needs_tiles and self.attention != "recompute":
+            # ring and flash take neither a window nor fewer kv heads; they
+            # never give way to another path in silence
+            raise ValueError(
+                f"attention={self.attention!r} takes no window and no "
+                "grouped kv heads: use attention='recompute'")
+
+    def layer_window(self, i: int) -> int | None:
+        if self.window is None:
+            return None
+        if self.window_layout is not None and not self.window_layout[i]:
+            return None
+        return self.window
+
+    def layer_rotates(self, i: int) -> bool:
+        if self.positions != "rotary":
+            return False
+        return self.rope_layout is None or bool(self.rope_layout[i])
+
+
+def _layer_shapes(cfg: TransformerConfig) -> dict[str, tuple[int, ...]]:
+    """The matrices of one layer, in the order their keys are drawn."""
+    d, hd = cfg.d_model, cfg.head_dim
+    shapes: dict[str, tuple[int, ...]] = {
+        "qkv": (d, (cfg.n_heads + 2 * cfg.n_kv_heads) * hd),
+        "proj": (cfg.n_heads * hd, d),
+    }
+    if cfg.ffn == "mlp":
+        shapes.update(w_up=(d, 4 * d), w_down=(4 * d, d))
+    else:
+        e, f = len(cfg.experts_held), cfg.d_expert
+        shapes.update(router=(d, cfg.n_experts), w_gate=(e, d, f),
+                      w_up=(e, d, f), w_down=(e, f, d))
+    return shapes
 
 
 def init_params(key: jax.Array, cfg: TransformerConfig) -> dict[str, Any]:
-    keys = jax.random.split(key, 2 + 4 * cfg.n_layers)
+    """Matrices ~ N(0, 0.02); learned norm scales 1. The tree: ``embed``;
+    ``pos`` with learned positions; ``head`` where the head is not tied;
+    ``final_norm`` with rmsnorm; ``layers[i]``: ``qkv`` (the q, k and v
+    projections side by side), ``proj``, then ``w_up``/``w_down`` (mlp) or
+    ``router``/``w_gate``/``w_up``/``w_down`` (experts, the held ones
+    stacked), and ``norm1``/``norm2`` with rmsnorm."""
+    shapes = _layer_shapes(cfg)
+    n = len(shapes)
+    keys = jax.random.split(key, 2 + n * cfg.n_layers)
     s = 0.02
     params: dict[str, Any] = {
         "embed": s * jax.random.normal(keys[0], (cfg.vocab, cfg.d_model)),
-        "pos": s * jax.random.normal(keys[1], (cfg.max_len, cfg.d_model)),
-        "layers": [],
     }
+    if cfg.positions == "learned":
+        params["pos"] = s * jax.random.normal(
+            keys[1], (cfg.max_len, cfg.d_model))
+    if not cfg.tie_head:
+        params["head"] = s * jax.random.normal(
+            jax.random.fold_in(keys[1], 1), (cfg.d_model, cfg.vocab))
+    if cfg.norm == "rmsnorm":
+        params["final_norm"] = jnp.ones((cfg.d_model,))
+    params["layers"] = []
     for i in range(cfg.n_layers):
-        k = keys[2 + 4 * i : 6 + 4 * i]
-        params["layers"].append(
-            {
-                "qkv": s * jax.random.normal(k[0], (cfg.d_model, 3 * cfg.d_model)),
-                "proj": s * jax.random.normal(k[1], (cfg.d_model, cfg.d_model)),
-                "w_up": s * jax.random.normal(k[2], (cfg.d_model, 4 * cfg.d_model)),
-                "w_down": s * jax.random.normal(k[3], (4 * cfg.d_model, cfg.d_model)),
-            }
-        )
+        k = keys[2 + n * i : 2 + n * (i + 1)]
+        layer = {name: s * jax.random.normal(k[j], shape)
+                 for j, (name, shape) in enumerate(shapes.items())}
+        if cfg.norm == "rmsnorm":
+            layer["norm1"] = jnp.ones((cfg.d_model,))
+            layer["norm2"] = jnp.ones((cfg.d_model,))
+        params["layers"].append(layer)
     return params
 
 
@@ -104,34 +217,77 @@ def _ln(x: jax.Array) -> jax.Array:
     return ((xf - mu) * lax.rsqrt(var + 1e-6)).astype(x.dtype)
 
 
-def forward_local(
+def _norm(x: jax.Array, scale: jax.Array | None, cfg: TransformerConfig):
+    """The configuration's norm: the parameter-free LayerNorm above, or
+    ``x / sqrt(mean(x^2) + eps) * g`` with the learned ``g`` (float32)."""
+    if cfg.norm == "layernorm":
+        return _ln(x)
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(xf * xf, -1, keepdims=True)
+    return (xf * lax.rsqrt(ms + cfg.norm_eps) * scale).astype(x.dtype)
+
+
+def _rotate(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotate-half RoPE of ``x`` [B, T, H, D] at global ``positions`` [T]:
+    pair ``(x[i], x[i + D/2])`` turns by ``positions * theta^(-2i/D)``."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions.astype(jnp.float32)[:, None] * freq[None, :]
+    cos = jnp.cos(angle)[None, :, None, :]
+    sin = jnp.sin(angle)[None, :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    ).astype(x.dtype)
+
+
+def _forward(
     params: dict[str, Any],
-    tokens_local: jax.Array,  # [B, T_local] — this device's sequence shard
+    tokens_local: jax.Array,
     cfg: TransformerConfig,
-    axis_name: str = SEQ_AXIS,
-) -> jax.Array:
-    """Logits [B, T_local, V] for this shard; attention spans the FULL
-    sequence via the ring."""
+    axis_name: str,
+) -> tuple[jax.Array, Any]:
+    """Logits, and the expert layers' load: per layer the assignments each
+    held expert received and the choices that named one (``None`` for a
+    block without experts)."""
     b, t_local = tokens_local.shape
     offset = lax.axis_index(axis_name) * t_local  # global positions
+    n_q, n_kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
 
     def cast(w: jax.Array) -> jax.Array:
         return w.astype(cfg.dtype)
 
     with jax.named_scope("embed"):
         x = cast(params["embed"])[tokens_local]
-        x = x + cast(
-            lax.dynamic_slice_in_dim(params["pos"], offset, t_local, 0)
-        )[None]
+        if cfg.positions == "learned":
+            x = x + cast(
+                lax.dynamic_slice_in_dim(params["pos"], offset, t_local, 0)
+            )[None]
 
-    def layer_block(x, layer):
-        layer = jax.tree.map(cast, layer)
-        h = _ln(x)
+    def layer_block(x, layer, *, window, rotates):
+        # the router's matrix and the norms' scales stay float32
+        kept = {name: layer[name] for name in ("router", "norm1", "norm2")
+                if name in layer}
+        layer = jax.tree.map(
+            cast, {k: v for k, v in layer.items() if k not in kept})
+        routing = None
+        if cfg.ffn == "experts":
+            with jax.named_scope("router"):
+                # before attention, on the block's input
+                routing = experts.route(
+                    x.reshape(b * t_local, cfg.d_model), kept["router"],
+                    cfg.top_k)
+        h = _norm(x, kept.get("norm1"), cfg)
         qkv = h @ layer["qkv"]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q, k, v = jnp.split(qkv, [n_q, n_q + n_kv], axis=-1)
         q = q.reshape(b, t_local, cfg.n_heads, cfg.head_dim)
-        k = k.reshape(b, t_local, cfg.n_heads, cfg.head_dim)
-        v = v.reshape(b, t_local, cfg.n_heads, cfg.head_dim)
+        k = k.reshape(b, t_local, cfg.n_kv_heads, cfg.head_dim)
+        v = v.reshape(b, t_local, cfg.n_kv_heads, cfg.head_dim)
+        if rotates:
+            positions = offset + jnp.arange(t_local)
+            q = _rotate(q, positions, cfg.rope_theta)
+            k = _rotate(k, positions, cfg.rope_theta)
         if cfg.attention in ("flash", "recompute"):
             # both want head-major [B, H, T, D]; offsets keep the causal
             # mask correct for any sequence shard (here the full sequence —
@@ -144,6 +300,8 @@ def forward_local(
                 {"interpret": cfg.flash_interpret}
                 if cfg.attention == "flash" else {}
             )
+            if window is not None:
+                kw["window"] = window
             with jax.named_scope("attention"):
                 attn = impl(
                     q.transpose(0, 2, 1, 3),
@@ -157,17 +315,72 @@ def forward_local(
         else:
             with jax.named_scope("attention"):
                 attn = ring_attention(q, k, v, axis_name, causal=True)
-        x = x + attn.reshape(b, t_local, cfg.d_model) @ layer["proj"]
+        x = x + attn.reshape(b, t_local, n_q) @ layer["proj"]
+        if cfg.ffn == "experts":
+            return x, routing  # the expert layer follows: `expert_half`
         with jax.named_scope("mlp"):
-            h = _ln(x)
-            return x + jax.nn.gelu(h @ layer["w_up"]) @ layer["w_down"]
+            h = _norm(x, kept.get("norm2"), cfg)
+            return x + jax.nn.gelu(h @ layer["w_up"]) @ layer["w_down"], None
 
-    if cfg.remat:
-        layer_block = jax.checkpoint(layer_block)
-    for layer in params["layers"]:
-        x = layer_block(x, layer)
+    def expert_half(x, layer, routing):
+        """Outside the layer's checkpoint: the expert layer recomputes its
+        own chunks (models/experts.py), and inside another recomputation it
+        would run forward three times."""
+        with jax.named_scope("experts"):
+            h = _norm(x, layer.get("norm2"), cfg)
+            y, load = experts.expert_layer(
+                h.reshape(b * t_local, cfg.d_model), *routing, layer,
+                cfg.experts_held, cfg.n_experts,
+                interpret=cfg.flash_interpret)
+            return x + y.reshape(x.shape), load
+
+    blocks: dict[Any, Any] = {}  # one traced block per kind of layer
+
+    def block_of(window, rotates):
+        if (window, rotates) not in blocks:
+            block = partial(layer_block, window=window, rotates=rotates)
+            blocks[window, rotates] = (
+                jax.checkpoint(block) if cfg.remat else block)
+        return blocks[window, rotates]
+
+    loads = []
+    for i, layer in enumerate(params["layers"]):
+        x, routing = block_of(cfg.layer_window(i), cfg.layer_rotates(i))(
+            x, layer)
+        if cfg.ffn == "experts":
+            x, load = expert_half(x, layer, routing)
+            loads.append(load)
     with jax.named_scope("lm_head_loss"):
-        return _ln(x) @ cast(params["embed"]).T
+        x = _norm(x, params.get("final_norm"), cfg)
+        logits = x @ (cast(params["embed"]).T if cfg.tie_head
+                      else cast(params["head"]))
+    if cfg.ffn != "experts":
+        return logits, None
+    return logits, jax.tree.map(lambda *xs: jnp.stack(xs), *loads)
+
+
+def forward_local(
+    params: dict[str, Any],
+    tokens_local: jax.Array,  # [B, T_local] — this device's sequence shard
+    cfg: TransformerConfig,
+    axis_name: str = SEQ_AXIS,
+) -> jax.Array:
+    """Logits [B, T_local, V] for this shard; attention spans the FULL
+    sequence via the ring."""
+    return _forward(params, tokens_local, cfg, axis_name)[0]
+
+
+def _loss_and_load(params, tokens_local, cfg, axis_name):
+    logits, load = _forward(params, tokens_local, cfg, axis_name)
+    with jax.named_scope("lm_head_loss"):
+        targets = tokens_local[:, 1:]
+        logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        local_sum = jnp.sum(nll)
+        local_cnt = jnp.asarray(nll.size, jnp.float32)
+    total = lax.psum(local_sum, axis_name)
+    count = lax.psum(local_cnt, axis_name)
+    return total / count, load
 
 
 def loss_local(
@@ -182,16 +395,7 @@ def loss_local(
     its target on the next shard, so that position is masked out (T/P - 1
     predictions per shard — negligible at scale, exact bookkeeping here).
     """
-    logits = forward_local(params, tokens_local, cfg, axis_name)
-    with jax.named_scope("lm_head_loss"):
-        targets = tokens_local[:, 1:]
-        logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        local_sum = jnp.sum(nll)
-        local_cnt = jnp.asarray(nll.size, jnp.float32)
-    total = lax.psum(local_sum, axis_name)
-    count = lax.psum(local_cnt, axis_name)
-    return total / count
+    return _loss_and_load(params, tokens_local, cfg, axis_name)[0]
 
 
 @dataclasses.dataclass(eq=False)  # identity hash: engine is a jit static arg
@@ -201,6 +405,10 @@ class FedTransformer:
     mesh: Mesh
     cfg: TransformerConfig
     optimizer: Any
+    # the expert layers' counts of the last rounds, still on the device
+    # (`record_expert_load` reads and empties it; bounded, oldest out)
+    _expert_load: collections.deque = dataclasses.field(
+        default_factory=lambda: collections.deque(maxlen=4096), repr=False)
 
     def init(self, key: jax.Array) -> tuple[Any, Any]:
         # params AND the whole optimizer state are committed to the mesh:
@@ -239,26 +447,60 @@ class FedTransformer:
         with engine_call("fed_transformer.round", 1):
             n_buffers = len(jax.tree.leaves(args))
             with device_launch("fed_transformer.round", n_buffers):
-                return self._round(*args)
+                *out, load = self._round(*args)
+        if load is not None:  # stays on the device: record_expert_load
+            self._expert_load.append(load)
+        return tuple(out)
+
+    def record_expert_load(self) -> dict[str, Any] | None:
+        """Read the expert layers' counts of the rounds since the last call
+        off the device and record them as ONE ``experts.load`` span:
+        ``rounds``, per layer ``assignments_per_round`` (per held expert,
+        summed over the stations, mean over the rounds), each round's total
+        over layers and experts ``assignments_by_round``, and over all layers
+        ``max_over_mean`` (the fullest held expert over the mean one) and
+        ``dropped`` (choices that named a held expert less rows its product
+        ran over: 0, there is no capacity). A round leaves its counts on the
+        device and this call fetches them, so call it OUTSIDE what is
+        timed. Returns the attributes, or None where there is nothing to
+        record (no expert layer, no round since the last call)."""
+        pending = list(self._expert_load)
+        self._expert_load.clear()
+        if not pending:
+            return None
+        counts = jax.device_get(pending)
+        a = np.stack([c["assignments"] for c in counts])  # [R, L, E]
+        routed = np.stack([c["routed_here"] for c in counts])
+        attrs = {
+            "rounds": len(counts),
+            "assignments_per_round": (a.sum(0) / len(counts)).tolist(),
+            "assignments_by_round": a.sum((1, 2)).tolist(),
+            **experts.load_summary(a, routed),
+        }
+        with TRACER.span("experts.load", kind="engine", attrs=attrs):
+            pass
+        return attrs
 
     @partial(jax.jit, static_argnums=0)
     def _round(
         self, params: Any, opt_state: Any, tokens: jax.Array,
         mask: jax.Array,
-    ) -> tuple[Any, Any, jax.Array]:
+    ) -> tuple[Any, Any, jax.Array, Any]:
         def station_body(params, tokens_block):
             # tokens_block: [S/D_s, B, T/P] — the inner vmap walks the
             # stations PACKED into this mesh slot (stations_per_slot > 1
             # when the mesh folds more stations than device slots, same
             # contract as FederationMesh.fed_map)
             def one_station(tok):
-                loss, grads = jax.value_and_grad(loss_local)(
-                    params, tok, self.cfg
-                )
+                (loss, load), grads = jax.value_and_grad(
+                    _loss_and_load, has_aux=True
+                )(params, tok, self.cfg, SEQ_AXIS)
                 # reduce over sequence shards WITHIN the station only
                 grads = lax.psum(grads, SEQ_AXIS)
                 loss = lax.pmean(loss, SEQ_AXIS)
-                return loss, grads
+                if load is not None:
+                    load = lax.psum(load, SEQ_AXIS)
+                return loss, grads, load
 
             with jax.named_scope("local_train"):
                 return jax.vmap(one_station)(tokens_block)
@@ -269,11 +511,11 @@ class FedTransformer:
         # works around the pallas-interpret + VMA interaction that rejects
         # the flash kernel inside a checked shard_map (jax 0.9 asks for
         # exactly this workaround).
-        losses, grads = jax.shard_map(
+        losses, grads, loads = jax.shard_map(
             station_body,
             mesh=self.mesh,
             in_specs=(P(), P(STATION_AXIS, None, SEQ_AXIS)),
-            out_specs=(P(STATION_AXIS), P(STATION_AXIS)),
+            out_specs=(P(STATION_AXIS), P(STATION_AXIS), P(STATION_AXIS)),
             check_vma=False,
         )(params, tokens)
         # explicit cross-station aggregation: the ONLY place station data mixes
@@ -284,7 +526,10 @@ class FedTransformer:
             )
             params = optax.apply_updates(params, updates)
         loss = collectives.fed_mean(losses, mask=mask)
-        return params, opt_state, loss
+        # the expert layers' counts, summed over the stations ([L, E_held]
+        # and [L]); None for a block without experts
+        load = jax.tree.map(lambda x: jnp.sum(x, axis=0), loads)
+        return params, opt_state, loss, load
 
 
 def make_engine(
