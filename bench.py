@@ -586,7 +586,9 @@ def worker_transformer() -> None:
     params, opt = eng.init(jax.random.key(0))
     mask = jnp.ones(1)
     t0 = time.perf_counter()
-    jax.block_until_ready(eng.round(params, opt, tokens, mask))  # compile + warm
+    # compile + warm; a round consumes its state, so go on from its outputs
+    params, opt, _ = jax.block_until_ready(
+        eng.round(params, opt, tokens, mask))
     compile_s = time.perf_counter() - t0
 
     def step(state, i):
@@ -657,7 +659,9 @@ def worker_fedoverhead() -> None:
         )
         params, opt = eng.init(jax.random.key(0))
         mask = jnp.ones(n_stations)
-        jax.block_until_ready(eng.round(params, opt, tokens, mask))  # warm
+        # warm; a round consumes its state, so go on from its outputs
+        params, opt, _ = jax.block_until_ready(
+            eng.round(params, opt, tokens, mask))
 
         def step(state, i):
             p, o = state

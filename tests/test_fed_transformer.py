@@ -1,5 +1,8 @@
 """Federated sequence-parallel transformer on the fake 8-device pod:
 4 stations x 2 sequence shards; loss decreases; isolation holds."""
+import re
+import warnings
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -39,8 +42,11 @@ def test_dropout_station_changes_aggregate(engine):
     params, opt_state = engine.init(jax.random.key(1))
     full_mask = jnp.ones(4)
     drop_mask = jnp.asarray([1.0, 1.0, 1.0, 0.0])
+    # a round consumes the state it is handed: the second one gets a copy
+    # taken before the first
+    kept = jax.tree.map(jnp.copy, (params, opt_state))
     p_full, _, _ = engine.round(params, opt_state, sharded, full_mask)
-    p_drop, _, _ = engine.round(params, opt_state, sharded, drop_mask)
+    p_drop, _, _ = engine.round(*kept, sharded, drop_mask)
     # station 3's data influenced the full aggregate but not the dropped one
     diff = jax.tree.leaves(
         jax.tree.map(lambda a, b: float(jnp.max(jnp.abs(a - b))), p_full, p_drop)
@@ -191,6 +197,92 @@ class TestStationPacking:
     def test_too_few_devices_for_seq_shards_rejected(self):
         with pytest.raises(ValueError, match="sequence shards"):
             FT.make_engine(n_stations=1, seq_devices=64, cfg=self._cfg())
+
+
+# a dense block with a station on each of four devices (the state
+# replicated over them, as the four-chip cell holds it), the same block
+# packed on one device, and a block with a router and an expert layer
+_TINY = dict(vocab=97, d_model=32, n_heads=4, n_layers=2, max_len=16,
+             attention="recompute", flash_interpret=True)
+CONSUMERS = {
+    "dense-sharded": (_TINY, 4),
+    "dense-packed": (_TINY, 1),
+    "experts-packed": (dict(
+        _TINY, remat=True, norm="rmsnorm", n_kv_heads=2, positions="rotary",
+        rope_layout=(0, 1), window=8, window_layout=(0, 1), ffn="experts",
+        n_experts=4, top_k=2, d_expert=16, experts_held=(2, 3),
+        tie_head=False), 1),
+}
+
+
+def _alive(tree) -> list[bool]:
+    return [not leaf.is_deleted() for leaf in jax.tree.leaves(tree)]
+
+
+@pytest.mark.parametrize("block", CONSUMERS)
+class TestTheRoundConsumesItsState:
+    """`FedTransformer.round` donates `params` and `opt_state`: the state
+    handed in is gone when it returns and the new one lies in its buffers;
+    `tokens` and `mask` are the caller's still."""
+
+    @pytest.fixture
+    def built(self, block):
+        kwargs, n_devices = CONSUMERS[block]
+        engine = FT.make_engine(4, 1, FT.TransformerConfig(**kwargs),
+                                devices=jax.devices()[:n_devices])
+        tokens = engine.shard_tokens(FT.make_federated_tokens(4, 2, 16, 97))
+        return engine, (*engine.init(jax.random.key(0)), tokens, jnp.ones(4))
+
+    def test_the_state_is_deleted_and_the_batch_is_not(self, built):
+        engine, (params, opt_state, tokens, mask) = built
+        was = jax.tree.map(lambda x: (x.shape, x.dtype, x.sharding),
+                           (params, opt_state))
+        *state, loss = engine.round(params, opt_state, tokens, mask)
+        assert not any(_alive((params, opt_state)))
+        assert all(_alive((tokens, mask, state, loss)))
+        # what comes back is placed as what went in, so the next round's
+        # outputs find the same buffers again (and no second program)
+        assert was == jax.tree.map(
+            lambda x: (x.shape, x.dtype, x.sharding), tuple(state))
+        programs = engine._round._cache_size()
+        engine.round(*state, tokens, mask)
+        assert not any(_alive(state))
+        assert engine._round._cache_size() == programs
+
+    def test_every_donated_leaf_finds_an_output(self, built):
+        """jax warns "Some donated buffers were not usable" for a leaf no
+        output matches; XLA's table of aliases then names each of them."""
+        engine, args = built
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            compiled = engine._round.lower(engine, *args).compile()
+            jax.block_until_ready(engine.round(*args))
+        n_state = 3 * len(jax.tree.leaves(args[0])) + 1
+        aliased = re.findall(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+                             compiled.as_text())
+        assert len(aliased) == n_state
+        assert sorted(int(arg) for _, arg in aliased) == list(range(n_state))
+
+    def test_rebinding_reads_what_a_caller_who_copies_first_reads(
+            self, built):
+        engine, (params, opt_state, tokens, mask) = built
+        start = jax.tree.map(jnp.copy, (params, opt_state))
+        rebound = []
+        for _ in range(3):
+            params, opt_state, loss = engine.round(
+                params, opt_state, tokens, mask)
+            rebound.append(float(loss))
+        copied, state = [], start
+        for _ in range(3):
+            kept = state
+            *state, loss = engine.round(
+                *jax.tree.map(jnp.copy, kept), tokens, mask)
+            assert all(_alive(kept))  # the copy went, not the original
+            copied.append(float(loss))
+        assert rebound == copied and rebound[2] < rebound[0]
+        for a, b in zip(jax.tree.leaves((params, opt_state)),
+                        jax.tree.leaves(state)):
+            np.testing.assert_array_equal(a, b)
 
 
 class TestRemat:

@@ -225,6 +225,10 @@ def test_one_engine_call_with_one_launch_under_it(engine, caller):
     assert launch["kind"] == "device"
     assert launch["trace_id"] == call["trace_id"]
     assert launch["attrs"]["n_buffers"] == n_buffers
+    # the transformer counts the leaves it donates (all it was handed but
+    # `tokens` and `mask`); FedAvg's launches record none
+    assert launch["attrs"].get("n_donated") == (
+        n_buffers - 2 if engine == "fed_transformer.round" else None)
     assert launch["attrs"]["function"].startswith(name)
     assert 0 < launch["dur"] <= call["dur"]
     if caller == "joined":
@@ -244,6 +248,20 @@ def test_the_engine_call_says_which_gather_its_program_was_built_with(
     ENGINES[engine](y_dtype=y_dtype)
     (call,) = _named(TRACER.drain(), "engine.call")
     assert call["attrs"]["gather"] == path
+
+
+@pytest.mark.parametrize("block", ["dense", "experts"])
+def test_a_transformer_launch_counts_the_state_it_donates(block):
+    """`n_donated` beside `n_buffers`: every leaf of `params` and
+    `opt_state` (three trees of the parameters' shape and Adam's count)."""
+    engine, args = {"dense": _transformer,
+                    "experts": _transformer_experts}[block]()
+    engine.round(*args)
+    (launch,) = _named(TRACER.drain(), "device.launch")
+    n_params = len(jax.tree.leaves(args[0]))
+    assert launch["attrs"] == {
+        "function": "fed_transformer.round",
+        "n_buffers": 3 * n_params + 3, "n_donated": 3 * n_params + 1}
 
 
 def test_a_launch_that_compiles_has_the_compile_under_it():
@@ -302,7 +320,9 @@ def _host_events(log_dir):
 
 def test_a_span_is_a_host_event_of_a_profiler_session(tmp_path):
     engine, args = _transformer()
-    engine.round(*args)  # compiled before the session
+    # compiled before the session; the round consumes its state, so the
+    # traced one goes on from what this one returns
+    args = (*engine.round(*args)[:2], *args[2:])
     TRACER.clear()
     jax.profiler.start_trace(str(tmp_path))
     try:

@@ -86,6 +86,13 @@ def inputs():
     return got
 
 
+def _fresh_state(engine, inputs):
+    """A state of the round's own: a round consumes what it is handed, and
+    the module's `inputs` are every test's."""
+    params = jax.tree.map(jnp.copy, inputs["params"])
+    return params, engine.optimizer.init(params)
+
+
 # ------------------------------------- the new block against the reference
 def test_the_rounds_follow_the_plain_reference(inputs):
     """`make_engine` + `FedTransformer.round` on the tiny SmallThinker: the
@@ -96,13 +103,12 @@ def test_the_rounds_follow_the_plain_reference(inputs):
     TRACER.clear()
     engine = FT.make_engine(2, 1, _block_config(), lr=CONFIG["adam"]["lr"],
                             devices=jax.devices()[:1])
-    params, mask = inputs["params"], inputs["mask"]
-    opt_state = engine.optimizer.init(params)
+    params, opt_state = _fresh_state(engine, inputs)
     losses, grad_norms = [], None
     for step in range(2):
         params, opt_state, loss = engine.round(
             params, opt_state, engine.shard_tokens(inputs["tokens"][step]),
-            mask)
+            inputs["mask"])
         losses.append(float(loss))
         if step == 0:
             grad_norms = compare.leaf_norms(opt_state[0].mu, scale=10.0)
@@ -133,7 +139,7 @@ def test_the_rounds_follow_the_plain_reference(inputs):
 def test_one_round_holds_the_reference_counts_per_layer_and_expert(inputs):
     engine = FT.make_engine(2, 1, _block_config(),
                             devices=jax.devices()[:1])
-    engine.round(inputs["params"], engine.optimizer.init(inputs["params"]),
+    engine.round(*_fresh_state(engine, inputs),
                  engine.shard_tokens(inputs["tokens"][1]), inputs["mask"])
     recorded = engine.record_expert_load()
     want = REFERENCE.expert_load(CONFIG, inputs["params"],
@@ -289,7 +295,10 @@ def test_key_blocks_outside_the_window_are_not_visited():
 # taken on the parent commit (51a384b) with the script in PERF.md section 6:
 # sha256 of `engine._round.lower(...).as_text()` (the program as XLA gets
 # it), the first loss and the norm of the first gradient, for
-# TransformerConfig(vocab=97, d_model=32, n_heads=4, n_layers=2, max_len=16)
+# TransformerConfig(vocab=97, d_model=32, n_heads=4, n_layers=2, max_len=16).
+# That commit's `_round` donated nothing: the hashes are held to the round's
+# body under a `jax.jit` that donates nothing either, and the program that
+# runs to that text plus the donated arguments' attributes (`_DONOR`)
 PARENT = {
     ("recompute", False): (
         "ab64276fceb32c2de7b2d8b259306afa5ddd247d72364c7f68bac31ead50fe38",
@@ -326,25 +335,58 @@ def _parent_init_params(key, cfg):
     return params
 
 
-@pytest.mark.parametrize("attention,remat", sorted(PARENT))
-def test_the_default_block_is_the_parents_bit_for_bit(attention, remat):
-    """`init_params` gives the parent's arrays, `_round` lowers to the
-    parent's program (so its loss and gradient are the parent's bits on any
-    machine), and on this one they read the parent's golden values."""
+# what `donate_argnums` writes on an argument of the lowered `main`: the
+# output it is aliased to or, where jax leaves the pairing to XLA, a mark
+_DONOR = re.compile(
+    r", (?:tf\.aliasing_output = \d+ : i32|jax\.buffer_donor = true)")
+
+
+def _default_block(attention, remat):
     cfg = FT.TransformerConfig(
         vocab=97, d_model=32, n_heads=4, n_layers=2, max_len=16,
         attention=attention, flash_interpret=True, remat=remat)
     engine = FT.make_engine(4, 1, cfg, devices=jax.devices()[:1])
-    params, opt_state = engine.init(jax.random.key(0))
+    tokens = engine.shard_tokens(FT.make_federated_tokens(4, 2, 16, 97))
+    return engine, (*engine.init(jax.random.key(0)), tokens, jnp.ones(4))
+
+
+def _body_text(engine, args) -> str:
+    """The round's body lowered under a `jax.jit` that donates nothing."""
+    body = FT.FedTransformer._round.__wrapped__
+    return jax.jit(body, static_argnums=0).lower(engine, *args).as_text()
+
+
+@pytest.mark.parametrize("attention,remat", sorted(PARENT))
+def test_donation_marks_the_states_arguments_and_changes_nothing_else(
+        attention, remat):
+    """The program that runs is the body's text with one attribute more on
+    each leaf of `params` and `opt_state` (three trees of the parameters'
+    shape and Adam's count) and none on `tokens` or `mask`: the same work,
+    written into the buffers it was handed."""
+    engine, args = _default_block(attention, remat)
+    text = engine._round.lower(engine, *args).as_text()
+    stripped, n_marked = _DONOR.subn("", text)
+    assert stripped == _body_text(engine, args)
+    assert n_marked == 3 * len(jax.tree.leaves(args[0])) + 1
+    main = re.search(r"func\.func public @main\((.*?)\) -> ", text).group(1)
+    marked = [bool(_DONOR.search(a)) for a in main.split("%arg")[1:]]
+    assert marked == [True] * n_marked + [False, False]
+
+
+@pytest.mark.parametrize("attention,remat", sorted(PARENT))
+def test_the_default_block_is_the_parents_bit_for_bit(attention, remat):
+    """`init_params` gives the parent's arrays, `_round`'s body lowers to
+    the parent's program (so its loss and gradient are the parent's bits on
+    any machine), and on this one they read the parent's golden values."""
+    engine, (params, opt_state, tokens, mask) = _default_block(
+        attention, remat)
+    cfg = engine.cfg
     want = _parent_init_params(jax.random.key(0), cfg)
     assert jax.tree.structure(params) == jax.tree.structure(want)
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(want)):
         assert np.array_equal(a, b)
-    tokens = engine.shard_tokens(FT.make_federated_tokens(4, 2, 16, 97))
-    mask = jnp.ones(4)
     lowered, loss_hex, grad_norm = PARENT[attention, remat]
-    text = engine._round.lower(engine, params, opt_state, tokens,
-                               mask).as_text()
+    text = _body_text(engine, (params, opt_state, tokens, mask))
     assert hashlib.sha256(text.encode()).hexdigest() == lowered
     _, new_state, loss = engine.round(params, opt_state, tokens, mask)
     assert float(loss) == pytest.approx(float.fromhex(loss_hex), rel=1e-6)

@@ -116,14 +116,19 @@ def engine_call(engine: str, rounds: int, **attrs: Any):
     )
 
 
-def device_launch(function: str, n_buffers: int):
+def device_launch(
+    function: str, n_buffers: int, n_donated: int | None = None
+):
     """The ``device.launch`` span: the call into a compiled program and
     nothing else. ``n_buffers`` is the array leaves of the dynamic
-    arguments, counted as arrays, not as per-chip shards."""
-    return TRACER.span(
-        "device.launch", kind="device",
-        attrs={"function": function, "n_buffers": n_buffers},
-    )
+    arguments, counted as arrays, not as per-chip shards; ``n_donated``,
+    where the caller gives it, is those of them handed over as donated
+    arguments (outputs may live in their buffers, so the launch need not
+    allocate them)."""
+    attrs = {"function": function, "n_buffers": n_buffers}
+    if n_donated is not None:
+        attrs["n_donated"] = n_donated
+    return TRACER.span("device.launch", kind="device", attrs=attrs)
 
 
 class _LeafSig(NamedTuple):

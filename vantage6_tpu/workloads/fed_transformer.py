@@ -439,15 +439,23 @@ class FedTransformer:
     ) -> tuple[Any, Any, jax.Array]:
         """One federated round: per-station grads (sp inside), FedAvg, step.
 
+        The state handed in is CONSUMED: ``params`` and ``opt_state`` are
+        donated to the program, which writes the new state into their
+        buffers, so every array of both is deleted when this returns. Go on
+        with what is returned; a caller who wants the old state copies it
+        first (``jax.tree.map(jnp.copy, ...)``). ``tokens`` and ``mask``
+        are left alone.
+
         Recorded as an ``engine.call`` span over the whole host side of the
         call and, under it, a ``device.launch`` span around the call into
         the jitted program and nothing else (``n_buffers`` = the array
-        leaves handed over)."""
-        args = (params, opt_state, tokens, mask)
+        leaves handed over, ``n_donated`` = those of them the program may
+        write its outputs into)."""
         with engine_call("fed_transformer.round", 1):
-            n_buffers = len(jax.tree.leaves(args))
-            with device_launch("fed_transformer.round", n_buffers):
-                *out, load = self._round(*args)
+            n_donated = len(jax.tree.leaves((params, opt_state)))
+            n_buffers = n_donated + len(jax.tree.leaves((tokens, mask)))
+            with device_launch("fed_transformer.round", n_buffers, n_donated):
+                *out, load = self._round(params, opt_state, tokens, mask)
         if load is not None:  # stays on the device: record_expert_load
             self._expert_load.append(load)
         return tuple(out)
@@ -481,7 +489,10 @@ class FedTransformer:
             pass
         return attrs
 
-    @partial(jax.jit, static_argnums=0)
+    # params and opt_state are donated: every state output has an input of
+    # its shape, dtype and placement, so the runtime allocates only the loss
+    # (and the experts' counts) before the program may start
+    @partial(jax.jit, static_argnums=0, donate_argnums=(1, 2))
     def _round(
         self, params: Any, opt_state: Any, tokens: jax.Array,
         mask: jax.Array,
