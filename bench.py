@@ -374,6 +374,13 @@ def _timed(jax, step, state, n: int = TIMED_RUNS):
     return state, times
 
 
+def _fresh(jax, tree):
+    """A copy of ``tree``: the fused programs consume the ``params`` and
+    ``opt_state`` they are handed, so a state that is stepped from more
+    than once is copied first."""
+    return jax.tree.map(jax.numpy.copy, tree)
+
+
 def worker_spmd() -> None:
     """rounds/sec of the one-program SPMD FedAvg path + final accuracy.
 
@@ -406,7 +413,8 @@ def worker_spmd() -> None:
     t0 = time.perf_counter()
     compiled = engine._run.lower(*args, n_rounds=rounds).compile()
     compile_s = time.perf_counter() - t0
-    jax.block_until_ready(compiled(*args))  # warm (buffer placement)
+    # warm (buffer placement)
+    jax.block_until_ready(compiled(*_fresh(jax, args[:2]), *args[2:]))
 
     def step(state, i):
         p, o = state
@@ -415,7 +423,7 @@ def worker_spmd() -> None:
         )
         return (p, o), losses
 
-    _, times = _timed(jax, step, (params, opt_state))
+    _, times = _timed(jax, step, _fresh(jax, (params, opt_state)))
     dt = _median(times)
     # the timed chain's final params are TIMED_RUNS * rounds deep into
     # training; evaluate a FRESH acc-leg run from init instead so both
@@ -492,7 +500,9 @@ def worker_fused() -> None:
             ps, os_, sx, sy, counts, rk, mask=mask
         )
         seq_losses.append(float(loss))
-    pf, _, losses_f, _ = fused(params, opt_state, sx, sy, counts, mask, key_id)
+    pf, _, losses_f, _ = fused(
+        *_fresh(jax, (params, opt_state)), sx, sy, counts, mask, key_id
+    )
     identical = all(
         bool(jnp.array_equal(a, b))
         for a, b in zip(jax.tree.leaves(pf), jax.tree.leaves(ps))
@@ -507,7 +517,9 @@ def worker_fused() -> None:
     acc_fused = W.evaluate(pf, ex, ey)
     acc_seq = W.evaluate(ps, ex, ey)
 
-    jax.block_until_ready(fused(params, opt_state, sx, sy, counts, mask, key))
+    jax.block_until_ready(
+        fused(*_fresh(jax, (params, opt_state)), sx, sy, counts, mask, key)
+    )
 
     def fused_step(state, i):
         p, o = state
@@ -524,7 +536,7 @@ def worker_fused() -> None:
             float(loss)  # per-round host pull: the per-round driver shape
         return (p, o), loss
 
-    _, f_times = _timed(jax, fused_step, (params, opt_state))
+    _, f_times = _timed(jax, fused_step, _fresh(jax, (params, opt_state)))
     _, s_times = _timed(jax, seq_step, (params, opt_state))
     fused_dt, seq_dt = _median(f_times), _median(s_times)
     print(json.dumps({
@@ -765,7 +777,7 @@ def worker_agg() -> None:
         )
         # placed as the engine's own entry places them (see worker_spmd)
         p_in, opt0, c_in, m_in, k_in = eng._place(
-            p0, eng.init(p0), counts, mask, key
+            _fresh(jax, p0), eng.init(p0), counts, mask, key
         )
         args = (p_in, opt0, sx, sy, c_in, m_in, k_in)
         # memory_stats() peaks are PROCESS-LIFETIME monotonic: a per-mode
@@ -788,7 +800,7 @@ def worker_agg() -> None:
             )
             return (p, o), losses
 
-        _, times = _timed(jax, step, (p1, o1))
+        _, times = _timed(jax, step, _fresh(jax, (p1, o1)))
         dt = _median(times)
         # the warm call already ran this deterministic program on `args`
         final_params[name] = p1
@@ -1915,12 +1927,12 @@ def worker_observability() -> None:
         rep_eng = FedAvg(mesh, FedAvgSpec(**kw))
         scat_eng = FedAvg(mesh, FedAvgSpec(**kw, shard_server_update=True))
         _, _, losses_rep, stats_rep = rep_eng.run_rounds(
-            p0, jnp.asarray(x), jnp.asarray(y), counts, key, rounds,
-            donate=False,
+            jnp.copy(p0), jnp.asarray(x), jnp.asarray(y), counts, key,
+            rounds,
         )
         _, _, _, stats_scat = scat_eng.run_rounds(
-            p0, jnp.asarray(x), jnp.asarray(y), counts, key, rounds,
-            donate=False,
+            jnp.copy(p0), jnp.asarray(x), jnp.asarray(y), counts, key,
+            rounds,
         )
         fp32_identical = all(
             np.array_equal(
@@ -2346,7 +2358,7 @@ def worker_compression() -> None:
         )
         # placed as the engine's own entry places them (see worker_spmd)
         p_in, opt0, c_in, m_in, k_in = eng._place(
-            p0, eng.init(p0), counts, mask, key
+            _fresh(jax, p0), eng.init(p0), counts, mask, key
         )
         args = (p_in, opt0, sx, sy, c_in, m_in, k_in)
         t0 = time.perf_counter()
@@ -2362,7 +2374,7 @@ def worker_compression() -> None:
             )
             return (p, o), ls
 
-        _, times = _timed(jax, step, (p1, o1))
+        _, times = _timed(jax, step, _fresh(jax, (p1, o1)))
         dt = _median(times)
         per_arm[name] = {
             "rounds_per_sec": round(rounds / dt, 3),
@@ -2590,12 +2602,12 @@ def worker_autopilot() -> None:
     key = jax.random.key(3)
     sm_rounds = 6
     _, _, losses_poisoned, stats = eng.run_rounds(
-        p0, jnp.asarray(x), jnp.asarray(y), counts, key, sm_rounds,
-        donate=False,
+        jnp.copy(p0), jnp.asarray(x), jnp.asarray(y), counts, key,
+        sm_rounds,
     )
     _, _, losses_clean, _ = eng.run_rounds(
-        p0, jnp.asarray(x), jnp.asarray(y_clean), counts, key, sm_rounds,
-        donate=False,
+        jnp.copy(p0), jnp.asarray(x), jnp.asarray(y_clean), counts, key,
+        sm_rounds,
     )
 
     actuator = ArrayActuator(S2)
@@ -2616,8 +2628,8 @@ def worker_autopilot() -> None:
         # hands-off recovery: rerun under the mask the AUTOPILOT set
         mask = jnp.asarray(actuator.participation_mask())
         _, _, losses_masked, _ = eng.run_rounds(
-            p0, jnp.asarray(x), jnp.asarray(y), counts, key, sm_rounds,
-            mask=mask, donate=False,
+            jnp.copy(p0), jnp.asarray(x), jnp.asarray(y), counts, key,
+            sm_rounds, mask=mask,
         )
         # alert clear -> revert: with the poisoned history gone the
         # anomalous_station rule proposes nothing and the engaged mask

@@ -253,7 +253,7 @@ def phase_fedavg_cnn(sz, meter, shared) -> dict[str, Any]:
     )
 
     # ... and K again from the returned state: the steady state
-    run = engine._run_donating
+    run = engine._run
     before = (run.stats()["compiles"], meter.compiles)
     t0 = time.perf_counter()
     p2, o2, losses2, _ = engine.run_rounds(

@@ -25,6 +25,7 @@ if "xla_force_host_platform_device_count" not in flags:
     ).strip()
 
 import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -36,3 +37,11 @@ def devices():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 fake CPU devices, got {devs}"
     return devs
+
+
+@pytest.fixture(scope="session")
+def fresh():
+    """``fresh(tree)``: a copy of a state. ``FedAvg.run_rounds`` and
+    ``run_rounds_async`` consume the state they are handed, so a test that
+    steps more than once from one init hands each call a copy."""
+    return lambda tree: jax.tree.map(jnp.copy, tree)

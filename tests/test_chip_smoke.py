@@ -183,7 +183,7 @@ def test_second_run_rounds_compiles_nothing(devices):
     engine.run_rounds(
         p, sx, sy, counts, jax.random.fold_in(key, 2), 2, opt_state=o
     )
-    stats = engine._run_donating.stats()
+    stats = engine._run.stats()
     assert (stats["compiles"], stats["retraces"], stats["fallbacks"]) == (
         1, 0, 0
     ), stats["last_compile"].get("changed")

@@ -416,8 +416,9 @@ class TestTreePacking:
 
 # ------------------------------------------------------------ FedAvg engine
 @pytest.fixture(scope="module")
-def tiny_fed():
-    """A tiny 8-station linear-regression federation (fast on CPU)."""
+def tiny_fed(fresh):
+    """A tiny 8-station linear-regression federation (fast on CPU).
+    ``p0`` is shared: ``run_rounds`` gets ``fresh(p0)``."""
     from vantage6_tpu.core.mesh import FederationMesh
     from vantage6_tpu.fed.fedavg import FedAvg, FedAvgSpec
 
@@ -444,14 +445,14 @@ def tiny_fed():
         ))
 
     return {"mesh": mesh, "sx": sx, "sy": sy, "counts": counts, "p0": p0,
-            "engine": engine}
+            "engine": engine, "fresh": fresh}
 
 
 class TestFedAvgCompressed:
     def _run(self, fed, eng, rounds=4):
         return eng.run_rounds(
-            fed["p0"], fed["sx"], fed["sy"], fed["counts"],
-            jax.random.key(0), n_rounds=rounds, donate=False,
+            fed["fresh"](fed["p0"]), fed["sx"], fed["sy"], fed["counts"],
+            jax.random.key(0), n_rounds=rounds,
         )
 
     def test_lossless_compressor_is_fp32_identical(self, tiny_fed):
@@ -503,7 +504,7 @@ class TestFedAvgCompressed:
         # is the same pytree shape)
         p2, state2, _, _ = eng.run_rounds(
             p1, tiny_fed["sx"], tiny_fed["sy"], tiny_fed["counts"],
-            jax.random.key(2), n_rounds=2, opt_state=state1, donate=False,
+            jax.random.key(2), n_rounds=2, opt_state=state1,
         )
         assert np.asarray(state2["ef"]).shape == (8, 13)
 
@@ -525,9 +526,9 @@ class TestFedAvgCompressed:
         eng = tiny_fed["engine"](compressor=spec)
         mask = jnp.asarray([1, 1, 0, 1, 1, 1, 1, 1], jnp.float32)
         params, _, losses, _ = eng.run_rounds(
-            tiny_fed["p0"], tiny_fed["sx"], tiny_fed["sy"],
-            tiny_fed["counts"], jax.random.key(0), n_rounds=2, mask=mask,
-            donate=False,
+            tiny_fed["fresh"](tiny_fed["p0"]), tiny_fed["sx"],
+            tiny_fed["sy"], tiny_fed["counts"], jax.random.key(0),
+            n_rounds=2, mask=mask,
         )
         for leaf in jax.tree.leaves(params):
             assert np.isfinite(np.asarray(leaf)).all()

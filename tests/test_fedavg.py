@@ -39,14 +39,13 @@ def test_loss_decreases_and_learns(mesh, small_engine, fed_data):
     assert acc > 0.5, f"accuracy {acc} not above chance"
 
 
-def test_run_rounds_deterministic(mesh, small_engine, fed_data):
+def test_run_rounds_deterministic(mesh, small_engine, fed_data, fresh):
     sx, sy, counts = fed_data
     key = jax.random.key(7)
     p0 = W.init_params(jax.random.fold_in(key, 1))
-    # donate=False: p0/key are reused across calls, so the default donating
-    # fast path (which consumes its inputs) must be opted out of here
-    r1 = small_engine.run_rounds(p0, sx, sy, counts, key, 3, donate=False)[2]
-    r2 = small_engine.run_rounds(p0, sx, sy, counts, key, 3, donate=False)[2]
+    # p0 is stepped from twice, and run_rounds consumes what it is handed
+    r1 = small_engine.run_rounds(fresh(p0), sx, sy, counts, key, 3)[2]
+    r2 = small_engine.run_rounds(fresh(p0), sx, sy, counts, key, 3)[2]
     np.testing.assert_array_equal(np.asarray(r1), np.asarray(r2))
 
 
@@ -139,8 +138,7 @@ def _three_rounds(engine, call, params, x, y, counts):
     dispatch or by three `round()` calls over the same keys."""
     key = jax.random.key(9)
     if call == "run_rounds":
-        p, _, losses, stats = engine.run_rounds(
-            params, x, y, counts, key, 3, donate=False)
+        p, _, losses, stats = engine.run_rounds(params, x, y, counts, key, 3)
         return jax.device_get((p, losses, stats))
     p, state, out = params, engine.init(params), []
     for k in jax.random.split(key, 3):
@@ -198,7 +196,7 @@ def _lower_run(engine, params, x, y, counts, n_rounds=3):
     p, state, counts, mask, key = engine._place(
         params, engine.init(params), counts, jnp.ones(8), jax.random.key(1))
     return engine._run.lower(p, state, x, y, counts, mask, key,
-                             n_rounds=n_rounds, unroll=1)
+                             n_rounds=n_rounds)
 
 
 @pytest.mark.parametrize("n_rounds", [1, 3, 5])
@@ -214,6 +212,45 @@ def test_one_gather_in_the_local_step_and_the_pack_outside_the_rounds(
     join = "(tensor<8x16x5xui32>, tensor<8x16x1xui32>) -> tensor<8x16x6xui32>"
     assert text.count(join) == 1
     assert main.index(join) < main.index("stablehlo.while")
+
+
+# taken on the parent commit (eddfcac) with PYTHONPATH=<its checkout>: sha256
+# of `engine._run_donating.lower(...).as_text()`, the program the engine cell
+# ran there, for the engine the benchmark's own entry builds at that cell's
+# tiny sizes and its K (the lines below with `_run_donating` for `_run`)
+PARENT_RUN_DONATING = (
+    "7f6baa01a279d334a8d6f15f10127bb199589c893cb997d13e2609a120a9c2ca")
+
+
+def test_run_rounds_lowers_to_the_parents_donating_program():
+    """With the twins gone `run_rounds` dispatches `_run`, and `_run` is
+    the parent's `_run_donating` to the letter: same module name, same
+    text, same donated arguments, so the same entry of a compile cache."""
+    import hashlib
+    import json
+    from pathlib import Path
+
+    from perfbench import cells
+
+    cell = cells.load_cell("logreg32.engine-1chip")
+    tiny = Path(__file__).parent / "benchmark" / "data" / "tiny"
+    for held, name in ((cell.config, "config.logreg-tabular-32st"),
+                       (cell.traffic, "traffic.engine-1chip")):
+        held.update(json.loads((tiny / f"{name}.json").read_text())["sizes"])
+    key = jax.random.key(0)
+    program = cell.entry_module().build(
+        cell.config, cell.traffic,
+        lambda: cell.reference_module().make_inputs(
+            cell.config, cell.traffic, key),
+        jax.devices()[:1])
+    engine = program.engine
+    p, state, counts, mask, key = engine._place(
+        program.params, program.opt_state, program.counts, program.mask, key)
+    text = engine._run.lower(
+        p, state, program.x, program.y, counts, mask, key,
+        n_rounds=cell.traffic["rounds_per_dispatch"]).as_text()
+    assert "jit__run_impl" in text and text.count("tf.aliasing_output") == 2
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_RUN_DONATING
 
 
 @pytest.mark.parametrize("path", ["packed", "separate"])

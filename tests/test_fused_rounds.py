@@ -8,13 +8,6 @@ zero host round-trips) and must be fp32-IDENTICAL to K sequential
 Bitwise, not allclose: the fused body is the very `_one_round` the
 per-round path jits, so ANY drift is a real seam leak (mask plumbing, EF
 carry, staleness bookkeeping), never fp noise.
-
-The Python-unrolled form (`unroll=True` / `local_unroll=True` — the
-XLA:CPU fast path, docs/device_speed.md "K-selection") is the one
-deliberate exception: XLA lowers convolutions differently in
-straight-line code, and a one-ULP conv difference amplifies chaotically
-over rounds on a barely-trained model. It is held to tight one-round
-closeness plus K-round loss-trajectory agreement instead.
 """
 import jax
 import jax.numpy as jnp
@@ -87,11 +80,11 @@ def assert_trees_identical(a, b, what):
         )
 
 
-def check_identity(engine, fed_data, init, mask=None, n_rounds=K):
+def check_identity(engine, fed_data, init, fresh, mask=None, n_rounds=K):
     sx, sy, counts = fed_data
     params, key = init
     fp, fo, fl, fs = engine.run_rounds(
-        params, sx, sy, counts, key, n_rounds, mask=mask, donate=False
+        fresh(params), sx, sy, counts, key, n_rounds, mask=mask
     )
     sp, so, sl, ss = sequential(
         engine, params, sx, sy, counts, key, n_rounds, mask=mask
@@ -104,42 +97,42 @@ def check_identity(engine, fed_data, init, mask=None, n_rounds=K):
 
 
 # ------------------------------------------------------------- identities
-def test_dense_identity(mesh, fed_data, init):
-    check_identity(make(mesh), fed_data, init)
+def test_dense_identity(mesh, fed_data, init, fresh):
+    check_identity(make(mesh), fed_data, init, fresh)
 
 
-def test_compressed_ef_identity(mesh, fed_data, init):
+def test_compressed_ef_identity(mesh, fed_data, init, fresh):
     """Top-k + int8 compression: the per-station error-feedback carry
     must ride the scan exactly as it rides sequential opt_states."""
     eng = make(
         mesh, compressor=CompressorSpec(topk_ratio=0.25, int8=True, chunk=8)
     )
-    check_identity(eng, fed_data, init)
+    check_identity(eng, fed_data, init, fresh)
 
 
-def test_scattered_zero1_identity(mesh, fed_data, init):
+def test_scattered_zero1_identity(mesh, fed_data, init, fresh):
     """ZeRO-1 sharded server update (FedAdam moments scattered over
     stations) composes with the fused scan unchanged."""
     eng = make(
         mesh, shard_server_update=True, server_optimizer=optax.adam(1e-2)
     )
-    check_identity(eng, fed_data, init)
+    check_identity(eng, fed_data, init, fresh)
 
 
-def test_masked_identity_single_roster(mesh, fed_data, init):
+def test_masked_identity_single_roster(mesh, fed_data, init, fresh):
     mask = np.ones(S, np.float32)
     mask[1] = 0.0
-    check_identity(make(mesh), fed_data, init, mask=jnp.asarray(mask))
+    check_identity(make(mesh), fed_data, init, fresh, mask=jnp.asarray(mask))
 
 
-def test_masked_identity_per_round_roster(mesh, fed_data, init):
+def test_masked_identity_per_round_roster(mesh, fed_data, init, fresh):
     """A [K, S] mask gives each fused round its own roster via the scan
     xs — and must equal a sequential driver passing row i to round i."""
     masks = np.ones((K, S), np.float32)
     masks[0, 2] = 0.0
     masks[2, 0] = 0.0
     masks[3, 3] = 0.0
-    check_identity(make(mesh), fed_data, init, mask=jnp.asarray(masks))
+    check_identity(make(mesh), fed_data, init, fresh, mask=jnp.asarray(masks))
 
 
 def test_per_round_mask_shape_is_validated(mesh, fed_data, init):
@@ -148,11 +141,11 @@ def test_per_round_mask_shape_is_validated(mesh, fed_data, init):
     bad = jnp.ones((K + 1, S), jnp.float32)
     with pytest.raises(ValueError, match="rounds"):
         make(mesh).run_rounds(
-            params, sx, sy, counts, key, K, mask=bad, donate=False
+            params, sx, sy, counts, key, K, mask=bad
         )
 
 
-def test_async_identity(mesh, fed_data, init):
+def test_async_identity(mesh, fed_data, init, fresh):
     """Fused buffered-async (staleness riding the scan carry) equals K
     sequential async_round() calls with host-side FedBuff bookkeeping."""
     eng = make(mesh)
@@ -166,7 +159,7 @@ def test_async_identity(mesh, fed_data, init):
     accepts = jnp.asarray(accepts)
 
     fp, fo, fstale, fl, fs = eng.run_rounds_async(
-        params, sx, sy, counts, key, K, accepts, spec, donate=False
+        fresh(params), sx, sy, counts, key, K, accepts, spec
     )
 
     sp, so = params, eng.init(params)
@@ -196,60 +189,8 @@ def test_async_identity(mesh, fed_data, init):
     assert float(fstale[3]) == 0.0  # re-accepted in rounds 2..3
 
 
-# ------------------------------------------------- unrolled fast path
-def test_unroll_true_matches_scan_one_round(mesh, fed_data, init):
-    """unroll=True (straight-line, XLA:CPU fast path) vs the scan form:
-    same math, conv lowering differs by ~1 ULP — one round stays within
-    1e-4 on every leaf (chaotic amplification needs many rounds)."""
-    eng = make(mesh)
-    sx, sy, counts = fed_data
-    params, key = init
-    a = eng.run_rounds(params, sx, sy, counts, key, 1, donate=False)
-    b = eng.run_rounds(
-        params, sx, sy, counts, key, 1, donate=False, unroll=True
-    )
-    for x, y in zip(jax.tree.leaves(a[0]), jax.tree.leaves(b[0])):
-        np.testing.assert_allclose(
-            np.asarray(x), np.asarray(y), atol=1e-4, rtol=0
-        )
-
-
-def test_unroll_true_k_rounds_same_trajectory(mesh, fed_data, init):
-    """Over K rounds the unrolled form may drift in the low mantissa bits
-    (documented chaos), but the loss trajectory must agree coarsely and
-    the program must still be ONE dispatch with per-round losses."""
-    eng = make(mesh)
-    sx, sy, counts = fed_data
-    params, key = init
-    _, _, scan_l, _ = eng.run_rounds(
-        params, sx, sy, counts, key, K, donate=False
-    )
-    _, _, unr_l, _ = eng.run_rounds(
-        params, sx, sy, counts, key, K, donate=False, unroll=True
-    )
-    assert unr_l.shape == (K,)
-    np.testing.assert_allclose(
-        np.asarray(unr_l), np.asarray(scan_l), atol=0.05, rtol=0
-    )
-
-
-def test_local_unroll_engine_one_round_close(mesh, fed_data, init):
-    """FedAvgSpec.local_unroll=True (inner local-steps loop unrolled)
-    stays within one-round fp-noise of the scan-form engine — the bench's
-    fused-leg precondition."""
-    sx, sy, counts = fed_data
-    params, key = init
-    opt = make(mesh).init(params)
-    a = make(mesh).round(params, opt, sx, sy, counts, key)
-    b = make(mesh, local_unroll=True).round(params, opt, sx, sy, counts, key)
-    for x, y in zip(jax.tree.leaves(a[0]), jax.tree.leaves(b[0])):
-        np.testing.assert_allclose(
-            np.asarray(x), np.asarray(y), atol=1e-4, rtol=0
-        )
-
-
 # ------------------------------------------------- observatory contract
-def test_k_sweep_is_static_sweep_not_retrace(mesh, fed_data, init):
+def test_k_sweep_is_static_sweep_not_retrace(mesh, fed_data, init, fresh):
     """Compiling the fused program at several K values (warmup K=1,
     production K, tail-flush) is a declared static sweep — it must not
     count as a retrace or feed recompile_storm."""
@@ -257,7 +198,7 @@ def test_k_sweep_is_static_sweep_not_retrace(mesh, fed_data, init):
     sx, sy, counts = fed_data
     params, key = init
     for k in (1, 2, 3):
-        eng.run_rounds(params, sx, sy, counts, key, k, donate=False)
+        eng.run_rounds(fresh(params), sx, sy, counts, key, k)
     assert eng._run.retraces == 0
     assert eng._run.static_sweeps >= 2
 

@@ -177,18 +177,18 @@ class TestEngineStats:
         out = eng.round(p0, eng.init(p0), x, y, counts, jax.random.key(0))
         assert out[3] == {}
 
-    def test_fp32_identical_replicated_vs_scattered(self):
+    def test_fp32_identical_replicated_vs_scattered(self, fresh):
         loss_fn, x, y, counts = _toy_problem(flip=1)
         kw = dict(loss_fn=loss_fn, local_steps=2, batch_size=8)
         p0 = jnp.zeros(3)
         key = jax.random.key(1)
         mesh = FederationMesh(4)
         _, _, _, s_rep = FedAvg(mesh, FedAvgSpec(**kw)).run_rounds(
-            p0, x, y, counts, key, 4, donate=False
+            fresh(p0), x, y, counts, key, 4
         )
         _, _, _, s_sc = FedAvg(
             mesh, FedAvgSpec(**kw, shard_server_update=True)
-        ).run_rounds(p0, x, y, counts, key, 4, donate=False)
+        ).run_rounds(fresh(p0), x, y, counts, key, 4)
         for k in s_rep:
             assert np.array_equal(np.asarray(s_rep[k]), np.asarray(s_sc[k]))
 
@@ -202,7 +202,7 @@ class TestEngineStats:
         ))
         p0 = jnp.zeros(3)
         _, _, _, stats = eng.run_rounds(
-            p0, x, y, counts, jax.random.key(0), 3, donate=False
+            p0, x, y, counts, jax.random.key(0), 3
         )
         assert "station_ef_norm" in stats
         # top-k drops mass, so EF accumulators are nonzero
@@ -215,7 +215,7 @@ class TestEngineStats:
         ))
         hist = eng.attach_history("engine-test")
         p0 = jnp.zeros(3)
-        eng.run_rounds(p0, x, y, counts, jax.random.key(0), 3, donate=False)
+        eng.run_rounds(p0, x, y, counts, jax.random.key(0), 3)
         p1 = jnp.zeros(3)
         eng.round(p1, eng.init(p1), x, y, counts, jax.random.key(1))
         assert hist.rounds_total == 4
@@ -526,7 +526,7 @@ class TestLearningRules:
         ))
         hist = eng.attach_history("e2e")
         p0 = jnp.zeros(3)
-        eng.run_rounds(p0, x, y, counts, jax.random.key(0), 5, donate=False)
+        eng.run_rounds(p0, x, y, counts, jax.random.key(0), 5)
         assert hist.rounds_total == 5
         wd = Watchdog(interval=60.0)
         wd.register_feed("learning", LEARNING.feed)

@@ -6,6 +6,7 @@ researcher client) runs in-process over localhost sockets, exercising call
 stacks §3.1 (task → result), §3.2 (central fan-out), and the encryption
 boundary.
 """
+import base64
 import time
 
 import numpy as np
@@ -13,6 +14,7 @@ import pandas as pd
 import pytest
 
 from vantage6_tpu.client import UserClient
+from vantage6_tpu.common import encryption
 from vantage6_tpu.node.daemon import NodeDaemon
 from vantage6_tpu.node.runner import RunSpec, TaskRunner
 from vantage6_tpu.server.app import ServerApp
@@ -263,9 +265,13 @@ def test_encrypted_collaboration_e2e(stack):
             image="v6-average-py",
             input_={"method": "partial_average", "kwargs": {"column": "age"}},
         )
-        # ciphertext at rest: the stored input/result are not plaintext JSON
+        # ciphertext at rest: the stored input is a sealed binary frame,
+        # not plaintext JSON
         raw_runs = stack["client"].run.from_task(task["id"])
-        assert all("$" in (r["input"] or "") for r in raw_runs)
+        for r in raw_runs:
+            stored = base64.b64decode(r["input"])
+            assert stored.startswith(encryption.ENC_MAGIC)
+            assert b"partial_average" not in stored
         results = carol.wait_for_results(task["id"], interval=0.05, timeout=60)
         total = sum(r["sum"] for r in results)
         count = sum(r["count"] for r in results)

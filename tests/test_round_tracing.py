@@ -87,7 +87,7 @@ def _fedavg_lowered(engine, params, data):
                            jnp.ones(8), jax.random.key(1))
     p, state, counts, mask, key = placed
     return engine._run.lower(p, state, x, y, counts, mask, key,
-                             n_rounds=3, unroll=1)
+                             n_rounds=3)
 
 
 # ------------------------------------------------------------------ scopes

@@ -9,9 +9,11 @@ Covers the acceptance contract of the sharded-update PR:
   FedAvg *and* a stateful server optimizer (FedAdam, whose moments live
   sharded);
 - bf16 on-wire deltas stay close to fp32 but are NOT claimed identical;
-- run_rounds donation never breaks `round()` callers or `donate=False`
-  callers that reuse params.
+- `round()` keeps its inputs; `run_rounds` and `run_rounds_async` consume
+  the state they are handed and return one that chains.
 """
+import inspect
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,6 +22,9 @@ import pytest
 
 from vantage6_tpu.core.mesh import FederationMesh
 from vantage6_tpu.fed import collectives as C
+from vantage6_tpu.fed.fedavg import AsyncRoundSpec, FedAvg, FedAvgSpec
+from vantage6_tpu.runtime.federation import Federation
+from vantage6_tpu.runtime.profiling import ObservedFunction
 from vantage6_tpu.workloads import fedavg_mnist as W
 
 RNG = np.random.default_rng(7)
@@ -121,7 +126,8 @@ def fed_data(mesh):
 @pytest.mark.parametrize(
     "server_opt", [None, optax.adam(1e-2)], ids=["fedavg", "fedadam"]
 )
-def test_sharded_server_update_parity_5_rounds(mesh, fed_data, server_opt):
+def test_sharded_server_update_parity_5_rounds(
+        mesh, fed_data, server_opt, fresh):
     """Acceptance: shard_server_update=True (fp32) matches replicated within
     atol=1e-5 on params after 5 rounds, identical participation masks."""
     sx, sy, counts = fed_data
@@ -134,10 +140,10 @@ def test_sharded_server_update_parity_5_rounds(mesh, fed_data, server_opt):
     e_rep = W.make_engine(mesh, **kw)
     e_shard = W.make_engine(mesh, shard_server_update=True, **kw)
     p_rep, _, l_rep, _ = e_rep.run_rounds(
-        p0, sx, sy, counts, key, 5, mask=mask, donate=False
+        fresh(p0), sx, sy, counts, key, 5, mask=mask
     )
     p_shard, _, l_shard, _ = e_shard.run_rounds(
-        p0, sx, sy, counts, key, 5, mask=mask, donate=False
+        fresh(p0), sx, sy, counts, key, 5, mask=mask
     )
     _assert_trees_close(p_rep, p_shard, atol=1e-5)
     np.testing.assert_allclose(
@@ -169,17 +175,17 @@ def test_sharded_opt_state_is_station_sharded(mesh):
         )
 
 
-def test_bf16_comm_close_to_fp32(mesh, fed_data):
+def test_bf16_comm_close_to_fp32(mesh, fed_data, fresh):
     sx, sy, counts = fed_data
     key = jax.random.key(5)
     p0 = W.init_params(jax.random.fold_in(key, 1))
     kw = dict(local_steps=2, batch_size=16)
     p_rep, _, _, _ = W.make_engine(mesh, **kw).run_rounds(
-        p0, sx, sy, counts, key, 5, donate=False
+        fresh(p0), sx, sy, counts, key, 5
     )
     p_bf, _, _, _ = W.make_engine(
         mesh, shard_server_update=True, comm_dtype=jnp.bfloat16, **kw
-    ).run_rounds(p0, sx, sy, counts, key, 5, donate=False)
+    ).run_rounds(fresh(p0), sx, sy, counts, key, 5)
     # bf16 wire keeps ~2-3 decimal digits; the drift bound documents the
     # accuracy caveat rather than pretending exactness
     for a, b in zip(jax.tree.leaves(p_rep), jax.tree.leaves(p_bf)):
@@ -229,17 +235,6 @@ def test_round_never_donates(mesh, fed_data):
     _assert_trees_close(out1[0], out2[0], atol=0)
 
 
-def test_run_rounds_donate_false_keeps_inputs(mesh, fed_data):
-    sx, sy, counts = fed_data
-    key = jax.random.key(13)
-    p0 = W.init_params(key)
-    eng = W.make_engine(mesh, local_steps=1, batch_size=8)
-    eng.run_rounds(p0, sx, sy, counts, key, 2, donate=False)
-    # p0 and key are still alive and reusable
-    r2 = eng.run_rounds(p0, sx, sy, counts, key, 2, donate=False)
-    assert np.isfinite(np.asarray(r2[2])).all()
-
-
 def test_run_rounds_default_donates_and_returns_fresh(mesh, fed_data):
     """The fast path may consume params/opt_state/key (backend permitting);
     the RETURNED carry must always be valid for chaining."""
@@ -254,3 +249,67 @@ def test_run_rounds_default_donates_and_returns_fresh(mesh, fed_data):
     assert np.isfinite(np.asarray(losses)).all()
     for leaf in jax.tree.leaves(p2):
         assert np.isfinite(np.asarray(leaf)).all()
+
+
+def _alive(tree) -> list[bool]:
+    return [not leaf.is_deleted() for leaf in jax.tree.leaves(tree)]
+
+
+@pytest.mark.parametrize("call", ["run_rounds", "run_rounds_async"])
+def test_a_fused_dispatch_consumes_the_state_it_is_handed(
+        mesh, fed_data, call):
+    """The one donation rule: the `params` and `opt_state` (and the
+    `staleness`) handed in are gone when the dispatch returns, the data is
+    the caller's still, and what comes back chains into the next dispatch,
+    which consumes it in turn. Server Adam, so the state has moments."""
+    sx, sy, counts = fed_data
+    eng = W.make_engine(
+        mesh, local_steps=1, batch_size=8, server_optimizer=optax.adam(1e-2)
+    )
+
+    def dispatch(state, i):
+        params, opt_state, *stale = state
+        if call == "run_rounds":
+            out = eng.run_rounds(
+                params, sx, sy, counts, jax.random.key(i), 2,
+                opt_state=opt_state,
+            )
+            return out[:2], out[2]
+        out = eng.run_rounds_async(
+            params, sx, sy, counts, jax.random.key(i), 2,
+            jnp.ones((2, 8)).at[0, 3].set(0.0), AsyncRoundSpec(quorum=7),
+            staleness=stale[0], opt_state=opt_state,
+        )
+        return out[:3], out[3]
+
+    # placed as a dispatch returns its state: the engine's entry commits
+    # an unplaced state to the mesh, and it is that one it consumes
+    p0 = mesh.replicate(W.init_params(jax.random.key(17)))
+    state = (p0, eng.init(p0))
+    if call == "run_rounds_async":
+        state += (mesh.replicate(jnp.zeros(8, jnp.float32)),)
+    for i in range(2):
+        handed, (state, losses) = state, dispatch(state, i)
+        assert not any(_alive(handed))
+        assert all(_alive((sx, sy, state, losses)))
+        assert np.isfinite(np.asarray(losses)).all()
+
+
+@pytest.mark.parametrize("has", [
+    FedAvg.run_rounds, FedAvg.run_rounds_async, FedAvgSpec,
+    Federation.run_fused_rounds, W.make_engine], ids=lambda f: f.__qualname__)
+def test_no_option_selects_a_program(has):
+    """`donate=`, `unroll=` and `local_unroll` went with the twin
+    executables and loop bodies they selected (a dataclass's signature is
+    its fields)."""
+    assert not {"donate", "unroll", "local_unroll"} & set(
+        inspect.signature(has).parameters)
+
+
+def test_an_engine_registers_three_programs(mesh):
+    eng = W.make_engine(mesh)
+    programs = {k: v.name for k, v in vars(eng).items()
+                if isinstance(v, ObservedFunction)}
+    assert programs == {
+        "_round": "fedavg.round", "_run": "fedavg.run_rounds",
+        "_run_async": "fedavg.run_rounds_async"}

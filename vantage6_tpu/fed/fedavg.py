@@ -108,13 +108,6 @@ class FedAvgSpec:
     # the replicated and scattered update paths. Off = stats come back as
     # an empty dict and the round pays nothing for them.
     learning_stats: bool = True
-    # Unroll factor of the inner local-steps lax.scan (True = fully
-    # unrolled, no while loop). Semantics and RNG streams are identical at
-    # any value — a pure compilation-strategy knob. XLA:CPU runs
-    # convolutions inside while-loop bodies ~6x slower than in straight-
-    # line code (measured on CPU, docs/device_speed.md), so CPU callers of
-    # the fused path want True. On the TPU: not measured.
-    local_unroll: int | bool = 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -187,36 +180,25 @@ class FedAvg:
         # optional learning-plane sink (attach_history): when set, every
         # round()/run_rounds() host-records its stats into it
         self.history: Any = None
-        # NOTE: no buffer donation here — callers legitimately reuse params
-        # across round() calls (e.g. ablations from one init); the scan in
-        # run_rounds already reuses buffers internally. All three
-        # executables dispatch through the device observatory
+        # The three programs dispatch through the device observatory
         # (runtime.profiling): every lowering/compile is a device.compile
         # span + v6t_jit_* telemetry, and a shape-wobbling caller shows up
-        # as a named retrace instead of silent slow rounds.
+        # as a named retrace instead of silent slow rounds. round() keeps
+        # its inputs: its callers step several times from one init.
         self._round = observed_jit("fedavg.round", self._round_impl)
         # n_rounds is a SWEEP static: callers legitimately compile the
         # fused program at several K values (warmup K=1, production K=32,
         # a tail-flush K=7). The observatory counts those as
         # static_sweeps, not retraces — a K sweep must not trip
         # recompile_storm (docs/device_speed.md "K-selection").
+        # The fused programs donate params and opt_state: XLA updates the
+        # scan carry in place instead of double-buffering model + moments
+        # for the whole run. (The key is not donated: it is split inside
+        # and no output has its type, so XLA could never reuse the buffer.)
         self._run = observed_jit(
             "fedavg.run_rounds", self._run_impl,
-            static_argnames=("n_rounds", "unroll"),
-            sweep_statics=("n_rounds", "unroll"),
-        )
-        # run_rounds IS the multi-round fast path: donating params and
-        # opt_state lets XLA update the scan carry in place instead of
-        # double-buffering model + moments for the whole run. (The key is
-        # not donated: it is split inside and no output has its type, so
-        # XLA could never reuse the buffer.)
-        # Kept as a SEPARATE executable so run_rounds(donate=False) (and
-        # AOT callers compiling self._run directly) never consume caller
-        # buffers.
-        self._run_donating = observed_jit(
-            "fedavg.run_rounds_donating", self._run_impl,
-            static_argnames=("n_rounds", "unroll"),
-            sweep_statics=("n_rounds", "unroll"),
+            static_argnames=("n_rounds",),
+            sweep_statics=("n_rounds",),
             donate_argnums=(0, 1),  # params, opt_state
         )
         # fused buffered-async runner: staleness rides the scan carry so K
@@ -224,11 +206,6 @@ class FedAvg:
         # dispatch, composing with compression EF exactly like _run_impl.
         self._run_async = observed_jit(
             "fedavg.run_rounds_async", self._run_async_impl,
-            static_argnames=("n_rounds",),
-            sweep_statics=("n_rounds",),
-        )
-        self._run_async_donating = observed_jit(
-            "fedavg.run_rounds_async_donating", self._run_async_impl,
             static_argnames=("n_rounds",),
             sweep_statics=("n_rounds",),
             donate_argnums=(0, 1, 8),  # params, opt_state, staleness
@@ -340,22 +317,7 @@ class FedAvg:
             return p, loss
 
         step_keys = jax.random.split(key, spec.local_steps)
-        if spec.local_unroll is True:
-            # Python-unrolled: identical math over the identical key
-            # stream, but NO scan/while op in the lowered program —
-            # XLA:CPU executes the conv inside a scan body (even a fully
-            # `unroll=`-ed one, which keeps a trip-count-1 while) ~6x
-            # slower than the same conv in straight-line code (measured,
-            # docs/device_speed.md "K-selection").
-            new_params, step_losses = params, []
-            for i in range(spec.local_steps):
-                new_params, loss = sgd_step(new_params, step_keys[i])
-                step_losses.append(loss)
-            losses = jnp.stack(step_losses)
-        else:
-            new_params, losses = jax.lax.scan(
-                sgd_step, params, step_keys, unroll=spec.local_unroll
-            )
+        new_params, losses = jax.lax.scan(sgd_step, params, step_keys)
         delta = jax.tree.map(lambda n, o: n - o, new_params, params)
         return delta, jnp.mean(losses)
 
@@ -695,8 +657,6 @@ class FedAvg:
         n_rounds: int,
         mask: jax.Array | None = None,
         opt_state: Any = None,
-        donate: bool = True,
-        unroll: int | bool = 1,
     ):
         """`n_rounds` federated rounds as ONE compiled program (lax.scan) —
         the FUSED fast path (docs/device_speed.md): per-station training,
@@ -713,20 +673,12 @@ class FedAvg:
         FedAdam etc. without resetting server-optimizer moments); omitted, a
         fresh optimizer state is initialized.
 
-        DONATION: by default the ``params`` and ``opt_state`` buffers are
-        donated — XLA updates the scan carry in place instead of
-        double-buffering model + moments, but the caller's input arrays are
-        CONSUMED and must not be touched again (use the returned values).
-        Pass ``donate=False`` to keep the inputs alive (e.g. ablations
-        re-running several configs from one init). ``round()`` never
-        donates (tests/test_scattered_update.py pins both contracts).
-
-        ``unroll`` is the round-loop unroll factor (True = fully unrolled,
-        no while loop) — a pure compilation-strategy knob with identical
-        semantics at any value. Combine with ``FedAvgSpec.local_unroll``
-        on CPU, where XLA runs convolutions inside while-loop bodies ~6x
-        slower than straight-line (docs/device_speed.md "K-selection").
-        On the TPU the two forms have not been compared: not measured.
+        The ``params`` and ``opt_state`` handed in are CONSUMED: the
+        program donates them, so XLA updates the scan carry in place
+        instead of double-buffering model + moments. Go on with what is
+        returned; a caller who wants the old state does
+        ``jax.tree.map(jnp.copy, ...)`` first. ``round()`` keeps its inputs
+        (tests/test_scattered_update.py pins both contracts).
         """
         with engine_call(
             "fedavg.run_rounds", n_rounds,
@@ -741,10 +693,9 @@ class FedAvg:
             )
             self._record_wire(params, n_rounds=n_rounds)
             self._record_fused(n_rounds)
-            run = self._run_donating if donate else self._run
-            out = run(
+            out = self._run(
                 params, opt_state, stacked_x, stacked_y, counts, mask, key,
-                n_rounds=n_rounds, unroll=unroll,
+                n_rounds=n_rounds,
             )
             self._record_history(out[2], out[3], rounds_per_dispatch=n_rounds)
             return out
@@ -762,7 +713,6 @@ class FedAvg:
         staleness: jax.Array | None = None,
         mask: jax.Array | None = None,
         opt_state: Any = None,
-        donate: bool = True,
     ):
         """``n_rounds`` buffered-async rounds as ONE fused program: the
         FedBuff staleness vector rides the scan carry, so K rounds of
@@ -773,7 +723,8 @@ class FedAvg:
         (same acceptance every round). Returns (params, opt_state,
         staleness[S], losses[n], stats) — the final staleness vector
         continues into the next fused dispatch, exactly like the host
-        bookkeeping it replaces."""
+        bookkeeping it replaces. ``params``, ``opt_state`` and
+        ``staleness`` are consumed, as in :meth:`run_rounds`."""
         spec.validate()
         with engine_call(
             "fedavg.run_rounds_async", n_rounds,
@@ -790,8 +741,7 @@ class FedAvg:
             )
             self._record_wire(params, n_rounds=n_rounds)
             self._record_fused(n_rounds)
-            run = self._run_async_donating if donate else self._run_async
-            out = run(
+            out = self._run_async(
                 params, opt_state, stacked_x, stacked_y, counts, mask, key,
                 accept_masks,
                 self.mesh.replicate(jnp.asarray(staleness, jnp.float32)),
@@ -844,7 +794,7 @@ class FedAvg:
 
     def _run_impl(
         self, params, opt_state, stacked_x, stacked_y, counts, mask, key,
-        *, n_rounds: int, unroll: int | bool = 1
+        *, n_rounds: int
     ):
         # the participation mask rides the scan xs (one [S] row per
         # round), not the closure: a [S] mask broadcasts to every round,
@@ -863,23 +813,9 @@ class FedAvg:
             return (p, s), (loss, stats)
 
         keys = jax.random.split(key, n_rounds)
-        if unroll is True:
-            # Python-unrolled round loop — same contract as the
-            # local_unroll fast path above: no while op survives in the
-            # lowered program, which is what lets XLA:CPU keep its fast
-            # conv path. Bit-identical to the scan form (same bodies over
-            # the same xs, in order).
-            carry, ys = (params, opt_state), []
-            for i in range(n_rounds):
-                carry, y = body(carry, (keys[i], masks[i]))
-                ys.append(y)
-            params, opt_state = carry
-            losses = jnp.stack([loss for loss, _ in ys])
-            stats = jax.tree.map(lambda *a: jnp.stack(a), *[s for _, s in ys])
-        else:
-            (params, opt_state), (losses, stats) = jax.lax.scan(
-                body, (params, opt_state), (keys, masks), unroll=unroll
-            )
+        (params, opt_state), (losses, stats) = jax.lax.scan(
+            body, (params, opt_state), (keys, masks)
+        )
         return params, opt_state, losses, stats
 
     def _run_async_impl(
@@ -912,5 +848,11 @@ class FedAvg:
         init = (params, opt_state, jnp.asarray(staleness, jnp.float32))
         (params, opt_state, staleness), (losses, stats) = jax.lax.scan(
             body, init, (keys, masks, accepts)
+        )
+        # back as it was handed in, on every device: the donated buffer
+        # is then the one it returns in, and the next dispatch takes it
+        # as it is
+        staleness = jax.lax.with_sharding_constraint(
+            staleness, self.mesh.replicated_sharding()
         )
         return params, opt_state, staleness, losses, stats

@@ -711,7 +711,6 @@ class Federation:
         key: Any,
         n_rounds: int,
         opt_state: Any = None,
-        donate: bool = True,
         metrics: Any = None,  # runtime.metrics.MetricsLogger
     ) -> dict[str, Any]:
         """Thin host driver over the FUSED K-round device program
@@ -721,7 +720,9 @@ class Federation:
         once per dispatch instead of once per round. The roster is
         sampled at dispatch time — a station going offline mid-dispatch
         affects the NEXT dispatch, which is the fused program's
-        freshness/throughput trade (pick K accordingly).
+        freshness/throughput trade (pick K accordingly). ``params`` and
+        ``opt_state`` are consumed, as ``engine.run_rounds`` says: go on
+        with the returned ones.
 
         ``metrics`` (a MetricsLogger) gets one ``round`` record per
         dispatch with ``rounds_per_dispatch=n_rounds``, so per-logical-
@@ -743,13 +744,12 @@ class Federation:
                     out = engine.run_rounds(
                         params, stacked_x, stacked_y, counts, key,
                         n_rounds, mask=mask, opt_state=opt_state,
-                        donate=donate,
                     )
                     jax.block_until_ready(out[0])
             else:
                 out = engine.run_rounds(
                     params, stacked_x, stacked_y, counts, key, n_rounds,
-                    mask=mask, opt_state=opt_state, donate=donate,
+                    mask=mask, opt_state=opt_state,
                 )
                 jax.block_until_ready(out[0])
         self._fused_dispatches += 1

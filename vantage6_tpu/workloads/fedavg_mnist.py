@@ -57,7 +57,6 @@ def make_engine(
     comm_dtype: Any = None,
     compressor: Any = None,
     learning_stats: bool = True,
-    local_unroll: int | bool = 1,
 ) -> FedAvg:
     return FedAvg(
         mesh,
@@ -74,7 +73,6 @@ def make_engine(
             # not compute stats it immediately discards (and the baseline
             # trend stays comparable to pre-learning-plane rounds)
             learning_stats=learning_stats,
-            local_unroll=local_unroll,
         ),
     )
 
