@@ -1,4 +1,6 @@
 """Pallas flash attention vs jnp oracle (interpret mode on CPU)."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -6,6 +8,9 @@ import pytest
 
 from vantage6_tpu.ops import flash_attention
 from vantage6_tpu.ops.flash_attention import reference
+
+# the module: `vantage6_tpu.ops.flash_attention` the attribute is a function
+FA = importlib.import_module("vantage6_tpu.ops.flash_attention")
 
 
 def rand(shape, seed):
@@ -115,10 +120,78 @@ def test_gradients_with_offsets():
 
 
 class TestRecomputeAttention:
-    """The pallas-free flash-memory path: blockwise jnp forward + recompute
-    backward must match the dense oracle in values AND gradients."""
+    """The pallas-free flash-memory path: the tiled jnp forward and backward
+    (only the key blocks a query block can see) must match the dense oracle
+    in values AND gradients."""
 
     from vantage6_tpu.ops.flash_attention import recompute_attention as _ra
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("t,block_q,block_k", [
+        (48, 32, 20),    # neither tile divides t
+        (96, 40, 36),
+        (100, 32, 48),
+        (100, None, None),  # the tile the shapes give: one, t under a tile
+    ])
+    def test_tiles_that_do_not_divide_the_sequence(
+            self, causal, t, block_q, block_k):
+        from vantage6_tpu.ops.flash_attention import recompute_attention
+
+        b, h, d = 2, 3, 8
+        q, k, v, w = (rand((b, h, t, d), s) for s in (30, 31, 32, 33))
+
+        def value_and_grads(f):
+            return jax.value_and_grad(
+                lambda *a: jnp.sum(w * f(*a)), argnums=(0, 1, 2))(q, k, v)
+
+        got = value_and_grads(lambda *a: recompute_attention(
+            *a, causal=causal, block_q=block_q, block_k=block_k))
+        want = value_and_grads(lambda *a: reference(*a, causal=causal))
+        for a, b_ in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(a, b_, atol=3e-5, rtol=3e-5)
+
+    @pytest.mark.parametrize("q_offset,k_offset,t_q,t_k", [
+        (64, 0, 32, 64),    # a later shard's queries over every key so far
+        (32, 32, 32, 32),   # a shard over its own keys: the diagonal hop
+        (32, 64, 32, 48),   # keys of a LATER shard: nothing visible
+        (40, 16, 24, 56),   # the diagonal crosses the tiles askew
+    ])
+    def test_ring_hop_shapes_forward_and_gradients(
+            self, q_offset, k_offset, t_q, t_k):
+        """`q_offset != k_offset` with `t_q != t_k`, the shapes a ring hop
+        hands over; where no key is visible the output and every gradient
+        are zero."""
+        from vantage6_tpu.ops.flash_attention import recompute_attention
+
+        b, h, d = 1, 2, 8
+        q, w = rand((b, h, t_q, d), 34), rand((b, h, t_q, d), 35)
+        k, v = rand((b, h, t_k, d), 36), rand((b, h, t_k, d), 37)
+        kw = dict(q_offset=q_offset, k_offset=k_offset, causal=True)
+
+        def value_and_grads(f):
+            return jax.value_and_grad(
+                lambda *a: jnp.sum(w * f(*a)), argnums=(0, 1, 2))(q, k, v)
+
+        got = value_and_grads(lambda *a: recompute_attention(
+            *a, block_q=16, block_k=24, **kw))
+        if k_offset >= q_offset + t_q:  # the dense softmax reads a mean there
+            for leaf in jax.tree.leaves(got):
+                np.testing.assert_array_equal(leaf, 0.0)
+            return
+        want = value_and_grads(lambda *a: reference(*a, **kw))
+        for a, b_ in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(a, b_, atol=3e-5, rtol=3e-5)
+
+    @pytest.mark.parametrize("lengths,tile", [
+        ((1024, 1024), (256, 256)),    # GPT-2 medium: the swept value
+        ((8192, 8192), (512, 512)),    # SmallThinker: the parent's
+        ((100, 100), (100, 100)),      # t under a tile: one tile
+        ((96, 2048), (96, 256)),       # a ring hop's short queries
+        ((2048, 2048), (256, 256)),    # read on the chip too
+        ((6000, 6000), (256, 256)),    # whole lane widths only
+    ])
+    def test_the_tile_is_a_function_of_the_lengths(self, lengths, tile):
+        assert FA.attention_tile(*lengths) == tile
 
     @pytest.mark.parametrize("causal", [False, True])
     @pytest.mark.parametrize("t", [64, 96])  # 96 exercises key padding
@@ -152,6 +225,81 @@ class TestRecomputeAttention:
             np.testing.assert_allclose(
                 np.asarray(grc), np.asarray(gr), atol=3e-5, rtol=3e-5
             )
+
+    def test_the_walk_at_the_shipped_tile_is_the_triangle_the_span_reports(
+            self):
+        """GPT-2 medium's shapes (T 1024, 16 heads of 64, 24 layers): the
+        ranges of `_key_block_range`, summed over the query blocks at the
+        tile the shapes give, are n (n + 1) / 2 of n x n tiles, and the
+        round's span says that count times the layers."""
+        from vantage6_tpu.workloads import fed_transformer as FT
+
+        t = 1024
+        block_q, block_k = FA.attention_tile(t, t)
+        assert block_q == block_k and t % block_q == 0
+        n = t // block_q
+        walked = 0
+        for i in range(n):
+            lo, hi = FA._key_block_range(
+                i, block_q, block_k, n, t, 0, 0, True, None)
+            # Python integers in, Python integers out: counting the walk
+            # for the span runs no program on the device
+            assert (lo, hi) == (0, i + 1) and type(hi) is int
+            walked += hi - lo
+        assert walked == n * (n + 1) // 2
+        assert FA.tiles_visited(t, t, block_q, block_k, True, None) == (
+            walked, n * n)
+        assert FA.tiles_visited(t, t, block_q, block_k, False, None) == (
+            n * n, n * n)
+        engine = FT.make_engine(4, 1, FT.TransformerConfig(
+            vocab=50257, d_model=1024, n_heads=16, n_layers=24,
+            max_len=1024, attention="recompute"), devices=jax.devices()[:1])
+        assert engine.attention_walk(t) == {
+            "attention_tile": f"{block_q}x{block_k}",
+            "attention_tiles_visited": 24 * walked,
+            "attention_tiles": 24 * n * n}
+
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_under_vmap_the_in_place_update_stays_an_update(self, depth):
+        """The backward adds each key block's `dK`/`dV` into its slice in
+        place. jax's rule for a batched `dynamic_update_slice` is a scatter;
+        with one start for the whole batch `_add_at` keeps the update, one
+        axis further in per `vmap`, and the gradients are the dense ones."""
+        from vantage6_tpu.ops.flash_attention import recompute_attention
+
+        shape = (3, 2)[:depth] + (2, 2, 40, 8)
+        q, k, v, w = (rand(shape, s) for s in (40, 41, 42, 43))
+
+        def grads(f):
+            one = lambda q, k, v, w: jax.grad(  # noqa: E731
+                lambda *a: jnp.sum(w * f(*a)), argnums=(0, 1, 2))(q, k, v)
+            for _ in range(depth):
+                one = jax.vmap(one)
+            return jax.jit(one)
+
+        tiled = grads(lambda *a: recompute_attention(
+            *a, causal=True, block_q=16, block_k=8))
+        text = tiled.lower(q, k, v, w).as_text()
+        assert "stablehlo.scatter" not in text
+        assert "stablehlo.dynamic_update_slice" in text
+        want = grads(lambda *a: reference(*a, causal=True))(q, k, v, w)
+        for a, b_ in zip(tiled(q, k, v, w), want):
+            np.testing.assert_allclose(a, b_, atol=3e-5, rtol=3e-5)
+
+    def test_a_start_per_batch_element_takes_jaxs_own_rule(self):
+        acc, block = rand((3, 2, 8, 4), 44), rand((3, 2, 2, 4), 45)
+        at = jnp.asarray([0, 2, 6])
+        got = jax.vmap(FA._add_at(1))(acc, block, at)
+        want = np.array(acc)
+        for i, a in enumerate([0, 2, 6]):
+            want[i, :, a:a + 2] += np.asarray(block[i])
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+        # an accumulator shared by the batch, a block each: broadcast
+        got = jax.vmap(FA._add_at(1), in_axes=(None, 0, None))(
+            acc[0], block, 4)
+        want = np.repeat(np.asarray(acc[:1]), 3, axis=0)
+        want[:, :, 4:6] += np.asarray(block)
+        np.testing.assert_allclose(got, want, rtol=1e-6)
 
     def test_ring_hop_offsets(self):
         from vantage6_tpu.ops.flash_attention import recompute_attention

@@ -5,6 +5,7 @@ operations. CPU, tiny sizes."""
 from __future__ import annotations
 
 import contextlib
+import importlib
 import os
 import re
 import subprocess
@@ -25,6 +26,8 @@ from vantage6_tpu.runtime.tracing import TRACER
 from vantage6_tpu.workloads import fed_transformer as FT
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# the module: `vantage6_tpu.ops.flash_attention` the attribute is a function
+FA = importlib.import_module("vantage6_tpu.ops.flash_attention")
 
 
 @pytest.fixture(autouse=True)
@@ -198,7 +201,10 @@ ENGINES = {"fed_transformer.round": _call_transformer,
            "fedavg.run_rounds": _call_run_rounds,
            "fedavg.round": _call_fedavg_round}
 # what an engine says of the program it launches, beside engine and rounds
-ENGINE_ATTRS = {"fed_transformer.round": {},
+# (the transformer: a sequence of 16 is one tile; two layers of one head)
+ENGINE_ATTRS = {"fed_transformer.round": {"attention_tile": "16x16",
+                                          "attention_tiles_visited": 2,
+                                          "attention_tiles": 2},
                 "fedavg.run_rounds": {"gather": "packed"},
                 "fedavg.round": {"gather": "packed"}}
 
@@ -248,6 +254,81 @@ def test_the_engine_call_says_which_gather_its_program_was_built_with(
     ENGINES[engine](y_dtype=y_dtype)
     (call,) = _named(TRACER.drain(), "engine.call")
     assert call["attrs"]["gather"] == path
+
+
+@pytest.mark.parametrize("attention", ["recompute", "flash", "ring"])
+def test_the_engine_call_says_which_tiles_the_attention_walks(
+        attention, monkeypatch):
+    """The counter that `recompute_attention` walked visible tiles only: the
+    tile its shapes gave and, over the layers of one sequence and head, the
+    tiles visited of the tiles there are; the sum of `_key_block_range`
+    over the query blocks. With tiles of 4 a causal sequence of 16 visits
+    1 + 2 + 3 + 4 of 16 in each of two layers. The other paths say
+    nothing."""
+    monkeypatch.setattr(FA, "TILED_BLOCK", 4)
+    engine, args = _transformer(attention)
+    engine.round(*args)
+    (call,) = _named(TRACER.drain(), "engine.call")
+    said = {k: v for k, v in call["attrs"].items() if k.startswith("attention")}
+    if attention != "recompute":
+        assert said == {}
+        return
+    walked = 0
+    for i in range(4):
+        lo, hi = FA._key_block_range(i, 4, 4, 4, 16, 0, 0, True, None)
+        walked += int(hi) - int(lo)
+    assert walked == 10
+    assert said == {"attention_tile": "4x4",
+                    "attention_tiles_visited": 2 * walked,
+                    "attention_tiles": 2 * 16}
+
+
+def test_a_windowed_layer_visits_fewer_tiles(monkeypatch):
+    """One full and one windowed layer (window 8, tiles of 4, sequence 16):
+    the windowed one leaves out the tiles wholly before the window too."""
+    monkeypatch.setattr(FA, "TILED_BLOCK", 4)
+    engine, args = _transformer_experts()
+    walk = engine.attention_walk(args[2].shape[-1])
+    windowed = 0
+    for i in range(4):
+        lo, hi = FA._key_block_range(i, 4, 4, 4, 16, 0, 0, True, 8)
+        windowed += int(hi) - int(lo)
+    assert windowed == 1 + 2 + 3 + 3
+    assert walk == {"attention_tile": "4x4",
+                    "attention_tiles_visited": 10 + windowed,
+                    "attention_tiles": 32}
+
+
+@pytest.mark.parametrize("build", [_transformer, _transformer_experts])
+def test_counting_the_walk_runs_no_program(build, monkeypatch):
+    """What the span says of the walk is host arithmetic on Python integers:
+    the first `round()` builds as many programs with it as with a walk that
+    says nothing (a `jnp` call for it would be one more each, and each a
+    program of every cell's set-up)."""
+    from perfbench.meter import CompileMeter
+
+    monkeypatch.setattr(FA, "TILED_BLOCK", 4)
+
+    def programs_of_the_first_round():
+        engine, args = build()
+        TRACER.clear()
+        meter = CompileMeter()
+        try:
+            jax.block_until_ready(engine.round(*args))
+        finally:
+            meter.close()
+        (call,) = _named(TRACER.drain(), "engine.call")
+        return meter.compiles, call["attrs"]
+
+    with_walk, said = programs_of_the_first_round()
+    assert {type(v) for k, v in said.items()
+            if k.startswith("attention_tiles")} == {int}
+    assert type(FA.tiles_visited(16, 16, 4, 4, True, 8)[0]) is int
+    monkeypatch.setattr(FT.FedTransformer, "attention_walk",
+                        lambda self, t: {})
+    without, said = programs_of_the_first_round()
+    assert not any(k.startswith("attention") for k in said)
+    assert with_walk == without >= 1
 
 
 @pytest.mark.parametrize("block", ["dense", "experts"])

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from perfbench import cells, compare
+from vantage6_tpu.runtime import profiling
 from vantage6_tpu.runtime.tracing import TRACER
 from vantage6_tpu.workloads import fed_transformer as FT
 
@@ -232,8 +233,9 @@ def _close(got, want, rtol=2e-5, atol=2e-5):
 
 def test_grouped_query_attention_equals_repeated_kv_attention():
     """[B, 2, T, D] keys and values beside [B, 6, T, D] queries give what
-    the untiled path gives on keys and values repeated to 6 heads, forward
-    and backward (a kv head's gradient is the sum over its query heads)."""
+    the same path gives on keys and values repeated to 6 heads, forward and
+    backward (a kv head's gradient is the sum over its query heads), and
+    both give the dense masked softmax."""
     q, k, v, w = _qkv(6, 2, 40)
     grouped = _value_and_grads(
         lambda q, k, v: FA.recompute_attention(q, k, v, causal=True,
@@ -243,7 +245,11 @@ def test_grouped_query_attention_equals_repeated_kv_attention():
         lambda q, k, v: FA.recompute_attention(
             q, jnp.repeat(k, 3, axis=1), jnp.repeat(v, 3, axis=1),
             causal=True), q, k, v, w)
+    dense = _value_and_grads(
+        lambda q, k, v: FA.reference(q, k, v, causal=True), q, k, v, w)
     _close(grouped, repeated)
+    _close(grouped, dense)
+    _close(repeated, dense)
 
 
 @pytest.mark.parametrize("t,window,block_q,block_k,h_kv", [
@@ -296,16 +302,23 @@ def test_key_blocks_outside_the_window_are_not_visited():
 # sha256 of `engine._round.lower(...).as_text()` (the program as XLA gets
 # it), the first loss and the norm of the first gradient, for
 # TransformerConfig(vocab=97, d_model=32, n_heads=4, n_layers=2, max_len=16).
-# That commit's `_round` donated nothing: the hashes are held to the round's
-# body under a `jax.jit` that donates nothing either, and the program that
-# runs to that text plus the donated arguments' attributes (`_DONOR`)
+# That commit's `_round` donated nothing, and its `_forward` ran a plain Python
+# block for every layer: the hashes are held to the round's body under a
+# `jax.jit` that donates nothing either, lowered with the block's own `jit`
+# taken out (`_plain_block`), and the program that runs to that text plus the
+# donated arguments' attributes (`_DONOR`) and the block as a function called
+# once a layer (`test_the_block_is_traced_and_lowered_once_a_kind`).
+# The `recompute` rows pinned the untiled walk, which is gone: their hashes
+# are the tiled walk's (the same recipe, at 282bc0e's child), and their loss
+# and gradient norm are held to the `ring` row's, another algorithm for the
+# same mathematics
 PARENT = {
     ("recompute", False): (
-        "ab64276fceb32c2de7b2d8b259306afa5ddd247d72364c7f68bac31ead50fe38",
-        "0x1.25426a0000000p+2", 0.8295300602912903),
+        "51d2fa5c50337eb3f53d7eb7240a1ff45a4a1056be2998f69eef33924b10e4ea",
+        "0x1.25426a0000000p+2", 0.8295300006866455),
     ("recompute", True): (
-        "2954878c0299cde307a48a113b81e4bbacb84070cc21a1f68078f67a8d1c5ea4",
-        "0x1.25426a0000000p+2", 0.8295300602912903),
+        "bfbcface6de379a3f115e055ea30dcdb6c58d6dba8df4bba256fa0b955bf4513",
+        "0x1.25426a0000000p+2", 0.8295300006866455),
     ("flash", False): (
         "59d4fc8162b74b07a60a46e49cf1a41fffa05ca5edb49744bbc20b3c8af3d64e",
         "0x1.25426a0000000p+2", 0.8295300006866455),
@@ -313,6 +326,14 @@ PARENT = {
         "60c7745e806715842d2163ffa328a0b8bbc973ea416eb20466751bb45bf2487b",
         "0x1.25426a0000000p+2", 0.8295300006866455),
 }
+# `_block_config()`'s `_round` body with tiles of 8 and the plain block (the
+# same recipe). On the parent commit (282bc0e) it read 8a6da479...; the walk is
+# the one that configuration ran there, and the text differs where the
+# backward adds `dK` / `dV` into their slice: four scatters (what jax's rule
+# made of the update under the stations' `vmap`) are four
+# `dynamic_update_slice`s
+PINNED_SMALLTHINKER = (
+    "579dc59831a068ed111a9a168af8704859c3795ddb37b6a0f4b9dda01145f03e")
 
 
 def _parent_init_params(key, cfg):
@@ -341,9 +362,9 @@ _DONOR = re.compile(
     r", (?:tf\.aliasing_output = \d+ : i32|jax\.buffer_donor = true)")
 
 
-def _default_block(attention, remat):
+def _default_block(attention, remat, n_layers=2):
     cfg = FT.TransformerConfig(
-        vocab=97, d_model=32, n_heads=4, n_layers=2, max_len=16,
+        vocab=97, d_model=32, n_heads=4, n_layers=n_layers, max_len=16,
         attention=attention, flash_interpret=True, remat=remat)
     engine = FT.make_engine(4, 1, cfg, devices=jax.devices()[:1])
     tokens = engine.shard_tokens(FT.make_federated_tokens(4, 2, 16, 97))
@@ -354,6 +375,28 @@ def _body_text(engine, args) -> str:
     """The round's body lowered under a `jax.jit` that donates nothing."""
     body = FT.FedTransformer._round.__wrapped__
     return jax.jit(body, static_argnums=0).lower(engine, *args).as_text()
+
+
+class _JaxWithoutJit:
+    """`jax` as `fed_transformer` names it, with a `jit` that hands its
+    function back."""
+
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+    @staticmethod
+    def jit(fun, **_):
+        return fun
+
+
+@pytest.fixture
+def plain_block(monkeypatch):
+    """`_forward` runs the plain Python block for every layer, as it did
+    before the block was jitted: what the pinned texts were taken with, and
+    the copy the jitted block's numbers are held to. A program traced under
+    it belongs to the engine it was traced for (jax keys its caches on the
+    engine): take a new engine for the jitted block."""
+    monkeypatch.setattr(FT, "jax", _JaxWithoutJit())
 
 
 @pytest.mark.parametrize("attention,remat", sorted(PARENT))
@@ -374,10 +417,12 @@ def test_donation_marks_the_states_arguments_and_changes_nothing_else(
 
 
 @pytest.mark.parametrize("attention,remat", sorted(PARENT))
-def test_the_default_block_is_the_parents_bit_for_bit(attention, remat):
-    """`init_params` gives the parent's arrays, `_round`'s body lowers to
-    the parent's program (so its loss and gradient are the parent's bits on
-    any machine), and on this one they read the parent's golden values."""
+def test_the_default_block_is_the_parents_bit_for_bit(
+        attention, remat, plain_block):
+    """`init_params` gives the parent's arrays, `_round`'s body with the
+    plain block lowers to the parent's program (so its loss and gradient are
+    the parent's bits on any machine), and on this one they read the parent's
+    golden values."""
     engine, (params, opt_state, tokens, mask) = _default_block(
         attention, remat)
     cfg = engine.cfg
@@ -387,10 +432,136 @@ def test_the_default_block_is_the_parents_bit_for_bit(attention, remat):
         assert np.array_equal(a, b)
     lowered, loss_hex, grad_norm = PARENT[attention, remat]
     text = _body_text(engine, (params, opt_state, tokens, mask))
+    assert "layer_block" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == lowered
     _, new_state, loss = engine.round(params, opt_state, tokens, mask)
     assert float(loss) == pytest.approx(float.fromhex(loss_hex), rel=1e-6)
-    got = 10 * float(jnp.sqrt(sum(
-        jnp.sum(x ** 2) for x in jax.tree.leaves(new_state[0].mu))))
-    assert got == pytest.approx(grad_norm, rel=1e-5)
+    assert (loss_hex, grad_norm) == PARENT["ring", False][1:]
+    assert _first_grad_norm(new_state) == pytest.approx(
+        grad_norm, rel=1e-6 if attention == "recompute" else 1e-5)
     assert engine.record_expert_load() is None  # a block without experts
+
+
+def _first_grad_norm(opt_state) -> float:
+    """The norm of the first averaged gradient, from Adam's first moment
+    after one step (mu = 0.1 g)."""
+    return 10 * float(jnp.sqrt(sum(
+        jnp.sum(x ** 2) for x in jax.tree.leaves(opt_state[0].mu))))
+
+
+def _smallthinker_round(inputs):
+    engine = FT.make_engine(2, 1, _block_config(),
+                            devices=jax.devices()[:1])
+    params = inputs["params"]
+    return engine, (params, engine.optimizer.init(params),
+                    engine.shard_tokens(inputs["tokens"][0]), inputs["mask"])
+
+
+def test_the_smallthinker_block_lowers_to_the_pinned_text(
+        inputs, plain_block):
+    """The configuration that ran the tiled walk before every call did: its
+    `_round` body, at the tiny sizes and the tiles of this file."""
+    text = _body_text(*_smallthinker_round(inputs))
+    assert hashlib.sha256(text.encode()).hexdigest() == PINNED_SMALLTHINKER
+
+
+# ------------------------------------------- one block per kind of layer
+def _functions(text: str) -> list[str]:
+    return re.findall(r"func\.func (?:public |private )?@([\w.]+)", text)
+
+
+@pytest.mark.parametrize("attention,remat", [
+    ("ring", False), ("ring", True), ("recompute", False), ("flash", True)])
+def test_the_block_is_traced_and_lowered_once_a_kind(attention, remat):
+    """`_forward` hands every layer of a kind to one jitted block: the
+    lowered round holds the block's function once a direction (forward and
+    backward) and calls it once a layer, so the text's functions do not grow
+    with the depth; the block's scopes are still on the compiled
+    operations."""
+    def lowered(n_layers):
+        engine, args = _default_block(attention, remat, n_layers)
+        return engine._round.lower(engine, *args)
+
+    six = lowered(6)
+    text = six.as_text()
+    blocks = [name for name in _functions(text)
+              if name.startswith("layer_block")]
+    assert len(blocks) == 2
+    assert len(_functions(text)) == len(_functions(lowered(2).as_text()))
+    for name in blocks:  # forward, backward: each called once a layer
+        assert len(re.findall(rf"call @{name}\(", text)) == 6
+    names = re.findall(r'op_name="([^"]*)"', six.compile().as_text())
+    for scope in ("attention", "mlp"):
+        assert any(re.search(rf"/local_train/.*jit\(layer_block\).*/{scope}/", n)
+                   for n in names), scope
+    # the block's own name is no scope of the device's operations
+    assert "layer_block" not in profiling.DEVICE_SCOPES
+
+
+@pytest.mark.parametrize("which", ["six_layers", "smallthinker"])
+def test_tracing_the_round_runs_the_block_once_a_kind(
+        which, inputs, monkeypatch):
+    """Counted, not timed: `_norm` runs while `_forward` is traced, twice in
+    a dense block (before attention and before the MLP) and once for the
+    head, so six layers of one kind call it 2 + 1 times. SmallThinker's four
+    layers are of two kinds (full and no rotation; window and rotary); a
+    block with experts calls it once and `expert_half`, which stays outside
+    the block and is traced for every layer, once more: 2 + 4 + 1."""
+    calls = []
+    norm = FT._norm
+    monkeypatch.setattr(FT, "_norm", lambda *a: calls.append(1) or norm(*a))
+    if which == "six_layers":
+        engine, args = _default_block("ring", False, n_layers=6)
+        expected = 2 + 1
+    else:
+        engine, args = _smallthinker_round(inputs)
+        cfg = engine.cfg
+        kinds = {(cfg.layer_window(i), cfg.layer_rotates(i))
+                 for i in range(cfg.n_layers)}
+        assert (len(kinds), cfg.n_layers) == (2, 4)
+        expected = 2 + 4 + 1
+    jax.make_jaxpr(FT.FedTransformer._round.__wrapped__, static_argnums=0)(
+        engine, *args)
+    assert len(calls) == expected
+
+
+@pytest.mark.parametrize("attention,remat", [
+    ("ring", False), ("recompute", False), ("recompute", True)])
+def test_the_jitted_block_reads_what_the_plain_block_reads(
+        attention, remat, request):
+    """Three rounds of six layers with the block jitted (as shipped) and with
+    the plain Python block for every layer: the same losses and the same
+    first gradient, to rounding (XLA inlines the calls; what it fuses after
+    that may round another way)."""
+    def three_rounds():
+        engine, (params, opt_state, tokens, mask) = _default_block(
+            attention, remat, n_layers=6)
+        losses = []
+        for _ in range(3):
+            params, opt_state, loss = engine.round(
+                params, opt_state, tokens, mask)
+            losses.append(float(loss))
+            if len(losses) == 1:
+                grad = _first_grad_norm(opt_state)
+        return losses, grad
+
+    jitted = three_rounds()
+    request.getfixturevalue("plain_block")
+    plain = three_rounds()
+    assert jitted[0] == pytest.approx(plain[0], rel=1e-6)
+    assert jitted[1] == pytest.approx(plain[1], rel=1e-5)
+    assert jitted[0][2] < jitted[0][0]  # and the rounds learn
+
+
+def test_under_the_stations_vmap_attention_adds_no_scatter():
+    """`FedTransformer._round` walks the packed stations with `vmap`: the
+    backward's in-place `dK` / `dV` update stays an update there (`_add_at`;
+    as the scatter jax's rule makes of it, it cost the chip more than the
+    tile's products). The round holds the scatters the embedding brings,
+    as many as with the ring, which updates nothing in place."""
+    def scatters(attention):
+        engine, args = _default_block(attention, False)
+        text = engine._round.lower(engine, *args).as_text()
+        return text.count("stablehlo.scatter")
+
+    assert scatters("recompute") == scatters("ring") > 0

@@ -224,48 +224,41 @@ def recompute_attention(
     k_offset: jax.Array | int = 0,
     causal: bool = False,
     scale: float | None = None,
-    block_k: int | None = None,  # None: 128, or the tiled path's 512
+    block_k: int | None = None,  # None: `attention_tile` of the lengths
     window: int | None = None,
     block_q: int | None = None,
 ) -> jax.Array:
-    """Flash-MEMORY attention without a Pallas kernel: a blockwise
-    (lax.scan over key blocks) online-softmax forward in plain jnp/XLA plus
-    the same blockwise custom_vjp backward as the kernel path.
+    """Flash-MEMORY attention without a Pallas kernel: an online-softmax
+    forward over (query block, key block) tiles in plain jnp/XLA
+    (`_tiled_forward`) and the softmax-attention VJP over the same tiles
+    (`_tiled_bwd`).
 
-    Peak transient memory is O(Tq * block_k) in BOTH directions and the
-    residuals are just (q, k, v, o) — the [Tq, Tk] probabilities that a
-    naive XLA attention saves for backward (the memory wall for long
-    context) never exist. Which of the two is faster on the chip, and
-    from what sequence length: not measured (ROADMAP A4).
+    Peak transient memory is O(block_q * block_k) a head in BOTH directions
+    and the residuals are (q, k, v, o) and the row statistics L ([B, H, Tq]
+    f32) — the [Tq, Tk] probabilities that a naive XLA attention saves for
+    backward (the memory wall for long context) never exist.
 
-    That path meets every query with every key block and masks. The TILED
-    path (`_tiled_forward` / `_tiled_bwd`) also tiles the queries
-    (``block_q``) and walks, for each query block, only the key blocks that
-    hold a visible key: none above the causal diagonal and, with
-    ``window``, none wholly outside ``(i - window, i]``. It is taken when
-    ``block_q`` is given, when a ``window`` is, or when ``k``/``v`` carry
-    fewer heads than ``q`` (``[B, H_kv, T, D]`` beside ``[B, H_q, T, D]``,
-    ``H_q % H_kv == 0``: query head ``h`` reads kv head ``h // (H_q //
-    H_kv)``; the repeated heads are never materialised, forward or
-    backward). With none of the three the program is the one it was."""
+    For each query block the walk visits only the key blocks that hold a
+    visible key (`_key_block_range`): every one without ``causal``, none
+    above the causal diagonal with it and, with ``window``, none wholly
+    outside ``(i - window, i]``. ``k``/``v`` may carry fewer heads than
+    ``q`` (``[B, H_kv, T, D]`` beside ``[B, H_q, T, D]``, ``H_q % H_kv ==
+    0``: query head ``h`` reads kv head ``h // (H_q // H_kv)``; the
+    repeated heads are never materialised, forward or backward). Where the
+    caller names no ``block_q`` / ``block_k`` the tile is `attention_tile`
+    of the lengths handed in, whose values were read on the chip."""
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / (d**0.5)
-    if window is not None or block_q is not None or k.shape[1] != q.shape[1]:
-        if window is not None and not causal:
-            raise ValueError("a window is a causal window: pass causal=True")
-        if q.shape[1] % k.shape[1]:
-            raise ValueError(
-                f"{q.shape[1]} query heads do not divide over "
-                f"{k.shape[1]} key/value heads")
-        fn = _tiled_vjp(causal, float(scale), window,
-                        block_q or TILED_BLOCK, block_k or TILED_BLOCK)
-        return fn(
-            q, k, v,
-            jnp.asarray(q_offset, jnp.int32),
-            jnp.asarray(k_offset, jnp.int32),
-        )
-    fn = _recompute_vjp(causal, float(scale), block_k or 128)
+    if window is not None and not causal:
+        raise ValueError("a window is a causal window: pass causal=True")
+    if q.shape[1] % k.shape[1]:
+        raise ValueError(
+            f"{q.shape[1]} query heads do not divide over "
+            f"{k.shape[1]} key/value heads")
+    tile_q, tile_k = attention_tile(q.shape[2], k.shape[2])
+    fn = _tiled_vjp(causal, float(scale), window,
+                    block_q or tile_q, block_k or tile_k)
     return fn(
         q, k, v,
         jnp.asarray(q_offset, jnp.int32),
@@ -273,67 +266,44 @@ def recompute_attention(
     )
 
 
-@functools.lru_cache(maxsize=None)
-def _recompute_vjp(causal, scale, block_k):
-    return _attach_recompute_vjp(
-        functools.partial(
-            _blockwise_forward, causal=causal, scale=scale, block_k=block_k
-        ),
-        causal,
-        scale,
-    )
-
-
-def _blockwise_forward(q, k, v, q_offset, k_offset, *, causal, scale,
-                       block_k):
-    """Online-softmax forward over key blocks (jnp; mirrors the kernel)."""
-    b, h, t_q, d = q.shape
-    t_k = k.shape[2]
-    block = min(block_k, t_k)
-    pad_k = (-t_k) % block
-    if pad_k:
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-    n_blocks = (t_k + pad_k) // block
-    kb = jnp.moveaxis(k.reshape(b, h, n_blocks, block, d), 2, 0)
-    vb = jnp.moveaxis(v.reshape(b, h, n_blocks, block, d), 2, 0)
-    base = jnp.arange(n_blocks) * block
-    q_pos = jnp.reshape(q_offset, ()) + jnp.arange(t_q)
-    k_off = jnp.reshape(k_offset, ())
-
-    def step(carry, blk):
-        m, l, acc = carry
-        k_j, v_j, idx0 = blk
-        s = jnp.einsum(
-            "bhqd,bhkd->bhqk", q, k_j, preferred_element_type=jnp.float32
-        ) * scale
-        k_idx = idx0 + jnp.arange(block)
-        valid = (k_idx < t_k)[None, :]
-        if causal:
-            valid = valid & (q_pos[:, None] >= (k_off + k_idx)[None, :])
-        s = jnp.where(valid[None, None], s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.maximum(jnp.max(s, -1), -1e20))
-        corr = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[..., None])
-        l = l * corr + jnp.sum(p, -1)
-        acc = acc * corr[..., None] + jnp.einsum(
-            "bhqk,bhkd->bhqd", p.astype(v_j.dtype), v_j,
-            preferred_element_type=jnp.float32,
-        )
-        return (m_new, l, acc), None
-
-    m0 = jnp.full((b, h, t_q), NEG_INF, jnp.float32)
-    (m, l, acc), _ = lax.scan(
-        step,
-        (m0, jnp.zeros_like(m0), jnp.zeros((b, h, t_q, d), jnp.float32)),
-        (kb, vb, base),
-    )
-    denom = jnp.where(l > 0, l, 1.0)[..., None]
-    return (acc / denom).astype(q.dtype)
-
-
 # ------------------------------------------------------------- tiled path
-TILED_BLOCK = 512  # query and key blocks where the caller names none
+TILED_BLOCK = 512  # the largest tile `attention_tile` names, and
+TILED_BLOCK_MIN = 256  # the smallest (a shorter sequence is one tile)
+
+
+def attention_tile(t_q: int, t_k: int) -> tuple[int, int]:
+    """The (query block, key block) a call uses where its caller names none,
+    from the sequence lengths it is handed: a sixteenth of the longer one in
+    whole lane widths, held between ``TILED_BLOCK_MIN`` and ``TILED_BLOCK``.
+    On the chip 256 x 256 was the fastest pair, or within 4% of it, at t 1024
+    and 2048, and 512 x 512 at t 8192 (PERF.md section 6, PR 34). The head
+    size moved nothing where it was read (64 and 128 at t 1024) and the query
+    heads per kv head were read at one value a length, so the rule rests on
+    the lengths alone."""
+    sixteenth = max(t_q, t_k) // 16 // LANES * LANES  # whole lane tiles
+    block = min(TILED_BLOCK, max(TILED_BLOCK_MIN, sixteenth))
+    return min(block, t_q), min(block, t_k)
+
+
+def tiles_visited(t_q: int, t_k: int, block_q: int, block_k: int,
+                  causal: bool, window: int | None) -> tuple[int, int]:
+    """How many (query block, key block) tiles one head's walk visits at
+    offsets 0, and how many the ``[t_q, t_k]`` square holds: the ranges of
+    `_key_block_range`, summed over the query blocks (on the host: Python
+    integers in, Python integers out, no program on the device)."""
+    block_q, block_k = min(block_q, t_q), min(block_k, t_k)
+    n_q, n_k = -(-t_q // block_q), -(-t_k // block_k)
+    visited = 0
+    for i in range(n_q):
+        lo, hi = _key_block_range(
+            i, block_q, block_k, n_k, t_k, 0, 0, causal, window)
+        visited += max(hi - lo, 0)
+    return visited, n_q * n_k
+
+
+def _clip(x, hi):
+    """``x`` held to ``[0, hi]``: a traced scalar, or a Python integer."""
+    return min(max(x, 0), hi) if isinstance(x, int) else jnp.clip(x, 0, hi)
 
 
 def _key_block_range(i, block_q, block_k, n_kblocks, t_k, q_offset, k_offset,
@@ -341,15 +311,16 @@ def _key_block_range(i, block_q, block_k, n_kblocks, t_k, q_offset, k_offset,
     """The key blocks ``[lo, hi)`` that hold a key some query of query block
     ``i`` may see: local key index ``j`` is visible to global query position
     ``p`` iff ``k_offset + j <= p`` (causal) and ``k_offset + j > p -
-    window``. Traced scalars; an empty range reads ``hi <= lo``."""
+    window``. Traced scalars (or Python integers throughout, for
+    `tiles_visited`); an empty range reads ``hi <= lo``."""
     first_q = q_offset + i * block_q
     hi = n_kblocks
     if causal:
-        last_key = jnp.clip(first_q + block_q - k_offset, 0, t_k)  # exclusive
+        last_key = _clip(first_q + block_q - k_offset, t_k)  # exclusive
         hi = (last_key + block_k - 1) // block_k
     lo = 0
     if window is not None:
-        lo = jnp.clip(first_q - window + 1 - k_offset, 0, t_k) // block_k
+        lo = _clip(first_q - window + 1 - k_offset, t_k) // block_k
     return lo, hi
 
 
@@ -437,13 +408,43 @@ def _tiled_forward(q, k, v, q_offset, k_offset, *, causal, scale, window,
     return o, big_l
 
 
+@functools.lru_cache(maxsize=None)
+def _add_at(axis: int):
+    """``add(acc, block, at)``: ``acc`` with ``block`` added to its slice
+    ``[at, at + block.shape[axis])`` along ``axis``, in place. Under `vmap`
+    with one ``at`` for the whole batch it stays that update, one axis
+    further in: jax's own rule for `dynamic_update_slice` makes every batched
+    one a scatter, which on the chip cost the backward of the packed
+    stations more than its products (PERF.md section 6, PR 34)."""
+
+    def add(acc, block, at):
+        size = block.shape[axis]
+        return lax.dynamic_update_slice_in_dim(
+            acc, lax.dynamic_slice_in_dim(acc, at, size, axis) + block,
+            at, axis)
+
+    add_unbatched = jax.custom_batching.custom_vmap(add)
+
+    @add_unbatched.def_vmap
+    def _(axis_size, in_batched, acc, block, at):
+        if in_batched[2]:  # a start of its own per element: jax's rule
+            in_axes = [0 if b else None for b in in_batched]
+            return jax.vmap(add, in_axes=in_axes)(acc, block, at), True
+        acc, block = (
+            x if b else jnp.broadcast_to(x, (axis_size,) + x.shape)
+            for x, b in zip((acc, block), in_batched))
+        return _add_at(axis + 1)(acc, block, at), True
+
+    return add_unbatched
+
+
 def _tiled_bwd(q, k, v, o, big_l, do, q_offset, k_offset, *, causal, scale,
                window, block_q, block_k):
     """The softmax-attention VJP over the same tiles: for each query block
     the visible key blocks only, ``P = exp(S - L)`` recomputed per tile,
-    ``dK``/``dV`` accumulated in place in f32 (a kv head sums over its
-    group of query heads inside the product), products in the inputs'
-    dtype with f32 accumulation."""
+    ``dK``/``dV`` accumulated in place in f32 (`_add_at`; a kv head sums
+    over its group of query heads inside the product), products in the
+    inputs' dtype with f32 accumulation."""
     b, h_q, t_q, d = q.shape
     h_kv, t_k = k.shape[1], k.shape[2]
     g = h_q // h_kv
@@ -489,14 +490,9 @@ def _tiled_bwd(q, k, v, o, big_l, do, q_offset, k_offset, *, causal, scale,
             dk_j = jnp.einsum(
                 "bhgqk,bhgqd->bhkd", ds, q_i,
                 preferred_element_type=jnp.float32) * scale
-            at = j * block_k
-            dk = lax.dynamic_update_slice_in_dim(
-                dk, lax.dynamic_slice_in_dim(dk, at, block_k, 2) + dk_j,
-                at, 2)
-            dv = lax.dynamic_update_slice_in_dim(
-                dv, lax.dynamic_slice_in_dim(dv, at, block_k, 2) + dv_j,
-                at, 2)
-            return dq_i, dk, dv
+            add_block = _add_at(2)
+            return (dq_i, add_block(dk, dk_j, j * block_k),
+                    add_block(dv, dv_j, j * block_k))
 
         dq_i, dk, dv = lax.fori_loop(lo, hi, step, (
             jnp.zeros((b, h_kv, g, block_q, d), jnp.float32), dk, dv))

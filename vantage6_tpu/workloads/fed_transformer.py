@@ -30,8 +30,10 @@ from vantage6_tpu.core.mesh import STATION_AXIS, _largest_divisor_leq
 from vantage6_tpu.fed import collectives
 from vantage6_tpu.models import experts
 from vantage6_tpu.ops.flash_attention import (
+    attention_tile,
     flash_attention,
     recompute_attention,
+    tiles_visited,
 )
 from vantage6_tpu.parallel.ring_attention import ring_attention
 from vantage6_tpu.runtime.profiling import device_launch, engine_call
@@ -55,10 +57,11 @@ class TransformerConfig:
     # "flash": the Pallas flash kernel (ops.flash_attention) — requires the
     # full sequence on each device (seq_devices == 1, enforced by
     # make_engine); `flash_interpret` runs it in interpret mode on CPU.
-    # "recompute": flash-memory attention WITHOUT pallas (blockwise jnp
-    # forward + recompute backward; ops.recompute_attention) — same
-    # seq_devices == 1 constraint. "flash" runs the kernel or raises; it
-    # never gives way to "recompute".
+    # "recompute": flash-memory attention WITHOUT pallas (ops.
+    # recompute_attention: forward and backward over tiles in jnp, only the
+    # key blocks a query block can see, the tile chosen from the shapes) —
+    # same seq_devices == 1 constraint. "flash" runs the kernel or raises;
+    # it never gives way to "recompute".
     attention: str = "ring"
     flash_interpret: bool = False
     # Rematerialization: drop every layer's activations on the forward pass
@@ -265,7 +268,7 @@ def _forward(
                 lax.dynamic_slice_in_dim(params["pos"], offset, t_local, 0)
             )[None]
 
-    def layer_block(x, layer, *, window, rotates):
+    def layer_block(x, layer, window, rotates):
         # the router's matrix and the norms' scales stay float32
         kept = {name: layer[name] for name in ("router", "norm1", "norm2")
                 if name in layer}
@@ -334,19 +337,18 @@ def _forward(
                 interpret=cfg.flash_interpret)
             return x + y.reshape(x.shape), load
 
-    blocks: dict[Any, Any] = {}  # one traced block per kind of layer
-
-    def block_of(window, rotates):
-        if (window, rotates) not in blocks:
-            block = partial(layer_block, window=window, rotates=rotates)
-            blocks[window, rotates] = (
-                jax.checkpoint(block) if cfg.remat else block)
-        return blocks[window, rotates]
-
+    # one traced block per kind of layer: the layers of a kind after the
+    # first hit jax's caches at every step (trace, jvp, partial evaluation,
+    # transpose, batching), and the lowering emits the block once and calls
+    # it. Its name in an operation's path, `jit(layer_block)`, is no scope.
+    kind = (2, 3)  # window, rotates
+    block = jax.jit(
+        jax.checkpoint(layer_block, static_argnums=kind) if cfg.remat
+        else layer_block, static_argnums=kind)
     loads = []
     for i, layer in enumerate(params["layers"]):
-        x, routing = block_of(cfg.layer_window(i), cfg.layer_rotates(i))(
-            x, layer)
+        x, routing = block(
+            x, layer, cfg.layer_window(i), cfg.layer_rotates(i))
         if cfg.ffn == "experts":
             x, load = expert_half(x, layer, routing)
             loads.append(load)
@@ -409,6 +411,8 @@ class FedTransformer:
     # (`record_expert_load` reads and empties it; bounded, oldest out)
     _expert_load: collections.deque = dataclasses.field(
         default_factory=lambda: collections.deque(maxlen=4096), repr=False)
+    # what `round`'s span says of the attention's walk, by sequence length
+    _walks: dict = dataclasses.field(default_factory=dict, repr=False)
 
     def init(self, key: jax.Array) -> tuple[Any, Any]:
         # params AND the whole optimizer state are committed to the mesh:
@@ -450,8 +454,11 @@ class FedTransformer:
         call and, under it, a ``device.launch`` span around the call into
         the jitted program and nothing else (``n_buffers`` = the array
         leaves handed over, ``n_donated`` = those of them the program may
-        write its outputs into)."""
-        with engine_call("fed_transformer.round", 1):
+        write its outputs into). With ``attention="recompute"`` the
+        ``engine.call`` span also carries the walk the program was built
+        with (`attention_walk`)."""
+        with engine_call("fed_transformer.round", 1,
+                         **self.attention_walk(tokens.shape[-1])):
             n_donated = len(jax.tree.leaves((params, opt_state)))
             n_buffers = n_donated + len(jax.tree.leaves((tokens, mask)))
             with device_launch("fed_transformer.round", n_buffers, n_donated):
@@ -459,6 +466,29 @@ class FedTransformer:
         if load is not None:  # stays on the device: record_expert_load
             self._expert_load.append(load)
         return tuple(out)
+
+    def attention_walk(self, t: int) -> dict[str, Any]:
+        """What says that `recompute_attention` walked visible tiles only at
+        sequence length ``t``: ``attention_tile`` (``"<block_q>x<block_k>"``,
+        the tile its shapes gave) and, summed over the layers of one
+        sequence and head, ``attention_tiles_visited`` of
+        ``attention_tiles`` (a windowed layer visits fewer). Computed once a
+        length; nothing for the other attention paths."""
+        if self.cfg.attention != "recompute":
+            return {}
+        if t not in self._walks:
+            cfg = self.cfg
+            tile = attention_tile(t, t)
+            kinds = collections.Counter(
+                cfg.layer_window(i) for i in range(cfg.n_layers))
+            counts = sum(
+                n * np.array(tiles_visited(t, t, *tile, True, window))
+                for window, n in kinds.items())
+            self._walks[t] = {
+                "attention_tile": "{}x{}".format(*tile),
+                "attention_tiles_visited": int(counts[0]),
+                "attention_tiles": int(counts[1])}
+        return self._walks[t]
 
     def record_expert_load(self) -> dict[str, Any] | None:
         """Read the expert layers' counts of the rounds since the last call
