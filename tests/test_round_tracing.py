@@ -67,6 +67,20 @@ def _transformer_experts():
     return engine, (params, opt_state, tokens, jnp.ones(4))
 
 
+def _transformer_looped():
+    """A stack of two layers walked three times over the same weights, with
+    the exit gate, norms after each half too and the gated MLP."""
+    cfg = FT.TransformerConfig(
+        vocab=97, d_model=32, n_heads=4, n_layers=2, max_len=16,
+        attention="recompute", flash_interpret=True, remat=True,
+        norm="rmsnorm", norm_after=True, positions="rotary", ffn="swiglu",
+        d_ff=48, tie_head=False, loops=3, exit_beta=0.05)
+    engine = FT.make_engine(4, 1, cfg, devices=jax.devices()[:1])
+    params, opt_state = engine.init(jax.random.key(0))
+    tokens = engine.shard_tokens(FT.make_federated_tokens(4, 2, 16, 97))
+    return engine, (params, opt_state, tokens, jnp.ones(4))
+
+
 def _nll(p, x, y, w):
     z = x @ p["w"] + p["b"]
     return jnp.sum(w * (jnp.logaddexp(0.0, z) - y * z)) / jnp.sum(w)
@@ -106,6 +120,8 @@ PROGRAMS = {
     "transformer-experts": (
         _transformer_experts,
         TRANSFORMER_SCOPES - {"mlp"} | {"router", "experts"}),
+    "transformer-looped": (
+        _transformer_looped, TRANSFORMER_SCOPES | {"loop", "exit_gate"}),
     "fedavg-fused": (lambda: _fedavg(), FEDAVG_SCOPES),
     "fedavg-compressed-zero1": (
         lambda: _fedavg(
@@ -299,6 +315,49 @@ def test_a_windowed_layer_visits_fewer_tiles(monkeypatch):
                     "attention_tiles": 32}
 
 
+def test_a_looped_stack_says_its_walks_and_counts_tiles_over_applications(
+        monkeypatch):
+    """`loops` and `layer_applications` on the `engine.call` span of a stack
+    walked more than once, and the attention's tiles counted over the layer
+    applications (two layers, three walks, tiles of 4 in a sequence of 16:
+    6 x 10 of 6 x 16); a stack walked once says neither."""
+    monkeypatch.setattr(FA, "TILED_BLOCK", 4)
+    engine, args = _transformer_looped()
+    engine.round(*args)
+    (call,) = _named(TRACER.drain(), "engine.call")
+    assert call["attrs"] == {
+        "engine": "fed_transformer.round", "rounds": 1,
+        "attention_tile": "4x4", "attention_tiles_visited": 60,
+        "attention_tiles": 96, "loops": 3, "layer_applications": 6}
+    TRACER.clear()
+    engine, args = _transformer()
+    engine.round(*args)
+    (call,) = _named(TRACER.drain(), "engine.call")
+    assert not {"loops", "layer_applications"} & set(call["attrs"])
+
+
+def test_the_exit_distribution_is_one_span_read_outside_the_round():
+    """A round of a looped stack leaves its exit distribution on the device
+    and records nothing of it; `record_exit_distribution` then records ONE
+    `exits.distribution` span for the rounds since the last call."""
+    engine, args = _transformer_looped()
+    state = args[:2]
+    for _ in range(2):
+        *state, _ = engine.round(*state, *args[2:])
+    assert not _named(TRACER.drain(), "exits.distribution")
+    attrs = engine.record_exit_distribution()
+    (span,) = _named(TRACER.drain(), "exits.distribution")
+    assert span["kind"] == "engine" and span["attrs"] == attrs
+    assert set(attrs) == {"rounds", "mean", "by_round", "expected_exit_step"}
+    assert attrs["rounds"] == 2 and np.shape(attrs["by_round"]) == (2, 3)
+    assert sum(attrs["mean"]) == pytest.approx(1.0)
+    assert 1.0 < attrs["expected_exit_step"] < 3.0
+    assert engine.record_exit_distribution() is None
+    plain, args = _transformer()
+    plain.round(*args)
+    assert plain.record_exit_distribution() is None
+
+
 @pytest.mark.parametrize("build", [_transformer, _transformer_experts])
 def test_counting_the_walk_runs_no_program(build, monkeypatch):
     """What the span says of the walk is host arithmetic on Python integers:
@@ -331,12 +390,12 @@ def test_counting_the_walk_runs_no_program(build, monkeypatch):
     assert with_walk == without >= 1
 
 
-@pytest.mark.parametrize("block", ["dense", "experts"])
+@pytest.mark.parametrize("block", ["dense", "experts", "looped"])
 def test_a_transformer_launch_counts_the_state_it_donates(block):
     """`n_donated` beside `n_buffers`: every leaf of `params` and
     `opt_state` (three trees of the parameters' shape and Adam's count)."""
-    engine, args = {"dense": _transformer,
-                    "experts": _transformer_experts}[block]()
+    engine, args = {"dense": _transformer, "experts": _transformer_experts,
+                    "looped": _transformer_looped}[block]()
     engine.round(*args)
     (launch,) = _named(TRACER.drain(), "device.launch")
     n_params = len(jax.tree.leaves(args[0]))

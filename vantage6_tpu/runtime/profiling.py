@@ -95,9 +95,9 @@ __all__ = [
 # lm_head_loss inside it; a block with experts opens router, before
 # attention, and experts where the dense one opens mlp) and server_update.
 DEVICE_SCOPES = (
-    "pack_table", "local_train", "gather", "loss_grad", "embed", "attention",
-    "mlp", "router", "experts", "lm_head_loss", "compress", "learning_stats",
-    "aggregate", "server_update",
+    "pack_table", "local_train", "gather", "loss_grad", "embed", "loop",
+    "attention", "mlp", "router", "experts", "lm_head_loss", "exit_gate",
+    "compress", "learning_stats", "aggregate", "server_update",
 )
 
 

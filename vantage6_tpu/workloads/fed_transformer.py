@@ -96,16 +96,31 @@ class TransformerConfig:
     # earlier key. None with a `window`: every layer is windowed.
     window: int | None = None
     window_layout: tuple[int, ...] | None = None
-    # "mlp": 4x GELU. "experts": a router BEFORE attention (on the block's
-    # input) over `n_experts`, `top_k` a token, and ReGLU experts of width
-    # `d_expert`, of which this chip holds `experts_held` (their ids):
-    # models/experts.py. No shared expert, no dense feed-forward.
+    # "mlp": 4x GELU. "swiglu": a dense gated one of width `d_ff`,
+    # ``W_down (silu(W_gate h) * (W_up h))``. "experts": a router BEFORE
+    # attention (on the block's input) over `n_experts`, `top_k` a token, and
+    # ReGLU experts of width `d_expert`, of which this chip holds
+    # `experts_held` (their ids): models/experts.py. No shared expert, no
+    # dense feed-forward.
     ffn: str = "mlp"
+    d_ff: int = 0
     n_experts: int = 0
     top_k: int = 0
     d_expert: int = 0
     experts_held: tuple[int, ...] = ()
     tie_head: bool = True  # False: an output head of its own, [d_model, vocab]
+    # a norm AFTER each half of the block as well as before it: ``x +
+    # N(attention(N(x)))``, ``x + N(ffn(N(x)))`` (with rmsnorm two more
+    # learned scales a layer, `norm1_post` and `norm2_post`)
+    norm_after: bool = False
+    # the stack is walked `loops` times over THE SAME parameters, the final
+    # norm after every walk; the next walk starts from the normed state.
+    # With more than one walk an exit gate (``sigmoid(h w + b)`` per token
+    # and walk, float32) weighs the walks' cross-entropies by the
+    # distribution of the walk a token would leave at, less `exit_beta`
+    # times that distribution's entropy (`_exit_loss`)
+    loops: int = 1
+    exit_beta: float = 0.0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -126,8 +141,16 @@ class TransformerConfig:
             raise ValueError(f"no such norm: {self.norm!r}")
         if self.positions not in ("learned", "rotary"):
             raise ValueError(f"no such positions: {self.positions!r}")
-        if self.ffn not in ("mlp", "experts"):
+        if self.ffn not in ("mlp", "swiglu", "experts"):
             raise ValueError(f"no such ffn: {self.ffn!r}")
+        if self.ffn == "swiglu" and self.d_ff < 1:
+            raise ValueError("ffn='swiglu' needs its width, d_ff")
+        if self.loops < 1:
+            raise ValueError(f"the stack is walked once or more: {self.loops}")
+        if self.ffn == "experts" and (self.loops > 1 or self.norm_after):
+            raise ValueError(
+                "a block with experts is walked once (its counts are kept "
+                "per layer, not per walk) and takes no norm after its halves")
         if self.n_heads % self.n_kv_heads:
             raise ValueError(
                 f"{self.n_heads} query heads do not divide over "
@@ -171,6 +194,9 @@ def _layer_shapes(cfg: TransformerConfig) -> dict[str, tuple[int, ...]]:
     }
     if cfg.ffn == "mlp":
         shapes.update(w_up=(d, 4 * d), w_down=(4 * d, d))
+    elif cfg.ffn == "swiglu":
+        shapes.update(w_gate=(d, cfg.d_ff), w_up=(d, cfg.d_ff),
+                      w_down=(cfg.d_ff, d))
     else:
         e, f = len(cfg.experts_held), cfg.d_expert
         shapes.update(router=(d, cfg.n_experts), w_gate=(e, d, f),
@@ -181,10 +207,13 @@ def _layer_shapes(cfg: TransformerConfig) -> dict[str, tuple[int, ...]]:
 def init_params(key: jax.Array, cfg: TransformerConfig) -> dict[str, Any]:
     """Matrices ~ N(0, 0.02); learned norm scales 1. The tree: ``embed``;
     ``pos`` with learned positions; ``head`` where the head is not tied;
-    ``final_norm`` with rmsnorm; ``layers[i]``: ``qkv`` (the q, k and v
-    projections side by side), ``proj``, then ``w_up``/``w_down`` (mlp) or
+    ``final_norm`` with rmsnorm; ``exit_gate`` (``w`` [d_model, 1] and a
+    bias ``b`` of 0) where the stack is walked more than once; ``layers[i]``:
+    ``qkv`` (the q, k and v projections side by side), ``proj``, then
+    ``w_up``/``w_down`` (mlp), ``w_gate``/``w_up``/``w_down`` (swiglu) or
     ``router``/``w_gate``/``w_up``/``w_down`` (experts, the held ones
-    stacked), and ``norm1``/``norm2`` with rmsnorm."""
+    stacked), and with rmsnorm ``norm1``/``norm2`` (and
+    ``norm1_post``/``norm2_post`` where a norm follows each half too)."""
     shapes = _layer_shapes(cfg)
     n = len(shapes)
     keys = jax.random.split(key, 2 + n * cfg.n_layers)
@@ -200,14 +229,20 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict[str, Any]:
             jax.random.fold_in(keys[1], 1), (cfg.d_model, cfg.vocab))
     if cfg.norm == "rmsnorm":
         params["final_norm"] = jnp.ones((cfg.d_model,))
+    if cfg.loops > 1:
+        params["exit_gate"] = {
+            "w": s * jax.random.normal(
+                jax.random.fold_in(keys[1], 2), (cfg.d_model, 1)),
+            "b": jnp.zeros((1,))}
+    scales = ("norm1", "norm2") + (
+        ("norm1_post", "norm2_post") if cfg.norm_after else ())
     params["layers"] = []
     for i in range(cfg.n_layers):
         k = keys[2 + n * i : 2 + n * (i + 1)]
         layer = {name: s * jax.random.normal(k[j], shape)
                  for j, (name, shape) in enumerate(shapes.items())}
         if cfg.norm == "rmsnorm":
-            layer["norm1"] = jnp.ones((cfg.d_model,))
-            layer["norm2"] = jnp.ones((cfg.d_model,))
+            layer.update({name: jnp.ones((cfg.d_model,)) for name in scales})
         params["layers"].append(layer)
     return params
 
@@ -245,15 +280,23 @@ def _rotate(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     ).astype(x.dtype)
 
 
+def _head(params: dict[str, Any], cfg: TransformerConfig) -> jax.Array:
+    """The output head [d_model, vocab] in the compute dtype."""
+    return (params["embed"].astype(cfg.dtype).T if cfg.tie_head
+            else params["head"].astype(cfg.dtype))
+
+
 def _forward(
     params: dict[str, Any],
     tokens_local: jax.Array,
     cfg: TransformerConfig,
     axis_name: str,
-) -> tuple[jax.Array, Any]:
-    """Logits, and the expert layers' load: per layer the assignments each
-    held expert received and the choices that named one (``None`` for a
-    block without experts)."""
+) -> tuple[list[jax.Array], list[Any]]:
+    """The stream after the final norm, once for every walk of the stack
+    (the head reads these: `forward_local`, `_loss_and_load`), and the
+    expert layers' load, one entry a layer: the assignments each held expert
+    received and the choices that named one (none for a block without
+    experts)."""
     b, t_local = tokens_local.shape
     offset = lax.axis_index(axis_name) * t_local  # global positions
     n_q, n_kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
@@ -270,8 +313,9 @@ def _forward(
 
     def layer_block(x, layer, window, rotates):
         # the router's matrix and the norms' scales stay float32
-        kept = {name: layer[name] for name in ("router", "norm1", "norm2")
-                if name in layer}
+        kept = {name: layer[name] for name in (
+            "router", "norm1", "norm2", "norm1_post", "norm2_post")
+            if name in layer}
         layer = jax.tree.map(
             cast, {k: v for k, v in layer.items() if k not in kept})
         routing = None
@@ -318,12 +362,22 @@ def _forward(
         else:
             with jax.named_scope("attention"):
                 attn = ring_attention(q, k, v, axis_name, causal=True)
-        x = x + attn.reshape(b, t_local, n_q) @ layer["proj"]
+        attn = attn.reshape(b, t_local, n_q) @ layer["proj"]
+        if cfg.norm_after:
+            attn = _norm(attn, kept.get("norm1_post"), cfg)
+        x = x + attn
         if cfg.ffn == "experts":
             return x, routing  # the expert layer follows: `expert_half`
         with jax.named_scope("mlp"):
             h = _norm(x, kept.get("norm2"), cfg)
-            return x + jax.nn.gelu(h @ layer["w_up"]) @ layer["w_down"], None
+            if cfg.ffn == "mlp":
+                y = jax.nn.gelu(h @ layer["w_up"]) @ layer["w_down"]
+            else:
+                y = (jax.nn.silu(h @ layer["w_gate"])
+                     * (h @ layer["w_up"])) @ layer["w_down"]
+            if cfg.norm_after:
+                y = _norm(y, kept.get("norm2_post"), cfg)
+            return x + y, None
 
     def expert_half(x, layer, routing):
         """Outside the layer's checkpoint: the expert layer recomputes its
@@ -340,25 +394,36 @@ def _forward(
     # one traced block per kind of layer: the layers of a kind after the
     # first hit jax's caches at every step (trace, jvp, partial evaluation,
     # transpose, batching), and the lowering emits the block once and calls
-    # it. Its name in an operation's path, `jit(layer_block)`, is no scope.
+    # it, however often the stack is walked. Its name in an operation's
+    # path, `jit(layer_block)`, is no scope.
     kind = (2, 3)  # window, rotates
     block = jax.jit(
         jax.checkpoint(layer_block, static_argnums=kind) if cfg.remat
         else layer_block, static_argnums=kind)
-    loads = []
-    for i, layer in enumerate(params["layers"]):
-        x, routing = block(
-            x, layer, cfg.layer_window(i), cfg.layer_rotates(i))
-        if cfg.ffn == "experts":
-            x, load = expert_half(x, layer, routing)
-            loads.append(load)
-    with jax.named_scope("lm_head_loss"):
-        x = _norm(x, params.get("final_norm"), cfg)
-        logits = x @ (cast(params["embed"]).T if cfg.tie_head
-                      else cast(params["head"]))
-    if cfg.ffn != "experts":
-        return logits, None
-    return logits, jax.tree.map(lambda *xs: jnp.stack(xs), *loads)
+
+    def stack(x):
+        loads = []
+        for i, layer in enumerate(params["layers"]):
+            x, routing = block(
+                x, layer, cfg.layer_window(i), cfg.layer_rotates(i))
+            if cfg.ffn == "experts":
+                x, load = expert_half(x, layer, routing)
+                loads.append(load)
+        return x, loads
+
+    if cfg.loops == 1:
+        x, loads = stack(x)
+        with jax.named_scope("lm_head_loss"):
+            return [_norm(x, params.get("final_norm"), cfg)], loads
+    # an unrolled walk, not a `lax.scan` over the walks: read on the chip
+    # (PERF.md section 6, PR 35) the scan compiles in half the time to a
+    # third of the code and its round is 2.6% slower
+    states = []
+    with jax.named_scope("loop"):
+        for _ in range(cfg.loops):  # the same layers, the same positions
+            x = _norm(stack(x)[0], params.get("final_norm"), cfg)
+            states.append(x)
+    return states, []
 
 
 def forward_local(
@@ -367,22 +432,100 @@ def forward_local(
     cfg: TransformerConfig,
     axis_name: str = SEQ_AXIS,
 ) -> jax.Array:
-    """Logits [B, T_local, V] for this shard; attention spans the FULL
-    sequence via the ring."""
-    return _forward(params, tokens_local, cfg, axis_name)[0]
+    """Logits [B, T_local, V] for this shard (after the last walk, where the
+    stack is walked more than once); attention spans the FULL sequence via
+    the ring."""
+    states, _ = _forward(params, tokens_local, cfg, axis_name)
+    with jax.named_scope("lm_head_loss"):
+        return states[-1] @ _head(params, cfg)
+
+
+# positions of a sequence whose logits are live at once. Read on the chip at
+# 2 stations x [1, 4096] x 49,152 (PERF.md section 6, PR 35): 512 the
+# fastest round (810 ms; 813 at 256, 822 at 1,024 and whole, 875 at 128),
+# and whole, a walk's logits made the round's temporaries 10.6 GB for 4.6
+HEAD_CHUNK = 512
+
+
+def _token_nll(h: jax.Array, head: jax.Array, targets: jax.Array):
+    """Cross-entropy per position [B, T] of ``h @ head`` against
+    ``targets``, `HEAD_CHUNK` positions at a time where they divide T.
+    Nothing of a chunk is kept for the backward pass, which computes its
+    logits [B, chunk, V] again: one chunk's are live at a time."""
+    @jax.checkpoint
+    def chunk_nll(chunk):
+        h, targets = chunk
+        logp = jax.nn.log_softmax((h @ head).astype(jnp.float32))
+        return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+
+    b, t = targets.shape
+    if t <= HEAD_CHUNK or t % HEAD_CHUNK:
+        return chunk_nll((h, targets))
+
+    def chunks(x):  # [B, T, ...] -> [T / chunk, B, chunk, ...]
+        return jnp.moveaxis(x.reshape(b, -1, HEAD_CHUNK, *x.shape[2:]), 1, 0)
+
+    nll = lax.map(chunk_nll, (chunks(h), chunks(targets)))
+    return jnp.moveaxis(nll, 0, 1).reshape(b, t)
+
+
+def _exit_loss(states, params, tokens_local, cfg):
+    """The exit-weighted loss of a stack walked R times, summed over this
+    shard's predicted positions, and the exit distribution summed over them
+    [R]. With ``lambda_r = sigmoid(h_r w + b)`` a token leaves after walk r
+    with ``p_r = lambda_r prod_{j<r} (1 - lambda_j)`` (``p_R`` the rest, so
+    they sum to 1), and its loss is ``sum_r p_r CE_r - exit_beta H(p)``. The
+    head is taken once a walk, each walk's logits for themselves
+    (`_token_nll`); gate, distribution and entropy in float32, in logs."""
+    head = _head(params, cfg)
+    # the last position predicts nothing: computed with the rest, left out
+    targets = jnp.roll(tokens_local, -1, axis=1)
+    with jax.named_scope("lm_head_loss"):
+        nll = jnp.stack([_token_nll(h, head, targets) for h in states])
+    with jax.named_scope("exit_gate"):
+        gate = params["exit_gate"]
+        z = jnp.stack([
+            jnp.matmul(h.astype(jnp.float32), gate["w"],
+                       precision=lax.Precision.HIGHEST)[..., 0] + gate["b"]
+            for h in states[:-1]])                           # [R - 1, B, T]
+        # log prod_{j<r} (1 - lambda_j) for r = 1..R, then log p_r
+        stayed = jnp.concatenate([
+            jnp.zeros_like(z[:1]), jnp.cumsum(jax.nn.log_sigmoid(-z), 0)])
+        log_p = stayed + jnp.concatenate([
+            jax.nn.log_sigmoid(z), jnp.zeros_like(z[:1])])
+        p = jnp.exp(log_p)[:, :, :-1]
+        entropy = -jnp.sum(p * log_p[:, :, :-1], axis=0)
+        per_token = jnp.sum(p * nll[:, :, :-1], axis=0)
+        return (jnp.sum(per_token - cfg.exit_beta * entropy),
+                jnp.sum(p, axis=(1, 2)))
 
 
 def _loss_and_load(params, tokens_local, cfg, axis_name):
-    logits, load = _forward(params, tokens_local, cfg, axis_name)
-    with jax.named_scope("lm_head_loss"):
-        targets = tokens_local[:, 1:]
-        logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        local_sum = jnp.sum(nll)
-        local_cnt = jnp.asarray(nll.size, jnp.float32)
+    """The loss, and what a round leaves on the device beside it: the expert
+    layers' load stacked over the layers, and the exit distribution summed
+    over the predicted positions [R]; each ``None`` where the block has no
+    such thing."""
+    states, loads = _forward(params, tokens_local, cfg, axis_name)
+    load = exits = None
+    if cfg.loops > 1:
+        local_sum, exits = _exit_loss(states, params, tokens_local, cfg)
+        b, t_local = tokens_local.shape
+        local_cnt = jnp.asarray(b * (t_local - 1), jnp.float32)
+    else:
+        with jax.named_scope("lm_head_loss"):
+            logits = states[0] @ _head(params, cfg)
+        if loads:  # between the head's product and the loss, in no scope
+            load = jax.tree.map(lambda *xs: jnp.stack(xs), *loads)
+        with jax.named_scope("lm_head_loss"):
+            targets = tokens_local[:, 1:]
+            logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32))
+            nll = -jnp.take_along_axis(
+                logp, targets[..., None], axis=-1)[..., 0]
+            local_sum = jnp.sum(nll)
+            local_cnt = jnp.asarray(nll.size, jnp.float32)
     total = lax.psum(local_sum, axis_name)
     count = lax.psum(local_cnt, axis_name)
-    return total / count, load
+    return total / count, (load, exits)
 
 
 def loss_local(
@@ -410,6 +553,10 @@ class FedTransformer:
     # the expert layers' counts of the last rounds, still on the device
     # (`record_expert_load` reads and empties it; bounded, oldest out)
     _expert_load: collections.deque = dataclasses.field(
+        default_factory=lambda: collections.deque(maxlen=4096), repr=False)
+    # the exit distributions of the last rounds, still on the device
+    # (`record_exit_distribution`), likewise
+    _exits: collections.deque = dataclasses.field(
         default_factory=lambda: collections.deque(maxlen=4096), repr=False)
     # what `round`'s span says of the attention's walk, by sequence length
     _walks: dict = dataclasses.field(default_factory=dict, repr=False)
@@ -456,24 +603,32 @@ class FedTransformer:
         leaves handed over, ``n_donated`` = those of them the program may
         write its outputs into). With ``attention="recompute"`` the
         ``engine.call`` span also carries the walk the program was built
-        with (`attention_walk`)."""
-        with engine_call("fed_transformer.round", 1,
-                         **self.attention_walk(tokens.shape[-1])):
+        with (`attention_walk`), and where the stack is walked more than
+        once, ``loops`` and ``layer_applications``."""
+        attrs = self.attention_walk(tokens.shape[-1])
+        if self.cfg.loops > 1:
+            attrs = {**attrs, "loops": self.cfg.loops,
+                     "layer_applications": self.cfg.loops * self.cfg.n_layers}
+        with engine_call("fed_transformer.round", 1, **attrs):
             n_donated = len(jax.tree.leaves((params, opt_state)))
             n_buffers = n_donated + len(jax.tree.leaves((tokens, mask)))
             with device_launch("fed_transformer.round", n_buffers, n_donated):
-                *out, load = self._round(params, opt_state, tokens, mask)
+                *out, load, exits = self._round(
+                    params, opt_state, tokens, mask)
         if load is not None:  # stays on the device: record_expert_load
             self._expert_load.append(load)
+        if exits is not None:  # likewise: record_exit_distribution
+            self._exits.append(exits)
         return tuple(out)
 
     def attention_walk(self, t: int) -> dict[str, Any]:
         """What says that `recompute_attention` walked visible tiles only at
         sequence length ``t``: ``attention_tile`` (``"<block_q>x<block_k>"``,
-        the tile its shapes gave) and, summed over the layers of one
-        sequence and head, ``attention_tiles_visited`` of
-        ``attention_tiles`` (a windowed layer visits fewer). Computed once a
-        length; nothing for the other attention paths."""
+        the tile its shapes gave) and, summed over the layer applications
+        of one sequence and head (every layer once a walk of the stack),
+        ``attention_tiles_visited`` of ``attention_tiles`` (a windowed layer
+        visits fewer). Computed once a length; nothing for the other
+        attention paths."""
         if self.cfg.attention != "recompute":
             return {}
         if t not in self._walks:
@@ -481,7 +636,7 @@ class FedTransformer:
             tile = attention_tile(t, t)
             kinds = collections.Counter(
                 cfg.layer_window(i) for i in range(cfg.n_layers))
-            counts = sum(
+            counts = cfg.loops * sum(
                 n * np.array(tiles_visited(t, t, *tile, True, window))
                 for window, n in kinds.items())
             self._walks[t] = {
@@ -519,29 +674,55 @@ class FedTransformer:
             pass
         return attrs
 
+    def record_exit_distribution(self) -> dict[str, Any] | None:
+        """Read the exit distributions of the rounds since the last call off
+        the device and record them as ONE ``exits.distribution`` span:
+        ``rounds``, ``by_round`` (per round the mean over stations and
+        predicted tokens of the distribution of the walk a token would leave
+        at, [R]), ``mean`` over the rounds, and ``expected_exit_step``
+        (``sum_r r * mean_r``). Like `record_expert_load`: call it OUTSIDE
+        what is timed; None where there is nothing to record (a stack walked
+        once, no round since the last call)."""
+        pending = list(self._exits)
+        self._exits.clear()
+        if not pending:
+            return None
+        sums = np.stack(jax.device_get(pending)).astype(np.float64)
+        by_round = sums / sums.sum(axis=1, keepdims=True)
+        mean = by_round.mean(axis=0)
+        attrs = {
+            "rounds": len(pending),
+            "mean": mean.tolist(),
+            "by_round": by_round.tolist(),
+            "expected_exit_step": float(
+                mean @ np.arange(1, mean.size + 1)),
+        }
+        with TRACER.span("exits.distribution", kind="engine", attrs=attrs):
+            pass
+        return attrs
+
     # params and opt_state are donated: every state output has an input of
     # its shape, dtype and placement, so the runtime allocates only the loss
-    # (and the experts' counts) before the program may start
+    # (and the experts' counts or the exit distribution) before the program
+    # may start
     @partial(jax.jit, static_argnums=0, donate_argnums=(1, 2))
     def _round(
         self, params: Any, opt_state: Any, tokens: jax.Array,
         mask: jax.Array,
-    ) -> tuple[Any, Any, jax.Array, Any]:
+    ) -> tuple[Any, Any, jax.Array, Any, Any]:
         def station_body(params, tokens_block):
             # tokens_block: [S/D_s, B, T/P] — the inner vmap walks the
             # stations PACKED into this mesh slot (stations_per_slot > 1
             # when the mesh folds more stations than device slots, same
             # contract as FederationMesh.fed_map)
             def one_station(tok):
-                (loss, load), grads = jax.value_and_grad(
+                (loss, (load, exits)), grads = jax.value_and_grad(
                     _loss_and_load, has_aux=True
                 )(params, tok, self.cfg, SEQ_AXIS)
                 # reduce over sequence shards WITHIN the station only
                 grads = lax.psum(grads, SEQ_AXIS)
                 loss = lax.pmean(loss, SEQ_AXIS)
-                if load is not None:
-                    load = lax.psum(load, SEQ_AXIS)
-                return loss, grads, load
+                return loss, grads, lax.psum((load, exits), SEQ_AXIS)
 
             with jax.named_scope("local_train"):
                 return jax.vmap(one_station)(tokens_block)
@@ -552,7 +733,7 @@ class FedTransformer:
         # works around the pallas-interpret + VMA interaction that rejects
         # the flash kernel inside a checked shard_map (jax 0.9 asks for
         # exactly this workaround).
-        losses, grads, loads = jax.shard_map(
+        losses, grads, left = jax.shard_map(
             station_body,
             mesh=self.mesh,
             in_specs=(P(), P(STATION_AXIS, None, SEQ_AXIS)),
@@ -567,10 +748,11 @@ class FedTransformer:
             )
             params = optax.apply_updates(params, updates)
         loss = collectives.fed_mean(losses, mask=mask)
-        # the expert layers' counts, summed over the stations ([L, E_held]
-        # and [L]); None for a block without experts
-        load = jax.tree.map(lambda x: jnp.sum(x, axis=0), loads)
-        return params, opt_state, loss, load
+        # the expert layers' counts ([L, E_held] and [L]) or the exit
+        # distribution ([R]), summed over the stations; nothing for the
+        # plain block
+        load, exits = jax.tree.map(lambda x: jnp.sum(x, axis=0), left)
+        return params, opt_state, loss, load, exits
 
 
 def make_engine(
