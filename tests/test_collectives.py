@@ -152,3 +152,108 @@ def test_secure_sum_under_jit_on_mesh():
 
     out = prog(fm.shard_stacked(x))
     np.testing.assert_allclose(np.asarray(out), x.sum(0), atol=1e-2)
+
+
+# --------------------------------------------------------------------------
+# fed_mean over a named axis, round a ring (`OverAxis`)
+# --------------------------------------------------------------------------
+
+class _Chip:
+    def __init__(self, *coords):
+        self.coords = coords
+
+
+def _grid(nx, ny):
+    """Chips numbered row by row, as a TPU's are."""
+    return [_Chip(x, y, 0) for y in range(ny) for x in range(nx)]
+
+
+@pytest.mark.parametrize("chips", [
+    _grid(2, 2),
+    [_Chip(0, y, z) for z in range(2) for y in range(2)],  # another plane
+    [_Chip(x, y, 0) for x, y in [(1, 1), (0, 0), (0, 1), (1, 0)]],
+], ids=["2x2", "2x2_in_y_and_z", "2x2_in_another_order"])
+def test_station_ring_goes_round_the_square_of_a_2_by_2(chips):
+    ring = C.station_ring(chips)
+    assert sorted(ring) == list(range(4))
+    for a, b in zip(ring, ring[1:] + ring[:1]):
+        hop = sum(abs(p - q) for p, q in zip(chips[a].coords, chips[b].coords))
+        assert hop == 1, (ring, a, b)
+
+
+@pytest.mark.parametrize("chips", [
+    [object() for _ in range(4)],                      # say nothing (CPU)
+    _grid(4, 1),                                       # a line: no cycle
+    [_Chip(0, 0, 0), _Chip(1, 0, 0), _Chip(2, 0, 0), _Chip(0, 1, 0)],
+    _grid(2, 1),                                       # two chips
+    _grid(4, 2), _grid(3, 3),                          # no such mesh was read
+], ids=["no_coords", "line", "four_in_an_L", "pair", "4x2", "3x3"])
+def test_station_ring_is_by_index_where_it_knows_no_better(chips):
+    assert C.station_ring(chips) == tuple(range(len(chips)))
+
+
+def test_ring_groups_close_at_their_bytes(monkeypatch):
+    monkeypatch.setattr(C, "RING_GROUP_BYTES", 100)
+    assert C.ring_groups([60, 60, 10, 200, 5]) == [[0, 1], [2, 3], [4]]
+    assert C.ring_groups([100] * 3) == [[0], [1], [2]]
+    assert C.ring_groups([]) == []
+
+
+RING_MASKS = {
+    "all": [1, 1, 1, 1, 1, 1, 1, 1], "weighted": [3, 1, 0, 2, 1, 5, 1, 1],
+    "all_dropped": [0] * 8,
+}
+
+
+@pytest.mark.parametrize("weights", RING_MASKS)
+@pytest.mark.parametrize("slots", [2, 4, 8])
+def test_fed_mean_over_an_axis_is_fed_mean_and_the_same_bits_on_every_slot(
+        slots, weights):
+    """Leaves of every kind a bucket is made of: whole lanes (laid row on
+    row), odd shapes and a scalar (raveled into one vector), two dtypes; a
+    dropped station holds NaN."""
+    if len(jax.devices()) < slots:
+        pytest.skip(f"needs {slots} fake devices")
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    w = np.asarray(RING_MASKS[weights], np.float32)
+    rng = np.random.default_rng(7)
+    groups = [
+        {"a": rng.normal(size=(8, 64, 128)), "b": rng.normal(size=(8, 32, 128)),
+         "odd": rng.normal(size=(8, 5, 3)), "loss": rng.normal(size=(8,))},
+        [rng.normal(size=(8, 2, 16, 256)), rng.normal(size=(8, 7))],
+    ]
+    groups = jax.tree.map(lambda x: x.astype(np.float32), groups)
+    groups[1][1] = groups[1][1].astype(jnp.bfloat16)
+    for x in jax.tree.leaves(groups):
+        x[w == 0] = np.nan
+    total = w.sum()
+    denom = np.float32(total if total > 0 else 1.0)
+    mesh = Mesh(np.array(jax.devices()[:slots]), ("station",))
+    ring = tuple(np.random.default_rng(1).permutation(slots).tolist())
+    out = jax.jit(jax.shard_map(
+        lambda g, w: jax.tree.map(
+            lambda x: x[None], [C.fed_mean(
+                C.OverAxis(group, "station", ring, denom), weights=w)
+                for group in g]),
+        mesh=mesh, in_specs=(P("station"), P("station")),
+        out_specs=P("station"), check_vma=False))(groups, w)
+    want = [C.fed_mean(g, weights=w) for g in groups]
+    for got, expect in zip(jax.tree.leaves(out), jax.tree.leaves(want)):
+        got = np.asarray(got.astype(jnp.float32))
+        assert got.shape == (slots, *expect.shape)
+        assert all(np.array_equal(got[0], other) for other in got[1:])
+        # a bfloat16 leaf is summed in bfloat16, as `fed_mean` sums it: a
+        # rounding a station, in another order (`_norm_weights`)
+        coarse = expect.dtype == jnp.bfloat16
+        np.testing.assert_allclose(
+            got[0], np.asarray(expect.astype(jnp.float32)),
+            rtol=5e-2 if coarse else 1e-5, atol=4e-3 if coarse else 1e-6)
+
+
+def test_ring_bytes_sent_counts_both_ways_and_the_padding():
+    # [64, 128] f32 is one bucket of whole lanes: 64 rows pad to 2 * 4 * 8
+    one = [((64, 128), jnp.float32)]
+    assert C.ring_bytes_sent(one, 4) == 2 * 3 * 64 * 128 * 4 // 4
+    # a scalar rides in a vector padded to 2 * 4 chunks of a whole tile
+    assert C.ring_bytes_sent([((), jnp.float32)], 4) == 2 * 3 * 8 * 1024 * 4 // 4

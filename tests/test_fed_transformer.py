@@ -315,3 +315,110 @@ class TestRemat:
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
             )
+
+
+# ---------------------------------------------------------------------------
+# The round on several slots: the cross-station mean goes round a ring inside
+# the mesh (`collectives.OverAxis`, `RingExchange`). It is `fed_mean`
+# over the stacked gradients all the same: the plain statement below.
+# ---------------------------------------------------------------------------
+
+MESHES = {  # stations, devices: slots x stations packed in each
+    "4x1": (4, 4),
+    "2x2": (4, 2),
+    "4x2": (8, 4),
+}
+MASKS = {
+    "all": lambda s: np.ones(s, np.float32),
+    "one_dropped": lambda s: np.r_[0.0, np.ones(s - 1)].astype(np.float32),
+    "a_dropped_station_is_nan": lambda s: np.r_[
+        0.0, np.ones(s - 1)].astype(np.float32),
+    "all_dropped": lambda s: np.zeros(s, np.float32),
+}
+POISON = 96  # the station whose first token is this has a NaN loss
+
+
+def _plain_round(engine, params, opt_state, tokens, mask):
+    """What a round computes, stated with `fed_mean` on one device: every
+    station's loss and gradient, the masked mean of both, one Adam step."""
+    import optax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from vantage6_tpu.core.mesh import STATION_AXIS
+    from vantage6_tpu.fed import collectives
+
+    one = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+               (STATION_AXIS, FT.SEQ_AXIS))
+
+    def stations(params, tokens):
+        def station(tok):
+            return jax.value_and_grad(
+                lambda p: FT._loss_and_load(p, tok, engine.cfg, FT.SEQ_AXIS)[0]
+            )(params)
+        return jax.vmap(station)(tokens)
+
+    losses, grads = jax.shard_map(
+        stations, mesh=one,
+        in_specs=(P(), P(STATION_AXIS, None, FT.SEQ_AXIS)),
+        out_specs=P(STATION_AXIS), check_vma=False)(params, tokens)
+    updates, opt_state = engine.optimizer.update(
+        collectives.fed_mean(grads, mask=mask), opt_state, params)
+    return (optax.apply_updates(params, updates), opt_state,
+            collectives.fed_mean(losses, mask=mask))
+
+
+@pytest.mark.parametrize("masked", MASKS)
+@pytest.mark.parametrize("mesh", MESHES)
+def test_a_round_on_several_slots_is_fed_mean_of_the_stacked_gradients(
+        mesh, masked, monkeypatch):
+    n_stations, n_devices = MESHES[mesh]
+    if len(jax.devices()) < n_devices:
+        pytest.skip(f"needs {n_devices} fake devices")
+    from vantage6_tpu.fed import collectives
+
+    # every layer a group of its own, so that groups wait for one another
+    monkeypatch.setattr(collectives, "RING_GROUP_BYTES", 1)
+    if masked == "a_dropped_station_is_nan":
+        whole = FT._loss_and_load
+
+        def poisoned(params, tok, *args):
+            loss, left = whole(params, tok, *args)
+            return loss * jnp.where(tok[0, 0] == POISON, jnp.nan, 1.0), left
+
+        monkeypatch.setattr(FT, "_loss_and_load", poisoned)
+    cfg = FT.TransformerConfig(vocab=97, d_model=32, n_heads=4, n_layers=3,
+                               max_len=16, attention="recompute")
+    engine = FT.make_engine(n_stations, 1, cfg,
+                            devices=jax.devices()[:n_devices])
+    tokens = np.minimum(
+        FT.make_federated_tokens(n_stations, 2, 16, 97), POISON - 1)
+    tokens[0, 0, 0] = POISON
+    mask = MASKS[masked](n_stations)
+    params, opt_state = engine.init(jax.random.key(0))
+    want = _plain_round(
+        engine, *jax.device_get((params, opt_state)), tokens, mask)
+    said = dict(engine.aggregation(params))
+    # no option of the TPU's compiler reaches this CPU compile: it would be
+    # refused ("No such compile option")
+    got = engine.round(
+        params, opt_state, engine.shard_tokens(tokens), jnp.asarray(mask))
+    assert all(np.isfinite(x).all() for x in jax.tree.leaves(got))
+    # the optimizer's moments are the mean gradient (and its square) scaled,
+    # and the loss is a mean: float32 rounding of another order of summing
+    for a, b in zip(jax.tree.leaves(got[1:]), jax.tree.leaves(want[1:])):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=1e-6 * np.abs(b).max())
+    # Adam's first step moves a weight by lr g / (|g| + eps): where |g| is
+    # near eps, the last bit of g is a good part of a step of lr = 1e-3
+    for a, b in zip(jax.tree.leaves(got[0]), jax.tree.leaves(want[0])):
+        np.testing.assert_allclose(a, b, rtol=0, atol=2e-4)
+    # every slot's copy of every parameter holds the same bits
+    for x in jax.tree.leaves(got[:2]):
+        first, *others = [np.asarray(s.data) for s in x.addressable_shards]
+        assert len(others) == n_devices - 1
+        assert all(np.array_equal(first, other, equal_nan=True)
+                   for other in others)
+    assert said.pop("aggregate_bytes") > 2 * sum(
+        x.nbytes for x in jax.tree.leaves(want[0])) * (
+            n_devices - 1) // n_devices
+    assert said == {"aggregate_overlap": "ring", "aggregate_groups": 3}

@@ -39,13 +39,13 @@ def _tracer_on():
 
 
 # ------------------------------------------------------------ tiny engines
-def _transformer(attention="recompute"):
+def _transformer(attention="recompute", slots=1):
     ring = attention == "ring"
     cfg = FT.TransformerConfig(
         vocab=97, d_model=32, n_heads=4, n_layers=2, max_len=16,
         attention=attention, flash_interpret=True, remat=ring)
     engine = FT.make_engine(4, 2 if ring else 1, cfg,
-                            devices=jax.devices()[: 8 if ring else 1])
+                            devices=jax.devices()[: 8 if ring else slots])
     params, opt_state = engine.init(jax.random.key(0))
     tokens = engine.shard_tokens(FT.make_federated_tokens(4, 2, 16, 97))
     return engine, (params, opt_state, tokens, jnp.ones(4))
@@ -67,7 +67,7 @@ def _transformer_experts():
     return engine, (params, opt_state, tokens, jnp.ones(4))
 
 
-def _transformer_looped():
+def _transformer_looped(slots=1):
     """A stack of two layers walked three times over the same weights, with
     the exit gate, norms after each half too and the gated MLP."""
     cfg = FT.TransformerConfig(
@@ -75,7 +75,7 @@ def _transformer_looped():
         attention="recompute", flash_interpret=True, remat=True,
         norm="rmsnorm", norm_after=True, positions="rotary", ffn="swiglu",
         d_ff=48, tie_head=False, loops=3, exit_beta=0.05)
-    engine = FT.make_engine(4, 1, cfg, devices=jax.devices()[:1])
+    engine = FT.make_engine(4, 1, cfg, devices=jax.devices()[:slots])
     params, opt_state = engine.init(jax.random.key(0))
     tokens = engine.shard_tokens(FT.make_federated_tokens(4, 2, 16, 97))
     return engine, (params, opt_state, tokens, jnp.ones(4))
@@ -218,9 +218,11 @@ ENGINES = {"fed_transformer.round": _call_transformer,
            "fedavg.round": _call_fedavg_round}
 # what an engine says of the program it launches, beside engine and rounds
 # (the transformer: a sequence of 16 is one tile; two layers of one head)
+NO_RING = {"aggregate_overlap": "none", "aggregate_groups": 0,
+           "aggregate_bytes": 0}
 ENGINE_ATTRS = {"fed_transformer.round": {"attention_tile": "16x16",
                                           "attention_tiles_visited": 2,
-                                          "attention_tiles": 2},
+                                          "attention_tiles": 2, **NO_RING},
                 "fedavg.run_rounds": {"gather": "packed"},
                 "fedavg.round": {"gather": "packed"}}
 
@@ -328,12 +330,39 @@ def test_a_looped_stack_says_its_walks_and_counts_tiles_over_applications(
     assert call["attrs"] == {
         "engine": "fed_transformer.round", "rounds": 1,
         "attention_tile": "4x4", "attention_tiles_visited": 60,
-        "attention_tiles": 96, "loops": 3, "layer_applications": 6}
+        "attention_tiles": 96, "loops": 3, "layer_applications": 6,
+        **NO_RING}
     TRACER.clear()
     engine, args = _transformer()
     engine.round(*args)
     (call,) = _named(TRACER.drain(), "engine.call")
     assert not {"loops", "layer_applications"} & set(call["attrs"])
+
+
+@pytest.mark.parametrize("engine", ["one_slot", "four_slots",
+                                    "looped_on_four_slots"])
+def test_the_engine_call_says_how_the_cross_station_mean_is_taken(engine):
+    """`aggregate_overlap`, `aggregate_groups` and `aggregate_bytes` on the
+    recorded `engine.call` span of a round: a ring on several slots (the two
+    tiny layers are one group; what a chip sends is the parameters' bytes
+    twice round less a chunk, and the padding), none on one slot, and none
+    where a stack is walked more than once, which keeps `fed_mean`'s
+    all-reduces on any mesh."""
+    slots = 1 if engine == "one_slot" else 4
+    if len(jax.devices()) < slots:
+        pytest.skip(f"needs {slots} fake devices")
+    build = _transformer_looped if "looped" in engine else _transformer
+    engine_, args = build(slots=slots)
+    held = sum(x.nbytes for x in jax.tree.leaves(args[0]))
+    out = engine_.round(*args)
+    assert np.isfinite(float(out[2]))
+    (call,) = _named(TRACER.drain(), "engine.call")
+    said = {k: v for k, v in call["attrs"].items() if k in NO_RING}
+    if engine != "four_slots":
+        assert said == NO_RING
+        return
+    assert said.pop("aggregate_bytes") >= 2 * 3 * held // 4
+    assert said == {"aggregate_overlap": "ring", "aggregate_groups": 1}
 
 
 def test_the_exit_distribution_is_one_span_read_outside_the_round():

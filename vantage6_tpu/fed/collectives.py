@@ -15,10 +15,12 @@ bit-accurate FedAvg-with-dropout without breaking the single-program model.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any, TYPE_CHECKING
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from vantage6_tpu.core.mesh import STATION_AXIS, station_shard_map
@@ -116,7 +118,13 @@ def fed_mean(
     Accumulation and division happen in each leaf's own dtype (see
     ``_norm_weights`` for the full numerics contract) — use
     ``fed_mean_scattered`` when f32 accumulation over bf16 leaves matters.
+
+    ``stacked`` may also be the stations of ONE slot of a mesh axis, inside
+    a `shard_map` (`OverAxis`): the same mean over ALL stations, its
+    cross-slot part a ring of asynchronous steps (`_ring_mean`).
     """
+    if isinstance(stacked, OverAxis):
+        return stacked.mean(weights)
     with jax.named_scope("aggregate"):
         n = _station_count(stacked)
         w = _norm_weights(n, weights, mask)
@@ -126,6 +134,285 @@ def fed_mean(
         return jax.tree.map(
             lambda x: _weighted_leaf_sum(x, w) / jnp.asarray(denom, x.dtype), stacked
         )
+
+
+# --------------------------------------------------------------------------
+# fed_mean over a named axis, as a ring: the cross-slot reduce made of
+# asynchronous `ppermute` steps that run beside whatever else is ready (the
+# backward pass of the layers below, the optimizer on the groups already
+# reduced), where `fed_mean`'s `jnp.sum` over a sharded axis becomes an
+# all-reduce with nothing beside it (PERF.md section 6, PR 36).
+# --------------------------------------------------------------------------
+
+# a group of leaves is closed once it holds this much. A step's fixed cost
+# and the waits at its end are paid once a step, whatever it carries, and
+# the compiler writes the gradients of a group's layers straight into its
+# buckets; but the last group made goes round with nothing left to run
+# beside it but the optimizer. Read on four v5e chips at GPT-2 medium's 48
+# MiB a layer (PERF.md section 6, PR 36): a round of 91.1 ms at 32 MiB (a
+# layer a group), 88.9 at 128 MiB (three), against 91.6 to 92.2 for the
+# all-reduces this replaces.
+RING_GROUP_BYTES = 128 << 20
+_SUBLANES, _LANES = 8, 128  # the tile a float32 array of rank >= 2 is held in
+
+
+def station_ring(devices: Any) -> tuple[int, ...]:
+    """The order in which the slots of a mesh axis are joined into a ring,
+    as positions along the axis, read from the slots' ``devices``. Four
+    chips that say where they sit (``coords``, as a TPU's do) in a 2 x 2 go
+    round its square, so that every step of the ring crosses one link (the
+    order by index crosses the diagonal twice). Anything else (CPU devices,
+    a line of chips, a larger grid: none has been read) is joined by index."""
+    devices = list(devices)
+    coords = [getattr(dev, "coords", None) for dev in devices]
+    if len(devices) == 4 and None not in coords:
+        varying = [k for k in range(len(coords[0]))
+                   if len({c[k] for c in coords}) > 1]
+        if len(varying) == 2:
+            place = {(c[varying[0]], c[varying[1]]): i
+                     for i, c in enumerate(coords)}
+            xs, ys = (sorted({xy[k] for xy in place}) for k in range(2))
+            if len(place) == 4 and len(xs) == len(ys) == 2:
+                return tuple(place[x, y] for x, y in (
+                    (xs[0], ys[0]), (xs[1], ys[0]),
+                    (xs[1], ys[1]), (xs[0], ys[1])))
+    return tuple(range(len(devices)))
+
+
+def ring_groups(nbytes: list[int]) -> list[list[int]]:
+    """Consecutive items gathered into groups of `RING_GROUP_BYTES` or more
+    (the last group may hold less): which of them are reduced together."""
+    groups: list[list[int]] = []
+    held = 0
+    for i, n in enumerate(nbytes):
+        if not groups or held >= RING_GROUP_BYTES:
+            groups.append([])
+            held = 0
+        groups[-1].append(i)
+        held += n
+    return groups
+
+
+def _ring_buckets(shapes: list[tuple[tuple[int, ...], Any]], d: int):
+    """How a group's leaves lie on the wire: ``[(key, leaf indices, rows)]``.
+    Leaves of one dtype and last dimension (whole lanes) are laid row on row
+    without leaving their tiles, the rest are raveled into one vector a
+    dtype; ``rows`` is the bucket's length padded so that both directions'
+    ``d`` chunks are whole tiles."""
+    found: dict[Any, list[int]] = {}
+    for i, (shape, dtype) in enumerate(shapes):
+        tiled = (len(shape) >= 2 and shape[-1] % _LANES == 0
+                 and math.prod(shape[:-1]) >= 2 * d * _SUBLANES)
+        found.setdefault(
+            (jnp.dtype(dtype), shape[-1] if tiled else None), []).append(i)
+    out = []
+    for key, members in found.items():
+        width = key[1] or 1
+        rows = sum(math.prod(shapes[i][0]) for i in members) // width
+        unit = 2 * d * (_SUBLANES if key[1] else _SUBLANES * _LANES)
+        out.append((key, members, rows + (-rows) % unit))
+    return out
+
+
+def ring_bytes_sent(shapes: list[tuple[tuple[int, ...], Any]], d: int) -> int:
+    """What one slot sends when a group of leaves of these shapes and dtypes
+    goes round a ring of ``d``: ``d - 1`` steps of a reduce-scatter and as
+    many of an all-gather, a ``d``-th of the (padded) group a step."""
+    return sum(
+        2 * (d - 1) * rows * (key[1] or 1) * key[0].itemsize // d
+        for key, _, rows in _ring_buckets(shapes, d))
+
+
+def _ring_ways(axis_name: str, ring: tuple[int, ...]):
+    """The two ways round ``ring``: for each its `ppermute` pairs and, for
+    THIS slot, the chunk it holds whole at every step (its place in the ring
+    counted the way that half travels, plus one, less the steps gone), read
+    from one table by `axis_index`."""
+    d = len(ring)
+    place = np.argsort(ring)
+    ways = []
+    for step in (1, -1):
+        perm = [(ring[i], ring[(i + step) % d]) for i in range(d)]
+        r = place if step == 1 else (d - place) % d
+        ways.append((perm, (r[:, None] + 1 - np.arange(d + 1)) % d))
+    table = jnp.asarray(np.stack([chunks for _, chunks in ways], 1), jnp.int32)
+    mine = table[jax.lax.axis_index(axis_name)]
+    return [(perm, [mine[half, s] for s in range(d + 1)])
+            for half, (perm, _) in enumerate(ways)]
+
+
+def _ring_mean(x: jax.Array, denom: jax.Array, axis_name: str, ways) -> jax.Array:
+    """``x`` [2, d, ...]: this slot's partial sums, chunk by chunk; half 0
+    goes round the ring one way and half 1 the other (``ways``:
+    `_ring_ways`), so that both links of a chip carry it. A reduce-scatter
+    (chunk c starts at the slot in place c and picks up one slot's part a
+    step, so it is summed in ONE order, and ends whole, and is divided, in
+    place c - 1) and then an all-gather that copies the whole chunks round:
+    every slot ends with the same bits. Nothing but the next step reads
+    what a step makes: a second reader (a mark of the reduce-scatter's end,
+    say) makes the compiler lay the buffer out another way and copy it whole
+    at every update, 23 ms of a GPT-2 medium round (PERF.md section 6)."""
+    d = x.shape[1]
+    zeros = (0,) * (x.ndim - 2)
+
+    def chunk(half, c):
+        return jax.lax.dynamic_slice(
+            x, (half, c, *zeros), (1, 1, *x.shape[2:]))
+
+    # at step s a slot in place r holds chunk r - s of the sum under way, and
+    # chunk r + 1 - s of the whole ones: ``at[s]`` is chunk r + 1 - s
+    part = [chunk(half, at[1]) for half, (_, at) in enumerate(ways)]
+    for s in range(d - 1):
+        part = [jax.lax.ppermute(p, axis_name, perm) + chunk(half, at[s + 2])
+                for half, (p, (perm, at)) in enumerate(zip(part, ways))]
+    part = [p / jnp.asarray(denom, x.dtype) for p in part]
+    out = x  # every chunk of it is written over: no second buffer
+    for s in range(d):
+        for half, (p, (_, at)) in enumerate(zip(part, ways)):
+            out = jax.lax.dynamic_update_slice(out, p, (half, at[s], *zeros))
+        if s < d - 1:
+            part = [jax.lax.ppermute(p, axis_name, perm)
+                    for p, (perm, _) in zip(part, ways)]
+    return out
+
+
+# traced once for every list of shapes: the layers of a stack after the
+# first hit jax's caches, and the lowering emits one function and calls it
+@partial(jax.jit, static_argnames=("axis_name", "ring"))
+def _ring_group(sums: list[jax.Array], denom: jax.Array, *, axis_name: str,
+                ring: tuple[int, ...]) -> list[jax.Array]:
+    """The slots' ``sums`` (one group's leaves, each slot's own part) added
+    over ``axis_name`` and divided by ``denom``, round ``ring``."""
+    d = len(ring)
+    ways = _ring_ways(axis_name, ring)
+    means: list[Any] = [None] * len(sums)
+    for (_, width), members, rows in _ring_buckets(
+            [(x.shape, x.dtype) for x in sums], d):
+        tail = (width,) if width else ()
+        flat = [sums[i].reshape(-1, *tail) for i in members]
+        flat = flat[0] if len(flat) == 1 else jnp.concatenate(flat)
+        flat = jnp.pad(
+            flat, [(0, rows - flat.shape[0])] + [(0, 0)] * len(tail))
+        mean = _ring_mean(flat.reshape(2, d, rows // (2 * d), *tail), denom,
+                          axis_name, ways).reshape(rows, *tail)
+        at = 0
+        for i in members:
+            n = sums[i].size // (width or 1)
+            means[i] = mean[at:at + n].reshape(sums[i].shape)
+            at += n
+    return means
+
+
+def _nothing(x: jax.Array) -> jax.Array:
+    """Zero, whatever ``x`` holds, and not before ``x`` exists: what ties an
+    operation to one it does not read (the compiler folds neither the
+    product nor the clamp away, and drops an `optimization_barrier` before
+    it orders the program)."""
+    return jnp.clip(jnp.where(x == x, x, 0.0), -1.0, 1.0) * 0.0
+
+
+class OverAxis:
+    """What `fed_mean` takes INSIDE a `shard_map` over a mesh axis along
+    which the stations lie: ``stacked``, the leaves [K, ...] of the K
+    stations packed in THIS slot (``weights`` then their own [K], float32,
+    mask applied), and what says how the slots are joined: the axis, the
+    ring (`station_ring`) and ``denom``, the effective total weight of ALL
+    stations, guarded as `fed_mean` guards it. Where the K stations are the
+    lanes of a `vmap` named ``packed_axis`` and not an axis of the leaves,
+    ``stacked`` holds the one station of this lane ([1, ...]).
+
+    The mean is `fed_mean`'s to the letter: in each leaf's dtype, the slot's
+    stations summed first as ``where(w != 0, x, 0) * w`` (a dropped
+    station's NaN never leaves its slot), then the slots' sums added and
+    divided by ``denom``; the same bits on every slot. What differs is what
+    carries it: the leaves go round the ring as `_ring_mean` has it,
+    4 (d - 1) `ppermute` steps a bucket, which the compiler makes
+    asynchronous. It also places them as late as it may: for steps that lie
+    between the operations that make the next leaves, see `RingExchange`."""
+
+    def __init__(self, stacked: Pytree, axis_name: str, ring: tuple[int, ...],
+                 denom: jax.Array, packed_axis: str | None = None):
+        self.stacked, self.denom = stacked, denom
+        self.axis_name, self.ring, self.packed_axis = (
+            axis_name, ring, packed_axis)
+
+    def __getitem__(self, k: int) -> Pytree:
+        """Station ``k`` of this slot, as it is: nothing crosses anything.
+        For the benchmark's planted fault alone (`no_exchange` patches
+        `fed_mean` to hand back ``stacked[0]``); no program calls it. It
+        goes when a `benchmark` PR plants that fault by name (PERF.md 7.10d,
+        ROADMAP.md A5)."""
+        return jax.tree.map(lambda x: x[k], self.stacked)
+
+    def mean(self, w: jax.Array) -> Pytree:
+        with jax.named_scope("aggregate"):
+            leaves, treedef = jax.tree.flatten(self.stacked)
+            sums = [_weighted_leaf_sum(x, w) for x in leaves]
+            if self.packed_axis is not None:
+                sums = [jax.lax.psum(x, self.packed_axis) for x in sums]
+            return jax.tree.unflatten(treedef, _ring_group(
+                sums, self.denom, axis_name=self.axis_name, ring=self.ring))
+
+
+class RingExchange:
+    """`fed_mean` over a mesh axis from INSIDE a backward pass, so that a
+    group's steps round the ring lie between the operations that make the
+    next groups' gradients and not behind them all. One instance serves one
+    trace of one station's loss (under the `vmap` over the stations packed
+    in a slot, named ``packed_axis``, inside the `shard_map` over
+    ``axis_name``); ``w`` is that station's weight and ``denom`` the
+    effective total weight.
+
+    ``x, leaves = exchange(x, leaves)`` is the identity on the way forward.
+    On the way back the cotangent of ``leaves`` (the station's gradient of
+    them, whole by then) comes out as its `fed_mean` over all stations
+    (`OverAxis`), and the cotangent of ``x`` (the activations on their way
+    to the layers below) is held until the group of the call before
+    (further up) has come round. The compiler's scheduler places every
+    operation as late as it may and would leave all the steps to the end of
+    the backward pass, five under way at a time and nothing beside them;
+    held so, a group's steps can lie nowhere but beside the backward pass of
+    the layers just below it and the optimizer's step on the group above."""
+
+    def __init__(self, w: jax.Array, denom: jax.Array, axis_name: str,
+                 ring: tuple[int, ...], packed_axis: str):
+        self._args = (w, denom)
+        # on the way back: a mark of the group further up, which exists
+        # when that group has come round
+        self._came_round = jnp.zeros((1,), jnp.float32)
+
+        @jax.custom_vjp
+        def exchange(x, leaves, came_round, w, denom):
+            return x, leaves, came_round
+
+        def forward(x, leaves, came_round, w, denom):
+            return (x, leaves, came_round), (w, denom)
+
+        def backward(weights, cotangents):
+            w, denom = weights
+            dx, grads, further_up = cotangents
+            mean = fed_mean(
+                OverAxis(jax.tree.map(lambda g: g[None], grads), axis_name,
+                         ring, denom, packed_axis), weights=w[None])
+            with jax.named_scope("aggregate"):
+                dx = dx + _nothing(further_up[0]).astype(dx.dtype)
+                # of the mean as it comes back and of nothing on its way: a
+                # second reader of what a step makes costs 23 ms a round
+                # (`_ring_mean`)
+                came_round = sum(
+                    jax.lax.slice(m, (0,) * m.ndim, (1,) * m.ndim)
+                    .reshape(1).astype(jnp.float32)
+                    for m in jax.tree.leaves(mean))
+            return (dx, mean, came_round,
+                    jnp.zeros_like(w), jnp.zeros_like(denom))
+
+        exchange.defvjp(forward, backward)
+        self._exchange = exchange
+
+    def __call__(self, x: jax.Array, leaves: Pytree) -> tuple[jax.Array, Pytree]:
+        x, leaves, self._came_round = self._exchange(
+            x, leaves, self._came_round, *self._args)
+        return x, leaves
 
 
 def fed_weighted_stats(

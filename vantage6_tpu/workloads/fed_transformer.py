@@ -291,12 +291,16 @@ def _forward(
     tokens_local: jax.Array,
     cfg: TransformerConfig,
     axis_name: str,
+    exchange: collectives.RingExchange | None = None,
 ) -> tuple[list[jax.Array], list[Any]]:
     """The stream after the final norm, once for every walk of the stack
     (the head reads these: `forward_local`, `_loss_and_load`), and the
     expert layers' load, one entry a layer: the assignments each held expert
     received and the choices that named one (none for a block without
-    experts)."""
+    experts). With an ``exchange`` the stream and each group of layers
+    (`_layer_groups`) pass through it on their way into the group: nothing
+    on the way forward, the cross-station mean of the group's gradient on
+    the way back (`FedTransformer._round` on several slots)."""
     b, t_local = tokens_local.shape
     offset = lax.axis_index(axis_name) * t_local  # global positions
     n_q, n_kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
@@ -403,7 +407,15 @@ def _forward(
 
     def stack(x):
         loads = []
-        for i, layer in enumerate(params["layers"]):
+        layers = params["layers"]
+        if exchange is not None:
+            layers = list(layers)
+            entered = {group[0]: group for group in _layer_groups(layers)}
+        for i in range(len(layers)):
+            if exchange is not None and i in entered:
+                x, layers[i:entered[i][-1] + 1] = exchange(
+                    x, [layers[j] for j in entered[i]])
+            layer = layers[i]
             x, routing = block(
                 x, layer, cfg.layer_window(i), cfg.layer_rotates(i))
             if cfg.ffn == "experts":
@@ -500,12 +512,12 @@ def _exit_loss(states, params, tokens_local, cfg):
                 jnp.sum(p, axis=(1, 2)))
 
 
-def _loss_and_load(params, tokens_local, cfg, axis_name):
+def _loss_and_load(params, tokens_local, cfg, axis_name, exchange=None):
     """The loss, and what a round leaves on the device beside it: the expert
     layers' load stacked over the layers, and the exit distribution summed
     over the predicted positions [R]; each ``None`` where the block has no
-    such thing."""
-    states, loads = _forward(params, tokens_local, cfg, axis_name)
+    such thing. ``exchange``: `_forward`'s."""
+    states, loads = _forward(params, tokens_local, cfg, axis_name, exchange)
     load = exits = None
     if cfg.loops > 1:
         local_sum, exits = _exit_loss(states, params, tokens_local, cfg)
@@ -543,6 +555,17 @@ def loss_local(
     return _loss_and_load(params, tokens_local, cfg, axis_name)[0]
 
 
+PACKED_AXIS = "packed"  # the stations packed in one slot of the mesh
+
+
+def _layer_groups(layers: list[Any]) -> list[list[int]]:
+    """The layers whose gradients cross the stations together: whole
+    consecutive layers, gathered by their bytes (`collectives.ring_groups`)."""
+    return collectives.ring_groups(
+        [sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(layer))
+         for layer in layers])
+
+
 @dataclasses.dataclass(eq=False)  # identity hash: engine is a jit static arg
 class FedTransformer:
     """Training engine over a ('station', 'device') mesh."""
@@ -560,6 +583,8 @@ class FedTransformer:
         default_factory=lambda: collections.deque(maxlen=4096), repr=False)
     # what `round`'s span says of the attention's walk, by sequence length
     _walks: dict = dataclasses.field(default_factory=dict, repr=False)
+    # and of the cross-station mean (`aggregation`)
+    _aggregation: dict | None = dataclasses.field(default=None, repr=False)
 
     def init(self, key: jax.Array) -> tuple[Any, Any]:
         # params AND the whole optimizer state are committed to the mesh:
@@ -603,9 +628,11 @@ class FedTransformer:
         leaves handed over, ``n_donated`` = those of them the program may
         write its outputs into). With ``attention="recompute"`` the
         ``engine.call`` span also carries the walk the program was built
-        with (`attention_walk`), and where the stack is walked more than
-        once, ``loops`` and ``layer_applications``."""
-        attrs = self.attention_walk(tokens.shape[-1])
+        with (`attention_walk`), always how the cross-station mean is taken
+        (`aggregation`), and where the stack is walked more than once,
+        ``loops`` and ``layer_applications``."""
+        attrs = {**self.attention_walk(tokens.shape[-1]),
+                 **self.aggregation(params)}
         if self.cfg.loops > 1:
             attrs = {**attrs, "loops": self.cfg.loops,
                      "layer_applications": self.cfg.loops * self.cfg.n_layers}
@@ -644,6 +671,45 @@ class FedTransformer:
                 "attention_tiles_visited": int(counts[0]),
                 "attention_tiles": int(counts[1])}
         return self._walks[t]
+
+    @property
+    def _rings(self) -> bool:
+        """Whether the cross-station mean goes round a ring inside the mesh
+        (`_round`): on several slots. Not where the stack is walked more
+        than once: a layer's gradient is whole only when its FIRST walk's
+        is, a ring behind the whole backward pass has nothing to run beside,
+        and no looped stack on several chips has been read. That keeps
+        `fed_mean`'s all-reduces, as one slot does."""
+        return self.mesh.shape[STATION_AXIS] > 1 and self.cfg.loops == 1
+
+    def aggregation(self, params: Any) -> dict[str, Any]:
+        """How the program takes a round's cross-station mean, from the mesh
+        and the shapes of ``params`` (arrays or shapes; computed once, the
+        configuration fixes them): ``aggregate_overlap`` ``"ring"`` where the
+        mean goes round the slots' ring beside the backward pass (`_rings`)
+        and ``"none"`` elsewhere (`fed_mean` after it), ``aggregate_groups``
+        the groups of layers whose mean is taken INSIDE the backward pass
+        (`collectives.RingExchange`; what is outside the layers follows it)
+        and ``aggregate_bytes`` what one chip sends round the ring a round;
+        both 0 without a ring. Host integers on `round`'s ``engine.call``
+        span; no program reads them."""
+        if self._aggregation is None:
+            slots = self.mesh.shape[STATION_AXIS]
+            crossing = []
+            if self._rings:
+                crossing = [[params["layers"][i] for i in group]
+                            for group in _layer_groups(params["layers"])]
+                crossing.append({name: x for name, x in params.items()
+                                 if name != "layers"})
+            self._aggregation = {
+                "aggregate_overlap": "ring" if self._rings else "none",
+                "aggregate_groups": max(len(crossing) - 1, 0),
+                "aggregate_bytes": sum(
+                    collectives.ring_bytes_sent(
+                        [(x.shape, x.dtype) for x in jax.tree.leaves(group)],
+                        slots)
+                    for group in crossing)}
+        return self._aggregation
 
     def record_expert_load(self) -> dict[str, Any] | None:
         """Read the expert layers' counts of the rounds since the last call
@@ -710,38 +776,73 @@ class FedTransformer:
         self, params: Any, opt_state: Any, tokens: jax.Array,
         mask: jax.Array,
     ) -> tuple[Any, Any, jax.Array, Any, Any]:
-        def station_body(params, tokens_block):
+        rings = self._rings
+        ring = collectives.station_ring(self.mesh.devices[:, 0])
+
+        def station_body(params, tokens_block, w=None, denom=None):
             # tokens_block: [S/D_s, B, T/P] — the inner vmap walks the
             # stations PACKED into this mesh slot (stations_per_slot > 1
             # when the mesh folds more stations than device slots, same
             # contract as FederationMesh.fed_map)
-            def one_station(tok):
+            def one_station(tok, w_own=None):
+                exchange = collectives.RingExchange(
+                    w_own, denom, STATION_AXIS, ring, PACKED_AXIS
+                ) if rings else None
                 (loss, (load, exits)), grads = jax.value_and_grad(
                     _loss_and_load, has_aux=True
-                )(params, tok, self.cfg, SEQ_AXIS)
+                )(params, tok, self.cfg, SEQ_AXIS, exchange)
                 # reduce over sequence shards WITHIN the station only
                 grads = lax.psum(grads, SEQ_AXIS)
                 loss = lax.pmean(loss, SEQ_AXIS)
                 return loss, grads, lax.psum((load, exits), SEQ_AXIS)
 
             with jax.named_scope("local_train"):
-                return jax.vmap(one_station)(tokens_block)
+                if not rings:
+                    return jax.vmap(one_station)(tokens_block)
+                return jax.vmap(one_station, axis_name=PACKED_AXIS)(
+                    tokens_block, w)
 
         # Variance checking OFF, same stance (and reason) as fed_map: the
         # station body is a purely local program whose only cross-device
-        # reductions are the EXPLICIT psums over SEQ_AXIS above; it also
+        # traffic is the explicit psum over the station's own 'device' axis;
         # works around the pallas-interpret + VMA interaction that rejects
         # the flash kernel inside a checked shard_map (jax 0.9 asks for
         # exactly this workaround).
-        losses, grads, left = jax.shard_map(
-            station_body,
-            mesh=self.mesh,
-            in_specs=(P(), P(STATION_AXIS, None, SEQ_AXIS)),
-            out_specs=(P(STATION_AXIS), P(STATION_AXIS), P(STATION_AXIS)),
-            check_vma=False,
-        )(params, tokens)
-        # explicit cross-station aggregation: the ONLY place station data mixes
-        g_mean = collectives.fed_mean(grads, mask=mask)
+        if not rings:
+            losses, grads, left = jax.shard_map(
+                station_body,
+                mesh=self.mesh,
+                in_specs=(P(), P(STATION_AXIS, None, SEQ_AXIS)),
+                out_specs=(P(STATION_AXIS), P(STATION_AXIS), P(STATION_AXIS)),
+                check_vma=False,
+            )(params, tokens)
+            # explicit cross-station aggregation: the ONLY place station data mixes
+            g_mean = collectives.fed_mean(grads, mask=mask)
+        else:
+            # the stations lie on several slots: the same mean of the
+            # gradients, taken inside the mesh round a ring of asynchronous
+            # steps that run beside the backward pass and the optimizer
+            # (`collectives.OverAxis`, `RingExchange`)
+            def ring_body(params, tokens_block, w, denom):
+                losses, grads, left = station_body(
+                    params, tokens_block, w, denom)
+                # the layers: every lane came back with the one mean
+                layers = jax.tree.map(lambda x: x[0], grads.pop("layers"))
+                g_mean = collectives.fed_mean(collectives.OverAxis(
+                    grads, STATION_AXIS, ring, denom), weights=w)
+                g_mean["layers"] = layers
+                return losses, g_mean, left
+
+            w = collectives._norm_weights(mask.shape[0], None, mask)
+            total = jnp.sum(w)
+            losses, g_mean, left = jax.shard_map(
+                ring_body,
+                mesh=self.mesh,
+                in_specs=(P(), P(STATION_AXIS, None, SEQ_AXIS),
+                          P(STATION_AXIS), P()),
+                out_specs=(P(STATION_AXIS), P(), P(STATION_AXIS)),
+                check_vma=False,
+            )(params, tokens, w, jnp.where(total > 0, total, 1.0))
         with jax.named_scope("server_update"):
             updates, opt_state = self.optimizer.update(
                 g_mean, opt_state, params
