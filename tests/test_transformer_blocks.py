@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from perfbench import cells, compare
+from vantage6_tpu.models import experts as X
 from vantage6_tpu.runtime import profiling
 from vantage6_tpu.runtime.tracing import TRACER
 from vantage6_tpu.workloads import fed_transformer as FT
@@ -134,6 +135,11 @@ def test_the_rounds_follow_the_plain_reference(inputs):
     assert recorded["assignments_by_round"][0] == first.sum()
     span = [s for s in TRACER.drain() if s["name"] == "experts.load"][-1]
     assert span["attrs"] == recorded
+    # the walk over row blocks: 2 stations x 4 layers, one chunk each
+    block, blocks = X.row_walk(inputs["tokens"][0][0].size, 2)
+    assert span["attrs"]["row_block"] == block
+    assert span["attrs"]["row_blocks"] == 2 * 4 * blocks
+    assert 0 < span["attrs"]["row_blocks_walked"] <= 2 * 4 * blocks
     assert engine.record_expert_load() is None  # read, and emptied
 
 
@@ -146,6 +152,31 @@ def test_one_round_holds_the_reference_counts_per_layer_and_expert(inputs):
     want = REFERENCE.expert_load(CONFIG, inputs["params"],
                                  inputs["tokens"][1])
     assert np.array_equal(recorded["assignments_per_round"], want)
+
+
+def test_the_load_record_counts_the_row_blocks_walked(inputs, monkeypatch):
+    """`experts.load` says what the expert layers walked: the blocks of
+    ``row_block`` rows that carried an assignment, station by station and
+    layer by layer, beside what the worst case would walk."""
+    monkeypatch.setattr(X, "ROW_TILE", 8)
+    TRACER.configure(enabled=True, sample=1.0)
+    TRACER.clear()
+    engine = FT.make_engine(2, 1, _block_config(),
+                            devices=jax.devices()[:1])
+    tokens = inputs["tokens"][1]
+    engine.round(*_fresh_state(engine, inputs),
+                 engine.shard_tokens(tokens), inputs["mask"])
+    engine.record_expert_load()
+    attrs = [s for s in TRACER.drain() if s["name"] == "experts.load"][-1][
+        "attrs"]
+    rows = tokens[0].size * 2  # a station's tokens, two choices each
+    assert attrs["row_block"] == 8
+    assert attrs["row_blocks"] == 2 * 4 * (rows // 8)
+    per_station = [REFERENCE.expert_load(
+        CONFIG, inputs["params"], tokens[s:s + 1]).sum(1) for s in (0, 1)]
+    assert attrs["row_blocks_walked"] == sum(
+        -(-int(live) // 8) for layers in per_station for live in layers)
+    assert attrs["row_blocks_walked"] < attrs["row_blocks"]
 
 
 def _logits(cfg, params, tokens):
@@ -331,9 +362,11 @@ PARENT = {
 # the one that configuration ran there, and the text differs where the
 # backward adds `dK` / `dV` into their slice: four scatters (what jax's rule
 # made of the update under the stations' `vmap`) are four
-# `dynamic_update_slice`s
+# `dynamic_update_slice`s (579dc598... from PR 34 on). Since PR 37 the expert
+# layer walks only the row blocks that carry an assignment, one station
+# after another, and writes its own backward pass: the text is another
 PINNED_SMALLTHINKER = (
-    "579dc59831a068ed111a9a168af8704859c3795ddb37b6a0f4b9dda01145f03e")
+    "87916e5665453fd7afe50e2386b85b4abd33c5635382a5881b881dd8ad9784e1")
 
 
 def _parent_init_params(key, cfg):

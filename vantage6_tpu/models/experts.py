@@ -11,18 +11,35 @@ is left out; nothing stands in for the other chips or their traffic. The
 shares of all chips add up to the uncut layer
 (tests/test_expert_layer.py).
 
-No capacity and NO DROPPED TOKEN, under any skew: the assignments are
-sorted by expert and every one of them is a row of a grouped matrix product
-whose row buffer holds the worst case (every choice of every token held
-here). The product itself is jax's Pallas TPU grouped matmul
+No capacity and NO DROPPED TOKEN, under any skew: the assignments of a chunk
+of tokens are sorted by expert and every one of them is a row of a grouped
+matrix product whose row buffer holds the worst case (every choice of every
+token held here). The worst case is the guarantee, not the cost: the
+assignments to held experts are the first ``live`` rows of the sorted order,
+and everything the layer does to rows (the gather of the tokens in, the
+activation, the weighted sum back to the tokens, and each of them again as
+a cotangent) walks the buffer in blocks of `row_walk`'s ``row_block`` rows
+and stops behind the last block that carries an assignment: a loop whose
+trip count is data. The product itself is jax's Pallas TPU grouped matmul
 (``jax.experimental.pallas.ops.tpu.megablox``: ``gmm`` forward, ``gmm`` and
 ``tgmm`` backward), whose grid is sized by the row tiles that carry an
-assignment, so the empty tail of the buffer costs no product: measured
-against ``jax.lax.ragged_dot``, which the TPU compiles natively but NOT
-under ``vmap`` (``FedTransformer._round`` walks the stations packed on a
-chip with one: "number of batch dimensions should be 0"), while a
-``pallas_call`` with a dynamic grid is batched as a loop over the stations.
-Off the TPU the kernels run interpreted (``interpret=True``).
+assignment: measured against ``jax.lax.ragged_dot``, which the TPU compiles
+natively but NOT under ``vmap`` (``FedTransformer._round`` walks the stations
+packed on a chip with one: "number of batch dimensions should be 0"), while
+a ``pallas_call`` with a dynamic grid is batched as a loop over the
+stations. So the empty tail of the buffer costs no product, no gather and no
+pass; what it still costs is its allocation, one fill with zeros a buffer
+and chunk (nothing reads the tail, but XLA hands out no memory unwritten),
+the sort of all ``n * top_k`` assignments, and the weights' gradients, which
+each chunk adds to in full whatever its load. Off the TPU the kernels run
+interpreted (``interpret=True``).
+
+The stations' ``vmap`` would undo the walk (jax batches a loop with a
+per-station bound by running it to the largest and passing a ``select`` over
+every carry each trip), so the layer is a ``custom_vjp`` whose forward and
+backward are each batched as a loop over the stations (`_one_at_a_time`);
+the backward is written out, and where the forward went chunk by chunk it
+computes each chunk's products again.
 
 Assumed, where SmallThinker's config does not say (stated in
 perfbench/configs/smallthinker-21b-ep8-2st.json too): the router's product,
@@ -32,6 +49,7 @@ rounding of the product rarely flips a choice; the expert is ReGLU,
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any
 
@@ -74,79 +92,237 @@ def _tile(dim: int) -> int:
                default=1024)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def grouped_matmul(lhs, rhs, group_sizes, out_dtype, interpret):
-    """``lhs[rows of group e] @ rhs[e]`` for every group: ``lhs`` [M, K]
-    sorted by group, ``rhs`` [E, K, N], ``group_sizes`` [E] int32 with
-    ``sum <= M``. Rows past the groups are NOT WRITTEN (they hold whatever
-    the buffer held): the caller masks them."""
+def _product(lhs, rhs, group_sizes, out_dtype, interpret, transposed=False):
+    """``lhs[rows of group e] @ rhs[e]`` for every group (``rhs[e].T`` where
+    ``transposed``): ``lhs`` [M, K] sorted by group, ``rhs`` [E, K, N],
+    ``group_sizes`` [E] int32 with ``sum <= M``. Rows past the groups are
+    neither read nor WRITTEN (they hold whatever the buffer held): the
+    caller masks them."""
     m, k = lhs.shape
-    n = rhs.shape[2]
+    n = rhs.shape[1 if transposed else 2]
     return _gmm(
         lhs, rhs, group_sizes, out_dtype,
-        (min(ROW_TILE, m), _tile(k), _tile(n)), interpret=interpret)
-
-
-def _grouped_matmul_fwd(lhs, rhs, group_sizes, out_dtype, interpret):
-    out = grouped_matmul(lhs, rhs, group_sizes, out_dtype, interpret)
-    return out, (lhs, rhs, group_sizes)
-
-
-def _grouped_matmul_bwd(out_dtype, interpret, res, grad):
-    lhs, rhs, group_sizes = res
-    m, k = lhs.shape
-    n = rhs.shape[2]
-    tm = min(ROW_TILE, m)
-    grad = grad.astype(lhs.dtype)
-    d_lhs = _gmm(
-        grad, rhs, group_sizes, lhs.dtype, (tm, _tile(n), _tile(k)),
-        transpose_rhs=True, interpret=interpret)
-    d_rhs = _tgmm(
-        lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype,
-        (tm, _tile(k), _tile(n)), num_actual_groups=rhs.shape[0],
+        (min(ROW_TILE, m), _tile(k), _tile(n)), transpose_rhs=transposed,
         interpret=interpret)
-    return d_lhs, d_rhs, None
 
 
-grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+def _product_to_rhs(lhs, grad, group_sizes, acc, interpret):
+    """``acc[e] + lhs[rows of group e].T @ grad[rows of group e]``: a
+    product's cotangent to its weights, added to the sum ``acc`` [E, K, N]
+    float32 inside the kernel."""
+    k, n = acc.shape[1:]
+    # a float32 tile of the sum, of the result and of the kernel's own
+    # accumulator share the chip's fast memory: half an n tile
+    tn = _tile(n) // 2 if _tile(n) % 256 == 0 else _tile(n)
+    return _tgmm(
+        lhs.swapaxes(0, 1), grad, group_sizes, acc.dtype,
+        (min(ROW_TILE, lhs.shape[0]), _tile(k), tn),
+        num_actual_groups=acc.shape[0], existing_out=acc, interpret=interpret)
 
 
-# ----------------------------------------- rows in and out of sorted order
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rows_sorted(h, order, inverse, top_k):
-    """Row ``r`` of the result is the token of assignment ``order[r]``
-    (assignment ``a`` belongs to token ``a // top_k``). ``order`` is a
-    permutation, so the cotangent comes back by a GATHER through its
-    ``inverse`` and a sum over each token's ``top_k`` slots; no scatter
-    (on the chip an unsorted scatter-add of such rows runs 8 times slower
-    than the gather: my chip run, PR 30)."""
-    return h[jnp.minimum(order // top_k, h.shape[0] - 1)]
+# ------------------------------------------- one station at a time, batched
+def _one_at_a_time(fn):
+    """``fn`` whose `vmap` is a loop over the batch (`lax.map`), so that inside
+    it nothing is batched: a count read off one station's routing is a
+    scalar, a loop bounded by it runs that many times and a carry of a
+    buffer's size is updated in place. The Pallas products are a loop over
+    the stations already. `jax.custom_batching.custom_vmap` has no transpose
+    rule, so this lies where reverse mode does not differentiate: inside a
+    `custom_vjp`'s forward and backward (``ops/flash_attention.py::_add_at``
+    is the precedent)."""
+    one = jax.custom_batching.custom_vmap(fn)
+
+    @one.def_vmap
+    def _(axis_size, in_batched, *args):
+        leaves, tree = jax.tree.flatten(args)
+        batched = jax.tree.leaves(in_batched)
+
+        def station(moving):
+            moving = iter(moving)
+            return one(*jax.tree.unflatten(tree, [
+                next(moving) if b else x for x, b in zip(leaves, batched)]))
+
+        out = lax.map(station, [x for x, b in zip(leaves, batched) if b])
+        return out, jax.tree.map(lambda _: True, out)
+
+    return one
 
 
-def _rows_sorted_fwd(h, order, inverse, top_k):
-    return _rows_sorted(h, order, inverse, top_k), (inverse, h.shape[0])
+# ------------------------------------------------ the walk over row blocks
+def row_walk(n_tokens: int, top_k: int,
+             token_chunk: int = TOKEN_CHUNK) -> tuple[int, int]:
+    """``(row_block, row_blocks)`` for a call of `expert_layer` on
+    ``n_tokens`` tokens: the rows of one block of the walk (a row tile of
+    the products; all of a buffer smaller than one), and the blocks the call
+    would walk if every choice of every token named a held expert. From the
+    shapes alone."""
+    chunks = 1
+    if n_tokens > token_chunk and n_tokens % token_chunk == 0:
+        chunks, n_tokens = n_tokens // token_chunk, token_chunk
+    m = n_tokens * top_k
+    block = min(ROW_TILE, -(-m // 8) * 8)
+    return block, chunks * -(-m // block)
 
 
-def _rows_sorted_bwd(top_k, res, g):
-    inverse, n = res
-    by_slot = g[inverse[: n * top_k]]
-    return jnp.sum(by_slot.reshape(n, top_k, -1), axis=1), None, None
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("order", "sizes"), meta_fields=("n", "top_k"))
+@dataclasses.dataclass(frozen=True)
+class _Walk:
+    """A chunk's assignments in the order of their expert, and the walk over
+    them in blocks: ``order`` [m_rows] (the assignments ``token * top_k +
+    slot`` to held experts first, by expert; behind them those to no expert
+    here and the padding to whole blocks), ``sizes`` [E_held] the rows of
+    each held expert."""
+    order: jax.Array
+    sizes: jax.Array
+    n: int
+    top_k: int
+
+    @classmethod
+    def sort(cls, local: jax.Array, n_held: int) -> "_Walk":
+        """``local`` [n, top_k]: each choice's place among the experts held
+        here, ``n_held`` for one held elsewhere."""
+        n, top_k = local.shape
+        block, blocks = row_walk(n, top_k, n)
+        flat = jnp.pad(local.reshape(-1), (0, block * blocks - n * top_k),
+                       constant_values=n_held)
+        return cls(jnp.argsort(flat, stable=True).astype(jnp.int32),
+                   jnp.sum(flat[:, None] == jnp.arange(n_held), axis=0,
+                           dtype=jnp.int32), n, top_k)
+
+    @property
+    def block(self) -> int:
+        return row_walk(self.n, self.top_k, self.n)[0]
+
+    @property
+    def live(self) -> jax.Array:
+        return jnp.sum(self.sizes)
+
+    @property
+    def walked(self) -> jax.Array:
+        """The blocks that carry an assignment."""
+        return (self.live + self.block - 1) // self.block
+
+    def each(self, body, init):
+        """``body(b, carry)`` over the blocks that carry an assignment. The
+        rows of every other block of a buffer in ``init`` stay as they are
+        and are read by nothing."""
+        return lax.fori_loop(0, self.walked, body, init)
+
+    def at(self, b):
+        """Block ``b``: its first row, its rows' tokens, their slots in the
+        chunk's [n * top_k] weights, and which of its rows carry an
+        assignment (all but the last block's last)."""
+        lo = b * self.block
+        a = lax.dynamic_slice_in_dim(self.order, lo, self.block)
+        carries = (lo + jnp.arange(self.block) < self.live)[:, None]
+        return (lo, jnp.minimum(a // self.top_k, self.n - 1),
+                jnp.minimum(a, self.n * self.top_k - 1), carries)
+
+    def rows(self, x, lo):
+        return lax.dynamic_slice_in_dim(x, lo, self.block)
+
+    def put(self, x, rows, lo):
+        return lax.dynamic_update_slice_in_dim(x, rows.astype(x.dtype), lo, 0)
 
 
-_rows_sorted.defvjp(_rows_sorted_fwd, _rows_sorted_bwd)
+def _products(h, walk, w, interpret):
+    """A chunk's forward up to the experts' results in sorted order:
+    ``rows`` [m_rows, d] (the tokens of the assignments), ``gate`` and ``up``
+    [m_rows, f] float32, ``mid`` and ``out`` in ``h``'s dtype. Only the
+    blocks that carry an assignment hold anything."""
+    m_rows = walk.order.shape[0]
+
+    def rows_in(b, rows):
+        lo, tokens, _, _ = walk.at(b)
+        return walk.put(rows, h[tokens], lo)
+
+    rows = walk.each(rows_in, jnp.zeros((m_rows, h.shape[1]), h.dtype))
+    gate = _product(rows, w["w_gate"], walk.sizes, jnp.float32, interpret)
+    up = _product(rows, w["w_up"], walk.sizes, jnp.float32, interpret)
+
+    def act(b, mid):
+        lo, _, _, carries = walk.at(b)
+        return walk.put(mid, jnp.where(
+            carries, jax.nn.relu(walk.rows(gate, lo)) * walk.rows(up, lo), 0),
+            lo)
+
+    mid = walk.each(act, jnp.zeros((m_rows, gate.shape[1]), h.dtype))
+    out = _product(mid, w["w_down"], walk.sizes, h.dtype, interpret)
+    return rows, gate, up, mid, out
 
 
-@jax.custom_vjp
-def _rows_by_slot(y, inverse, order):
-    """``y[inverse]``: sorted rows back in assignment order; the cotangent
-    comes back through ``order``, the inverse of ``inverse``."""
-    return y[inverse]
+def _chunk_forward(h, walk, weights, w, interpret):
+    """``y`` [n, d] and the chunk's `_products`."""
+    kept = _products(h, walk, w, interpret)
+    out = kept[-1]
+    slots = weights.reshape(-1)  # float32: a weight is not rounded
+
+    def rows_back(b, y):
+        lo, tokens, slot, carries = walk.at(b)
+        return y.at[tokens].add(jnp.where(
+            carries, slots[slot][:, None] * walk.rows(out, lo), 0))
+
+    y = walk.each(rows_back, jnp.zeros(h.shape, jnp.float32))
+    return y.astype(h.dtype), kept
 
 
-_rows_by_slot.defvjp(
-    lambda y, inverse, order: (y[inverse], order),
-    lambda order, g: (g[order], None, None),
-)
+def _chunk_backward(h, walk, weights, w, kept, d_w, dy, interpret):
+    """The chunk's cotangents from ``dy`` [n, d]: ``dh``, ``d_weights``, and
+    the three matrices' added to ``d_w`` (float32). ``kept``: the chunk's
+    `_products`, or None and they are computed again."""
+    rows, gate, up, mid, out = kept or _products(h, walk, w, interpret)
+    slots = weights.reshape(-1)
+
+    def to_lhs(grad, w):  # a product's cotangent to its rows
+        return _product(grad, w, walk.sizes, h.dtype, interpret,
+                        transposed=True)
+
+    def rows_back_t(b, carry):
+        d_out, d_slots = carry
+        lo, tokens, slot, carries = walk.at(b)
+        dy_rows = dy[tokens].astype(jnp.float32)
+        d_slot = jnp.sum(dy_rows * walk.rows(out, lo), axis=-1)
+        return (
+            walk.put(d_out, jnp.where(
+                carries, slots[slot][:, None] * dy_rows, 0), lo),
+            d_slots.at[jnp.where(carries[:, 0], slot, slots.shape[0])].set(
+                d_slot, mode="drop"))
+
+    d_out, d_slots = walk.each(
+        rows_back_t, (jnp.zeros_like(out), jnp.zeros_like(slots)))
+    d_mid = to_lhs(d_out, w["w_down"])
+
+    def act_t(b, carry):
+        d_gate, d_up = carry
+        lo, _, _, carries = walk.at(b)
+        g = walk.rows(gate, lo)
+        d = walk.rows(d_mid, lo).astype(jnp.float32)
+        return (
+            walk.put(d_gate, jnp.where(
+                carries & (g > 0), d * walk.rows(up, lo), 0), lo),
+            walk.put(d_up, jnp.where(carries, d * jax.nn.relu(g), 0), lo))
+
+    d_gate, d_up = walk.each(
+        act_t, (jnp.zeros_like(mid), jnp.zeros_like(mid)))
+    d_rows_gate = to_lhs(d_gate, w["w_gate"])
+    d_rows_up = to_lhs(d_up, w["w_up"])
+
+    def rows_in_t(b, dh):
+        lo, tokens, _, carries = walk.at(b)
+        return dh.at[tokens].add(jnp.where(
+            carries, walk.rows(d_rows_gate, lo).astype(jnp.float32)
+            + walk.rows(d_rows_up, lo), 0))
+
+    dh = walk.each(rows_in_t, jnp.zeros(h.shape, jnp.float32))
+    d_w = {
+        "w_gate": _product_to_rhs(
+            rows, d_gate, walk.sizes, d_w["w_gate"], interpret),
+        "w_up": _product_to_rhs(
+            rows, d_up, walk.sizes, d_w["w_up"], interpret),
+        "w_down": _product_to_rhs(
+            mid, d_out, walk.sizes, d_w["w_down"], interpret)}
+    return dh.astype(h.dtype), d_slots.reshape(weights.shape), d_w
 
 
 def expert_layer(
@@ -162,9 +338,11 @@ def expert_layer(
     """This chip's part of the expert layer: for every token the weighted
     sum over its chosen experts that are ``held``. Returns ``y`` [N, d] and
     the load: ``assignments`` [E_held] int32, the rows each held expert's
-    product ran over, and ``routed_here`` (), the choices that named a held
-    expert, counted apart from the sort; they agree unless a token was
-    dropped.
+    product ran over, ``routed_here`` (), the choices that named a held
+    expert, counted apart from the sort (they agree unless a token was
+    dropped), and ``row_blocks_walked`` (), the blocks of `row_walk`'s
+    ``row_block`` rows that carried an assignment and were walked (of its
+    ``row_blocks``).
 
     More than ``token_chunk`` tokens (a whole number of chunks) go through
     one chunk after another, each RECOMPUTED IN THE BACKWARD PASS, so that
@@ -172,53 +350,88 @@ def expert_layer(
     one chunk's (``token_chunk * top_k`` rows) whatever the batch: the
     layer brings its own recomputation, and a caller that recomputes its
     layers leaves this one out of that."""
-    n = h.shape[0]
-    one = functools.partial(_expert_chunk, params=params, held=held,
-                            n_experts=n_experts, interpret=interpret)
-    if n <= token_chunk or n % token_chunk:
-        return one(h, choice, weights)
-    chunks = n // token_chunk
-    y, load = lax.map(
-        lambda c: jax.checkpoint(one)(*c),
-        tuple(x.reshape(chunks, token_chunk, *x.shape[1:])
-              for x in (h, choice, weights)))
-    return y.reshape(n, -1), jax.tree.map(lambda x: jnp.sum(x, 0), load)
+    part = _held_part(tuple(held), n_experts, interpret, token_chunk)
+    return part(h, choice, weights,
+                {name: params[name] for name in ("w_gate", "w_up", "w_down")})
 
 
-def _expert_chunk(h, choice, weights, *, params, held, n_experts, interpret):
-    n, top_k = choice.shape
+@functools.lru_cache(maxsize=None)
+def _held_part(held, n_experts, interpret, token_chunk):
+    """`expert_layer` for one static configuration: a `custom_vjp` whose
+    forward and backward each walk one station at a time (`_one_at_a_time`),
+    one chunk after another, and within a chunk the row blocks that carry
+    an assignment (`_Walk`)."""
     n_held = len(held)
     to_local = np.full((n_experts,), n_held, np.int32)  # n_held: not here
     to_local[list(held)] = np.arange(n_held, dtype=np.int32)
-    local = jnp.asarray(to_local)[choice]  # [N, top_k]
-    here = local < n_held
 
-    # every assignment is a row; the row buffer holds them all, padded to
-    # whole tiles with assignments to no expert
-    m = n * top_k
-    tile = min(ROW_TILE, -(-m // 8) * 8)
-    m_rows = -(-m // tile) * tile
-    flat = jnp.pad(local.reshape(m), (0, m_rows - m), constant_values=n_held)
-    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-    inverse = jnp.argsort(order).astype(jnp.int32)
-    sizes = jnp.bincount(flat, length=n_held + 1)[:n_held].astype(jnp.int32)
-    live = (jnp.arange(m_rows) < jnp.sum(sizes))[:, None]
+    def chunks(x):
+        """[1, N, ...], or [N / token_chunk, token_chunk, ...] where the
+        chunks are walked one after another (and recomputed)."""
+        n = x.shape[0]
+        several = n > token_chunk and n % token_chunk == 0
+        return x.reshape(-1, token_chunk if several else n, *x.shape[1:])
 
-    def product(rows, w, out_dtype):
-        return grouped_matmul(rows, w.astype(h.dtype), sizes, out_dtype,
-                              interpret)
+    def cast(params, dtype):
+        return {name: w.astype(dtype) for name, w in params.items()}
 
-    rows = jnp.where(live, _rows_sorted(h, order, inverse, top_k), 0)
-    gate = product(rows, params["w_gate"], jnp.float32)
-    up = product(rows, params["w_up"], jnp.float32)
-    mid = jnp.where(live, jax.nn.relu(gate) * up, 0).astype(h.dtype)
-    out = jnp.where(live, product(mid, params["w_down"], h.dtype), 0)
-    by_slot = _rows_by_slot(out, inverse, order)[:m].reshape(n, top_k, -1)
-    w_here = jnp.where(here, weights, 0.0)  # float32: a weight is not rounded
-    y = jnp.sum(w_here[..., None] * by_slot, axis=1).astype(h.dtype)
-    load = {"assignments": sizes,
-            "routed_here": jnp.sum(here).astype(jnp.int32)}
-    return y, load
+    @_one_at_a_time
+    def forward(h, choice, weights, params):
+        """``y``, the load, and for the backward pass each chunk's sorted
+        order and, of one chunk alone, its products."""
+        w = cast(params, h.dtype)
+        local = jnp.asarray(to_local)[choice]
+
+        def chunk(c):
+            h, local, weights = c
+            walk = _Walk.sort(local, n_held)
+            y, kept = _chunk_forward(h, walk, weights, w, interpret)
+            return y, walk, kept
+
+        if chunks(h).shape[0] == 1:
+            y, walk, kept = chunk((h, local, weights))
+            walks = jax.tree.map(lambda x: x[None], walk)
+        else:  # a chunk's products are not kept
+            y, walks = lax.map(lambda c: chunk(c)[:2], (
+                chunks(h), chunks(local), chunks(weights)))
+            kept = None
+        load = {"assignments": jnp.sum(walks.sizes, axis=0),
+                "routed_here": jnp.sum(local < n_held).astype(jnp.int32),
+                "row_blocks_walked": jnp.sum(
+                    jax.vmap(lambda walk: walk.walked)(walks)
+                ).astype(jnp.int32)}
+        return y.reshape(h.shape), load, walks, kept
+
+    @_one_at_a_time
+    def backward(h, weights, params, walks, kept, dy):
+        w = cast(params, h.dtype)
+
+        def chunk(d_w, c):
+            h, weights, walk, dy = c
+            dh, d_weights, d_w = _chunk_backward(
+                h, walk, weights, w, kept, d_w, dy, interpret)
+            return d_w, (dh, d_weights)
+
+        d_w, (dh, d_weights) = lax.scan(
+            chunk, jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), w),
+            (chunks(h), chunks(weights), walks, chunks(dy)))
+        return (dh.reshape(h.shape), d_weights.reshape(weights.shape),
+                jax.tree.map(lambda g, p: g.astype(p.dtype), d_w, params))
+
+    @jax.custom_vjp
+    def part(h, choice, weights, params):
+        return forward(h, choice, weights, params)[:2]
+
+    def part_fwd(h, choice, weights, params):
+        y, load, walks, kept = forward(h, choice, weights, params)
+        return (y, load), (h, weights, params, walks, kept)
+
+    def part_bwd(res, cotangents):
+        dh, d_weights, d_params = backward(*res, cotangents[0])
+        return dh, None, d_weights, d_params
+
+    part.defvjp(part_fwd, part_bwd)
+    return part
 
 
 def load_summary(assignments: Any, routed_here: Any) -> dict[str, Any]:

@@ -585,6 +585,8 @@ class FedTransformer:
     _walks: dict = dataclasses.field(default_factory=dict, repr=False)
     # and of the cross-station mean (`aggregation`)
     _aggregation: dict | None = dataclasses.field(default=None, repr=False)
+    # the shape of the tokens whose load `_expert_load` holds (`row_walk`)
+    _load_shape: tuple | None = dataclasses.field(default=None, repr=False)
 
     def init(self, key: jax.Array) -> tuple[Any, Any]:
         # params AND the whole optimizer state are committed to the mesh:
@@ -644,6 +646,7 @@ class FedTransformer:
                     params, opt_state, tokens, mask)
         if load is not None:  # stays on the device: record_expert_load
             self._expert_load.append(load)
+            self._load_shape = tokens.shape
         if exits is not None:  # likewise: record_exit_distribution
             self._exits.append(exits)
         return tuple(out)
@@ -711,6 +714,19 @@ class FedTransformer:
                     for group in crossing)}
         return self._aggregation
 
+    def row_walk(self, shape: tuple[int, ...]) -> dict[str, int]:
+        """What the expert layers of a round over tokens of ``shape``
+        [S, B, T] walk at most: ``row_block``, the rows of one block of
+        `experts.expert_layer`'s walk over a chunk's sorted assignments, and
+        ``row_blocks``, the blocks of a round if every choice of every token
+        named an expert held here (all stations, all layers). Host integers
+        from the shapes."""
+        stations, b, t = shape
+        seq = self.mesh.shape[SEQ_AXIS]
+        block, blocks = experts.row_walk(b * t // seq, self.cfg.top_k)
+        return {"row_block": block,
+                "row_blocks": stations * seq * self.cfg.n_layers * blocks}
+
     def record_expert_load(self) -> dict[str, Any] | None:
         """Read the expert layers' counts of the rounds since the last call
         off the device and record them as ONE ``experts.load`` span:
@@ -719,10 +735,14 @@ class FedTransformer:
         over layers and experts ``assignments_by_round``, and over all layers
         ``max_over_mean`` (the fullest held expert over the mean one) and
         ``dropped`` (choices that named a held expert less rows its product
-        ran over: 0, there is no capacity). A round leaves its counts on the
-        device and this call fetches them, so call it OUTSIDE what is
-        timed. Returns the attributes, or None where there is nothing to
-        record (no expert layer, no round since the last call)."""
+        ran over: 0, there is no capacity), and of the walk over row blocks
+        ``row_block``, ``row_blocks`` (`row_walk`: what a round would walk in
+        the worst case) and ``row_blocks_walked`` (what the rounds walked,
+        the blocks that carried an assignment, mean over the rounds). A
+        round leaves its counts on the device and this call fetches them, so
+        call it OUTSIDE what is timed. Returns the attributes, or None where
+        there is nothing to record (no expert layer, no round since the last
+        call)."""
         pending = list(self._expert_load)
         self._expert_load.clear()
         if not pending:
@@ -735,6 +755,9 @@ class FedTransformer:
             "assignments_per_round": (a.sum(0) / len(counts)).tolist(),
             "assignments_by_round": a.sum((1, 2)).tolist(),
             **experts.load_summary(a, routed),
+            **self.row_walk(self._load_shape),
+            "row_blocks_walked": float(np.sum(
+                [c["row_blocks_walked"] for c in counts]) / len(counts)),
         }
         with TRACER.span("experts.load", kind="engine", attrs=attrs):
             pass
