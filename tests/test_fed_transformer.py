@@ -387,7 +387,8 @@ def test_a_round_on_several_slots_is_fed_mean_of_the_stacked_gradients(
 
         monkeypatch.setattr(FT, "_loss_and_load", poisoned)
     cfg = FT.TransformerConfig(vocab=97, d_model=32, n_heads=4, n_layers=3,
-                               max_len=16, attention="recompute")
+                               max_len=16, attention="recompute",
+                               flash_interpret=True)
     engine = FT.make_engine(n_stations, 1, cfg,
                             devices=jax.devices()[:n_devices])
     tokens = np.minimum(

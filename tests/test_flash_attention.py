@@ -1,4 +1,5 @@
 """Pallas flash attention vs jnp oracle (interpret mode on CPU)."""
+import functools
 import importlib
 
 import jax
@@ -191,7 +192,26 @@ class TestRecomputeAttention:
         ((6000, 6000), (256, 256)),    # whole lane widths only
     ])
     def test_the_tile_is_a_function_of_the_lengths(self, lengths, tile):
-        assert FA.attention_tile(*lengths) == tile
+        """Where a kernel would be interpreted the XLA walk runs, at the
+        tiles it always had, whatever the heads are."""
+        for head_dim, group in ((64, 1), (128, 7)):
+            assert FA.attention_tile(
+                *lengths, head_dim, group, jnp.bfloat16, True
+            ) == ("walk", *tile)
+
+    @pytest.mark.parametrize("lengths,head_dim,group,blocks", [
+        ((4096, 4096), 128, 1, ("kernel", 512, 512)),  # Ouro
+        ((8192, 8192), 128, 7, ("kernel", 512, 512)),  # SmallThinker
+        ((100, 300), 128, 1, ("kernel", 128, 384)),    # whole lane tiles
+        # heads of 64 are no whole lane tile (and lost on four chips)
+        ((1024, 1024), 64, 1, ("walk", 256, 256)),     # GPT-2 medium
+        # a head's step no longer fits the chip's VMEM
+        ((65536, 65536), 128, 1, ("walk", 512, 512)),
+    ])
+    def test_compiled_the_rule_names_the_kernels_where_a_head_fits(
+            self, lengths, head_dim, group, blocks):
+        assert FA.attention_tile(
+            *lengths, head_dim, group, jnp.bfloat16, False) == blocks
 
     @pytest.mark.parametrize("causal", [False, True])
     @pytest.mark.parametrize("t", [64, 96])  # 96 exercises key padding
@@ -235,8 +255,9 @@ class TestRecomputeAttention:
         from vantage6_tpu.workloads import fed_transformer as FT
 
         t = 1024
-        block_q, block_k = FA.attention_tile(t, t)
-        assert block_q == block_k and t % block_q == 0
+        path, block_q, block_k = FA.attention_tile(
+            t, t, 64, 1, jnp.float32, True)
+        assert path == "walk" and block_q == block_k and t % block_q == 0
         n = t // block_q
         walked = 0
         for i in range(n):
@@ -253,8 +274,10 @@ class TestRecomputeAttention:
             n * n, n * n)
         engine = FT.make_engine(4, 1, FT.TransformerConfig(
             vocab=50257, d_model=1024, n_heads=16, n_layers=24,
-            max_len=1024, attention="recompute"), devices=jax.devices()[:1])
+            max_len=1024, attention="recompute", flash_interpret=True),
+            devices=jax.devices()[:1])
         assert engine.attention_walk(t) == {
+            "attention_path": "walk",
             "attention_tile": f"{block_q}x{block_k}",
             "attention_tiles_visited": 24 * walked,
             "attention_tiles": 24 * n * n}
@@ -320,7 +343,7 @@ class TestRecomputeAttention:
 
         cfg = FT.TransformerConfig(
             vocab=32, d_model=16, n_heads=2, n_layers=1, max_len=64,
-            attention="recompute",
+            attention="recompute", flash_interpret=True,
         )
         eng = FT.make_engine(n_stations=2, seq_devices=1, cfg=cfg, lr=3e-3)
         tokens = FT.make_federated_tokens(2, batch=2, seq_len=16, vocab=32)
@@ -329,6 +352,103 @@ class TestRecomputeAttention:
             jnp.ones(2),
         )
         assert np.isfinite(float(loss))
+
+
+class TestAttentionKernels:
+    """The walk inside the Pallas kernels (`_kernel_vjp`), interpreted here,
+    against the XLA walk at the same blocks (`_tiled_vjp`): the output and
+    all three gradients."""
+
+    MASKS = {"causal": (True, None), "window": (True, 12),
+             "full": (False, None)}
+    WRAPS = {
+        "alone": lambda f: f,
+        "vmap": jax.vmap,  # the packed stations
+        "checkpoint": jax.checkpoint,  # the block's remat
+    }
+
+    @staticmethod
+    @functools.lru_cache(maxsize=None)
+    def _value_and_grads(path, mask, wrap):
+        """One program a (path, mask, wrap): the offsets are traced."""
+        causal, window = TestAttentionKernels.MASKS[mask]
+        config = (causal, 8 ** -0.5, window, 16, 16)
+        fn = (FA._kernel_vjp(*config, True) if path == "kernel"
+              else FA._tiled_vjp(*config))
+        wrapped = TestAttentionKernels.WRAPS[wrap]
+
+        def loss(q, k, v, w, offset):
+            return jnp.sum(w * fn(q, k, v, offset, offset))
+
+        if wrap == "checkpoint":
+            return jax.jit(jax.value_and_grad(wrapped(loss), (0, 1, 2)))
+        in_axes = (0, 0, 0, 0, None) if wrap == "vmap" else ()
+        return jax.jit(wrapped(jax.value_and_grad(loss, (0, 1, 2)),
+                               *((in_axes,) if in_axes else ())))
+
+    @pytest.mark.parametrize("wrap", WRAPS)
+    @pytest.mark.parametrize("t", [32, 40])  # 40 pads to three blocks of 16
+    @pytest.mark.parametrize("offset", [0, 48])  # 48: a later shard's
+    @pytest.mark.parametrize("group", [1, 4])  # query heads a kv head
+    @pytest.mark.parametrize("mask", MASKS)
+    def test_the_kernels_are_the_walk(self, mask, group, offset, t, wrap):
+        stations = (2,) if wrap == "vmap" else ()
+        q, w = (rand(stations + (1, 4, t, 8), s) for s in (50, 51))
+        k, v = (rand(stations + (1, 4 // group, t, 8), s) for s in (52, 53))
+        got, want = (
+            self._value_and_grads(path, mask, wrap)(
+                q, k, v, w, jnp.int32(offset))
+            for path in ("kernel", "walk"))
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(a, b, atol=2e-6, rtol=2e-6)
+
+    @pytest.mark.parametrize("mask,group,wrap", [
+        ("causal", 1, "vmap"), ("window", 4, "alone"),
+        ("full", 4, "checkpoint")])
+    def test_a_head_of_whole_lane_tiles_is_read_where_it_lies(
+            self, mask, group, wrap):
+        """Heads of 128, the width the kernels are compiled at: a head is a
+        column slab of [B, T, H * D] (`_walk_call`), the transposes there
+        and back cancelling against the caller's; same numbers."""
+        stations = (2,) if wrap == "vmap" else ()
+        q, w = (rand(stations + (1, 4, 40, 128), s) for s in (57, 58))
+        k, v = (rand(stations + (1, 4 // group, 40, 128), s)
+                for s in (59, 60))
+        got, want = (
+            self._value_and_grads(path, mask, wrap)(
+                q, k, v, w, jnp.int32(48))
+            for path in ("kernel", "walk"))
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
+
+    def test_no_score_tensor_in_the_gradients_program(self):
+        """Nothing the size of [Tq, Tk] in the jaxpr of the kernel path's
+        gradient, the kernels' own bodies included: the largest value a
+        tile's scores."""
+        t, block = 64, 16
+        q, k, v = (rand((1, 2, t, 8), s) for s in (54, 55, 56))
+        fn = FA._kernel_vjp(True, 8 ** -0.5, None, block, block, True)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(fn(*a, jnp.int32(0), jnp.int32(0))),
+            argnums=(0, 1, 2)))(q, k, v)
+
+        def shapes(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield from (tuple(x.aval.shape) for x in eqn.outvars)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from shapes(sub)
+
+        seen = set(shapes(jaxpr.jaxpr))
+        assert (block, block) in seen  # a tile's scores are there
+        assert not any(shape.count(t) >= 2 for shape in seen)
+        assert max(int(np.prod(shape)) for shape in seen) < t * t
+
+    def test_compiled_blocks_are_whole_lane_tiles(self):
+        assert FA._kernel_blocks(1024, 1000, 512, 512, False) == (512, 512)
+        assert FA._kernel_blocks(100, 300, 512, 512, False) == (128, 384)
+        assert FA._kernel_blocks(40, 40, 16, 16, True) == (16, 16)
+        with pytest.raises(ValueError, match="lane"):
+            FA._kernel_blocks(1024, 1024, 96, 512, False)
 
 
 class TestInterpreterTwin:

@@ -164,3 +164,53 @@ def test_on_one_slot_nothing_crosses(topo, uncached):
     assert engine.aggregation(params) == {
         "aggregate_overlap": "none", "aggregate_groups": 0,
         "aggregate_bytes": 0}
+
+
+def test_heads_of_64_keep_the_xla_walk_on_the_chip(four_chips):
+    """Compiled for the chip, GPT-2 medium's heads are no whole lane tile:
+    the rule keeps the XLA walk (on four chips the kernels lost 2.5% of the
+    round, PERF.md section 6, PR 38), the span says so and the program holds
+    no attention kernel."""
+    engine, _, ops = four_chips
+    assert engine.attention_walk(1024) == {
+        "attention_path": "walk", "attention_tile": "256x256",
+        "attention_tiles_visited": 10 * N_LAYERS,
+        "attention_tiles": 16 * N_LAYERS}
+    assert not any("attention_walk" in line for line in ops)
+
+
+# the attention layers of the cells whose heads are whole lane tiles:
+# stations, batch, query heads, kv heads, t, head size, window
+# (`perfbench/configs`, `perfbench/traffic`)
+CELLS = {
+    "ouro.looped4k": (2, 1, 16, 16, 4096, 128, None),
+    "smallthinker.packed8k": (2, 1, 28, 4, 8192, 128, 4096),
+}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_attention_kernels_compile_at_a_cells_shapes(
+        cell, topo, uncached):
+    """What the interpreted kernels cannot show: Mosaic takes the blocks the
+    rule names, a head's step fits VMEM, and the stations' `vmap` is a grid
+    axis (one custom call a direction, not one a station)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from vantage6_tpu.ops.flash_attention import recompute_attention
+
+    stations, batch, h_q, h_kv, t, d, window = CELLS[cell]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q, k, v = (jax.ShapeDtypeStruct(
+        (stations, batch, h, t, d), jnp.bfloat16, sharding=one_chip)
+        for h in (h_q, h_kv, h_kv))
+
+    def loss(q, k, v):
+        return jnp.sum(recompute_attention(
+            q, k, v, causal=True, window=window, interpret=False
+        ).astype(jnp.float32))
+
+    text = jax.jit(jax.vmap(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+                   ).lower(q, k, v).compile().as_text()
+    for name in ("attention_walk_fwd", "attention_walk_bwd"):
+        assert sum("custom-call" in line and name in line
+                   for line in text.splitlines()) == 1

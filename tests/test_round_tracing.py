@@ -5,6 +5,7 @@ operations. CPU, tiny sizes."""
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import importlib
 import os
 import re
@@ -220,7 +221,8 @@ ENGINES = {"fed_transformer.round": _call_transformer,
 # (the transformer: a sequence of 16 is one tile; two layers of one head)
 NO_RING = {"aggregate_overlap": "none", "aggregate_groups": 0,
            "aggregate_bytes": 0}
-ENGINE_ATTRS = {"fed_transformer.round": {"attention_tile": "16x16",
+ENGINE_ATTRS = {"fed_transformer.round": {"attention_path": "walk",
+                                          "attention_tile": "16x16",
                                           "attention_tiles_visited": 2,
                                           "attention_tiles": 2, **NO_RING},
                 "fedavg.run_rounds": {"gather": "packed"},
@@ -296,9 +298,40 @@ def test_the_engine_call_says_which_tiles_the_attention_walks(
         lo, hi = FA._key_block_range(i, 4, 4, 4, 16, 0, 0, True, None)
         walked += int(hi) - int(lo)
     assert walked == 10
-    assert said == {"attention_tile": "4x4",
+    assert said == {"attention_path": "walk", "attention_tile": "4x4",
                     "attention_tiles_visited": 2 * walked,
                     "attention_tiles": 2 * 16}
+
+
+def test_the_engine_call_says_that_the_kernels_walk(monkeypatch):
+    """Where the program is compiled for the chip (`flash_interpret` False)
+    and a head is a whole lane tile the walk runs inside the kernels: the
+    span says so, and the blocks they really use (a sequence of 16 is one
+    block of a whole lane tile; at 4,096 it is 512 x 512, 36 of 64 tiles a
+    layer). Heads of 8 stay on the XLA walk. The program itself is not run
+    here: a kernel compiles for a TPU alone."""
+    cfg = FT.TransformerConfig(
+        vocab=97, d_model=32, n_heads=2, head_dim=128, n_layers=2,
+        max_len=16, attention="recompute")
+    engine = FT.make_engine(4, 1, cfg, devices=jax.devices()[:1])
+    params, opt_state = engine.init(jax.random.key(0))
+    tokens = engine.shard_tokens(FT.make_federated_tokens(4, 2, 16, 97))
+    monkeypatch.setattr(
+        FT.FedTransformer, "_round",
+        lambda self, params, opt_state, tokens, mask: (
+            params, opt_state, jnp.float32(0), None, None))
+    engine.round(params, opt_state, tokens, jnp.ones(4))
+    (call,) = _named(TRACER.drain(), "engine.call")
+    assert {k: v for k, v in call["attrs"].items()
+            if k.startswith("attention")} == {
+        "attention_path": "kernel", "attention_tile": "128x128",
+        "attention_tiles_visited": 2, "attention_tiles": 2}
+    assert engine.attention_walk(4096) == {
+        "attention_path": "kernel", "attention_tile": "512x512",
+        "attention_tiles_visited": 2 * 36, "attention_tiles": 2 * 64}
+    narrow, _ = _transformer()
+    narrow.cfg = dataclasses.replace(narrow.cfg, flash_interpret=False)
+    assert narrow.attention_walk(16)["attention_path"] == "walk"
 
 
 def test_a_windowed_layer_visits_fewer_tiles(monkeypatch):
@@ -312,7 +345,7 @@ def test_a_windowed_layer_visits_fewer_tiles(monkeypatch):
         lo, hi = FA._key_block_range(i, 4, 4, 4, 16, 0, 0, True, 8)
         windowed += int(hi) - int(lo)
     assert windowed == 1 + 2 + 3 + 3
-    assert walk == {"attention_tile": "4x4",
+    assert walk == {"attention_path": "walk", "attention_tile": "4x4",
                     "attention_tiles_visited": 10 + windowed,
                     "attention_tiles": 32}
 
@@ -329,7 +362,8 @@ def test_a_looped_stack_says_its_walks_and_counts_tiles_over_applications(
     (call,) = _named(TRACER.drain(), "engine.call")
     assert call["attrs"] == {
         "engine": "fed_transformer.round", "rounds": 1,
-        "attention_tile": "4x4", "attention_tiles_visited": 60,
+        "attention_path": "walk", "attention_tile": "4x4",
+        "attention_tiles_visited": 60,
         "attention_tiles": 96, "loops": 3, "layer_applications": 6,
         **NO_RING}
     TRACER.clear()

@@ -342,7 +342,11 @@ def test_key_blocks_outside_the_window_are_not_visited():
 # The `recompute` rows pinned the untiled walk, which is gone: their hashes
 # are the tiled walk's (the same recipe, at 282bc0e's child), and their loss
 # and gradient norm are held to the `ring` row's, another algorithm for the
-# same mathematics
+# same mathematics. PR 38 put the walk inside two Pallas kernels where the
+# program is compiled for a TPU; these rows (and `PINNED_SMALLTHINKER`) say
+# `flash_interpret=True`, `attention_tile` leaves them on the XLA walk, and
+# their texts stand unchanged
+# (`test_the_pinned_recompute_rows_take_the_xla_walk`)
 PARENT = {
     ("recompute", False): (
         "51d2fa5c50337eb3f53d7eb7240a1ff45a4a1056be2998f69eef33924b10e4ea",
@@ -473,6 +477,21 @@ def test_the_default_block_is_the_parents_bit_for_bit(
     assert _first_grad_norm(new_state) == pytest.approx(
         grad_norm, rel=1e-6 if attention == "recompute" else 1e-5)
     assert engine.record_expert_load() is None  # a block without experts
+
+
+def test_the_pinned_recompute_rows_take_the_xla_walk(inputs):
+    """What the pins of this file hold is the XLA walk: the rule keeps it
+    wherever a kernel would be interpreted, and says so on the span."""
+    engine, _ = _default_block("recompute", False)
+    assert engine.attention_walk(16)["attention_path"] == "walk"
+    engine, _ = _smallthinker_round(inputs)
+    assert engine.attention_walk(16)["attention_path"] == "walk"
+    # compiled, heads of whole lane tiles would leave it: not these
+    cfg = engine.cfg
+    for head_dim, path in ((cfg.head_dim, "walk"), (128, "kernel")):
+        assert FA.attention_tile(
+            16, 16, head_dim, cfg.n_heads // cfg.n_kv_heads, cfg.dtype,
+            False).path == path
 
 
 def _first_grad_norm(opt_state) -> float:

@@ -21,10 +21,18 @@ monolithic causal attention (offsets 0) and each hop of ring attention
 
 Compiled (``interpret=False``) the kernel runs on the TPU and nowhere else;
 the CPU tests run it with ``interpret=True``.
+
+``recompute_attention`` is the training path of every benchmark cell: a walk
+over the visible (query block, key block) tiles, forward and backward, with
+residuals (q, k, v, o, L) and grouped kv heads, a causal window and traced
+offsets. On a TPU the walk runs inside two further kernels ("kernel path"
+below: a whole head a grid step, the tiles a loop inside it); anywhere else
+the same walk runs in XLA ("tiled path"). ``attention_tile`` says which.
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -69,7 +77,7 @@ def _block_sizes(
 
 
 def _softmax_step(q, kblk, vblk, m, l, acc, q_pos0, k_pos0, k_idx0, k_valid,
-                  *, causal: bool, scale: float):
+                  *, causal: bool, scale: float, window: int | None = None):
     """One (q block, k block) online-softmax update — THE op sequence, run
     by the kernel on refs' values and replayed by ``interpreter_twin`` on
     plain arrays. ``m``/``l`` are lane-replicated ``[block_q, LANES]``,
@@ -90,6 +98,8 @@ def _softmax_step(q, kblk, vblk, m, l, acc, q_pos0, k_pos0, k_idx0, k_valid,
     if causal:
         rows = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
         s = jnp.where(q_pos0 + rows >= k_pos0 + cols, s, NEG_INF)
+        if window is not None:  # none of the keys before the window either
+            s = jnp.where(k_pos0 + cols > q_pos0 + rows - window, s, NEG_INF)
     blk_max = jnp.max(s, axis=1, keepdims=True)
     # clamp at a finite floor: for a fully-masked block, exp(s - m_new)
     # must be exp(-huge) = 0, NOT exp(NEG_INF - NEG_INF) = 1
@@ -224,14 +234,16 @@ def recompute_attention(
     k_offset: jax.Array | int = 0,
     causal: bool = False,
     scale: float | None = None,
-    block_k: int | None = None,  # None: `attention_tile` of the lengths
+    block_k: int | None = None,  # None: `attention_tile` of the shapes
     window: int | None = None,
     block_q: int | None = None,
+    interpret: bool | None = None,  # None: whether this is no TPU
 ) -> jax.Array:
-    """Flash-MEMORY attention without a Pallas kernel: an online-softmax
-    forward over (query block, key block) tiles in plain jnp/XLA
-    (`_tiled_forward`) and the softmax-attention VJP over the same tiles
-    (`_tiled_bwd`).
+    """Flash-MEMORY attention: an online-softmax forward over (query block,
+    key block) tiles and the softmax-attention VJP over the same tiles, as
+    two Pallas kernels on a TPU (`_kernel_forward`, `_kernel_bwd`) and in
+    plain jnp/XLA wherever a kernel would be interpreted (`_tiled_forward`,
+    `_tiled_bwd`): `attention_tile` says which, and at which blocks.
 
     Peak transient memory is O(block_q * block_k) a head in BOTH directions
     and the residuals are (q, k, v, o) and the row statistics L ([B, H, Tq]
@@ -246,7 +258,7 @@ def recompute_attention(
     0``: query head ``h`` reads kv head ``h // (H_q // H_kv)``; the
     repeated heads are never materialised, forward or backward). Where the
     caller names no ``block_q`` / ``block_k`` the tile is `attention_tile`
-    of the lengths handed in, whose values were read on the chip."""
+    of the shapes handed in, whose values were read on the chip."""
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / (d**0.5)
@@ -256,9 +268,14 @@ def recompute_attention(
         raise ValueError(
             f"{q.shape[1]} query heads do not divide over "
             f"{k.shape[1]} key/value heads")
-    tile_q, tile_k = attention_tile(q.shape[2], k.shape[2])
-    fn = _tiled_vjp(causal, float(scale), window,
-                    block_q or tile_q, block_k or tile_k)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    rule = attention_tile(q.shape[2], k.shape[2], d,
+                          q.shape[1] // k.shape[1], q.dtype, interpret)
+    config = (causal, float(scale), window,
+              block_q or rule.block_q, block_k or rule.block_k)
+    fn = (_kernel_vjp(*config, interpret) if rule.path == "kernel"
+          else _tiled_vjp(*config))
     return fn(
         q, k, v,
         jnp.asarray(q_offset, jnp.int32),
@@ -267,22 +284,52 @@ def recompute_attention(
 
 
 # ------------------------------------------------------------- tiled path
-TILED_BLOCK = 512  # the largest tile `attention_tile` names, and
-TILED_BLOCK_MIN = 256  # the smallest (a shorter sequence is one tile)
+TILED_BLOCK = 512  # the largest tile `attention_tile` names for the XLA
+TILED_BLOCK_MIN = 256  # walk, and the smallest (a shorter sequence is one)
+KERNEL_BLOCK = 512  # the kernels' (query block, key block): PERF.md, PR 38
 
 
-def attention_tile(t_q: int, t_k: int) -> tuple[int, int]:
-    """The (query block, key block) a call uses where its caller names none,
-    from the sequence lengths it is handed: a sixteenth of the longer one in
-    whole lane widths, held between ``TILED_BLOCK_MIN`` and ``TILED_BLOCK``.
-    On the chip 256 x 256 was the fastest pair, or within 4% of it, at t 1024
-    and 2048, and 512 x 512 at t 8192 (PERF.md section 6, PR 34). The head
-    size moved nothing where it was read (64 and 128 at t 1024) and the query
-    heads per kv head were read at one value a length, so the rule rests on
-    the lengths alone."""
+class Blocks(NamedTuple):
+    """What `attention_tile` says of a call: which walk it takes
+    (``"kernel"``: the Pallas kernels; ``"walk"``: the same walk in XLA) and
+    the (query block, key block) that walk really uses."""
+    path: str
+    block_q: int
+    block_k: int
+
+
+def attention_tile(t_q: int, t_k: int, head_dim: int, group: int, dtype,
+                   interpret: bool) -> Blocks:
+    """The path and the (query block, key block) a call uses where its
+    caller names none, from what the call sees: the sequence lengths, the
+    head size, the query heads a kv head serves (``group``), the dtype and
+    whether a kernel would be interpreted (anything but a TPU).
+
+    Compiled, the walk runs inside the kernels where a head is whole lane
+    tiles (128 wide: the kernels read it where it lies, a column slab of
+    [B, T, H * D]) and a head's step fits the chip's VMEM (`_kernel_vmem`: t
+    8,192 does, t 32,768 does not). Their blocks were read on the chip at t
+    4,096 and 8,192, alone and seven query heads a kv head: 512 x 512 was
+    the fastest pair at each, so ``group`` moves nothing yet. Heads of 64
+    stay on the XLA walk: at t 1,024 the kernels won 3.4% of a round of four
+    packed stations and LOST 2.5% where each station has a chip and the
+    cross-station ring runs beside the backward pass (PERF.md section 6,
+    PR 38).
+
+    Everywhere else the XLA walk runs, at a sixteenth of the longer length
+    in whole lane widths, held between ``TILED_BLOCK_MIN`` and
+    ``TILED_BLOCK``: on the chip 256 x 256 was the fastest pair, or within
+    4% of it, at t 1024 and 2048, and 512 x 512 at t 8192 (PERF.md section
+    6, PR 34)."""
+    if not interpret and head_dim % LANES == 0:
+        block_q, block_k = _kernel_blocks(
+            t_q, t_k, KERNEL_BLOCK, KERNEL_BLOCK, interpret)
+        if _kernel_vmem(_round_up(t_q, block_q), _round_up(t_k, block_k),
+                        head_dim, dtype, block_q, block_k) <= KERNEL_VMEM:
+            return Blocks("kernel", block_q, block_k)
     sixteenth = max(t_q, t_k) // 16 // LANES * LANES  # whole lane tiles
     block = min(TILED_BLOCK, max(TILED_BLOCK_MIN, sixteenth))
-    return min(block, t_q), min(block, t_k)
+    return Blocks("walk", min(block, t_q), min(block, t_k))
 
 
 def tiles_visited(t_q: int, t_k: int, block_q: int, block_k: int,
@@ -507,29 +554,296 @@ def _tiled_bwd(q, k, v, o, big_l, do, q_offset, k_offset, *, causal, scale,
             dv[:, :, :t_k].astype(v.dtype))
 
 
-@functools.lru_cache(maxsize=None)
-def _tiled_vjp(causal, scale, window, block_q, block_k):
-    """custom_vjp of the tiled path, one callable per static config. The
-    residuals are (q, k, v, o, L): L is [B, Hq, Tq] f32, so the backward
-    needs no first pass over the keys for the softmax statistics."""
-    kw = dict(causal=causal, scale=scale, window=window, block_q=block_q,
-              block_k=block_k)
+def _walk_vjp(forward, backward):
+    """custom_vjp of a walk over visible tiles: ``forward`` gives ``(o, L)``
+    and the residuals are (q, k, v, o, L): L is [B, Hq, Tq] f32, so the
+    backward needs no first pass over the keys for the softmax statistics."""
 
     @jax.custom_vjp
     def fa(q, k, v, qoff, koff):
-        return _tiled_forward(q, k, v, qoff, koff, **kw)[0]
+        return forward(q, k, v, qoff, koff)[0]
 
     def fwd(q, k, v, qoff, koff):
-        o, big_l = _tiled_forward(q, k, v, qoff, koff, **kw)
+        o, big_l = forward(q, k, v, qoff, koff)
         return o, (q, k, v, o, big_l, qoff, koff)
 
     def bwd(res, do):
         q, k, v, o, big_l, qoff, koff = res
-        return (*_tiled_bwd(q, k, v, o, big_l, do, qoff, koff, **kw),
-                None, None)
+        return (*backward(q, k, v, o, big_l, do, qoff, koff), None, None)
 
     fa.defvjp(fwd, bwd)
     return fa
+
+
+@functools.lru_cache(maxsize=None)
+def _tiled_vjp(causal, scale, window, block_q, block_k):
+    """The XLA walk, one callable per static config."""
+    kw = dict(causal=causal, scale=scale, window=window, block_q=block_q,
+              block_k=block_k)
+    return _walk_vjp(functools.partial(_tiled_forward, **kw),
+                     functools.partial(_tiled_bwd, **kw))
+
+
+# ------------------------------------------------------------ kernel path
+# The same walk inside two Pallas kernels. A grid step owns ONE query head:
+# its queries and its kv head's keys and values are blocks in VMEM, and
+# (backward) the kv head's float32 ``dK`` / ``dV`` sums are scratch there,
+# while the step walks the query blocks and, for each, the visible key
+# blocks (`_key_block_range`, the trip count of the inner loop), so a tile's
+# scores, probabilities and ``dS`` live in VMEM from the product that makes
+# them to the product that reads them.
+KERNEL_VMEM = 100 * 2**20  # what a step may hold of a v5e's 128 MiB
+
+
+def _kernel_vmem(t_q: int, t_k: int, head_dim: int, dtype, block_q: int,
+                 block_k: int) -> int:
+    """Bytes of VMEM the backward kernel's step holds (the larger of the
+    two): a head's q, dO and dQ, the kv head's k, v, dK and dV, each twice
+    (Pallas double-buffers a block), the row statistics, the float32 sums
+    of dK and dV and a few float32 tiles of scores."""
+    item = jnp.dtype(dtype).itemsize
+    blocks = (3 * t_q + 4 * t_k) * item * head_dim + 64 * t_q
+    return 2 * blocks + 2 * t_k * head_dim * 4 + 8 * block_q * block_k * 4
+
+
+def _kernel_blocks(t_q, t_k, block_q, block_k, interpret):
+    """A sequence shorter than a block is one block; compiled, a block is
+    whole lane tiles (it is the lane axis of a score tile, forward or
+    backward)."""
+    unit = 1 if interpret else LANES
+    block_q = min(block_q, _round_up(t_q, unit))
+    block_k = min(block_k, _round_up(t_k, unit))
+    if block_q % unit or block_k % unit:
+        raise ValueError(
+            f"the compiled attention kernels need blocks of whole lane "
+            f"tiles ({LANES}), got {block_q} x {block_k}")
+    return block_q, block_k
+
+
+def _pad_rows(x, block, axis=2):
+    pad = (-x.shape[axis]) % block
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(x, widths)
+
+
+def _rows(i, block):
+    return pl.ds(pl.multiple_of(i * block, block), block)
+
+
+_CONTRACT_LAST = (((1,), (1,)), ((), ()))  # a @ b.T
+_CONTRACT_FIRST = (((0,), (0,)), ((), ()))  # a.T @ b
+
+
+def _walk_fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, l_ref,
+                     *, causal, scale, window, block_q, block_k, t_k):
+    """One query head forward: q_ref / o_ref [Tq, D], k_ref / v_ref
+    [Tk, D], l_ref [Tq / block_q, block_q] (a query block's statistics are
+    a row). `_tiled_forward`'s online softmax, a tile by `_softmax_step`."""
+    n_k = k_ref.shape[0] // block_k
+    q_off, k_off = qoff_ref[0], koff_ref[0]
+
+    def query_block(i, _):
+        q_i = q_ref[_rows(i, block_q), :]
+        lo, hi = _key_block_range(i, block_q, block_k, n_k, t_k, q_off,
+                                  k_off, causal, window)
+
+        def key_block(j, carry):
+            keys = _rows(j, block_k)
+            return _softmax_step(
+                q_i, k_ref[keys, :], v_ref[keys, :], *carry,
+                q_off + i * block_q, k_off + j * block_k, j * block_k, t_k,
+                causal=causal, scale=scale, window=window)
+
+        m, l, acc = lax.fori_loop(lo, hi, key_block, (
+            jnp.full((block_q, LANES), NEG_INF, jnp.float32),
+            jnp.zeros((block_q, LANES), jnp.float32),
+            jnp.zeros((block_q, q_ref.shape[1]), jnp.float32)))
+        safe_l = jnp.where(l > 0, l, 1.0)
+        o_ref[_rows(i, block_q), :] = (acc / safe_l[:, :1]).astype(o_ref.dtype)
+        # the lane-replicated column [block_q, LANES] turned into a row
+        l_ref[pl.ds(i, 1), :] = (m + jnp.log(safe_l)).T[:1]
+        return 0
+
+    lax.fori_loop(0, q_ref.shape[0] // block_q, query_block, 0)
+
+
+def _walk_bwd_kernel(qoff_ref, koff_ref, q_ref, do_ref, k_ref, v_ref, l_ref,
+                     dsum_ref, dq_ref, dk_ref, dv_ref, dk_sum, dv_sum, *,
+                     causal, scale, window, block_q, block_k, t_k, group):
+    """One query head backward, `_tiled_bwd`'s five products on TRANSPOSED
+    tiles (keys down the sublanes, queries along the lanes), so that the
+    statistics are rows ([1, block_q] of l_ref / dsum_ref), ``dV`` and
+    ``dK`` are plain products and only ``dS`` is turned once a tile, for
+    ``dQ``. dk_sum / dv_sum are the kv head's float32 sums in scratch: the
+    steps of its query heads follow one another, the first clears them and
+    the last writes them out."""
+    n_k = k_ref.shape[0] // block_k
+    q_off, k_off = qoff_ref[0], koff_ref[0]
+    in_group = pl.program_id(1) % group
+
+    @pl.when(in_group == 0)
+    def _():
+        dk_sum[...] = jnp.zeros(dk_sum.shape, dk_sum.dtype)
+        dv_sum[...] = jnp.zeros(dv_sum.shape, dv_sum.dtype)
+
+    def query_block(i, _):
+        q_i, do_i = q_ref[_rows(i, block_q), :], do_ref[_rows(i, block_q), :]
+        l_i, d_i = l_ref[pl.ds(i, 1), :], dsum_ref[pl.ds(i, 1), :]
+        lo, hi = _key_block_range(i, block_q, block_k, n_k, t_k, q_off,
+                                  k_off, causal, window)
+        q_pos = q_off + i * block_q + lax.broadcasted_iota(
+            jnp.int32, (block_k, block_q), 1)
+
+        def key_block(j, dq_i):
+            keys = _rows(j, block_k)
+            k_j, v_j = k_ref[keys, :], v_ref[keys, :]
+            s = lax.dot_general(k_j, q_i, _CONTRACT_LAST,
+                                preferred_element_type=jnp.float32) * scale
+            k_idx = j * block_k + lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 0)
+            visible = k_idx < t_k
+            if causal:
+                visible &= q_pos >= k_off + k_idx
+                if window is not None:
+                    visible &= k_off + k_idx > q_pos - window
+            p = jnp.exp(jnp.where(visible, s, NEG_INF) - l_i)
+            dv_sum[keys, :] += jnp.dot(p.astype(do_i.dtype), do_i,
+                                       preferred_element_type=jnp.float32)
+            dp = lax.dot_general(v_j, do_i, _CONTRACT_LAST,
+                                 preferred_element_type=jnp.float32)
+            ds = (p * (dp - d_i)).astype(q_i.dtype)
+            dk_sum[keys, :] += jnp.dot(
+                ds, q_i, preferred_element_type=jnp.float32) * scale
+            return dq_i + lax.dot_general(
+                ds, k_j, _CONTRACT_FIRST,
+                preferred_element_type=jnp.float32) * scale
+
+        dq_i = lax.fori_loop(lo, hi, key_block, jnp.zeros(
+            (block_q, q_ref.shape[1]), jnp.float32))
+        dq_ref[_rows(i, block_q), :] = dq_i.astype(dq_ref.dtype)
+        return 0
+
+    lax.fori_loop(0, q_ref.shape[0] // block_q, query_block, 0)
+
+    @pl.when(in_group == group - 1)
+    def _():
+        dk_ref[...] = dk_sum[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_sum[...].astype(dv_ref.dtype)
+
+
+def _walk_call(kernel, name, offsets, q_like, kv_like, stats, out_kinds,
+               interpret, block_q, **static):
+    """One of the two kernels over the grid (batch, query head): ``q_like``
+    arrays ([B, Hq, Tq, D]) are blocked a query head [Tq, D], ``kv_like``
+    ([B, Hkv, Tk, D]) a kv head [Tk, D], ``stats`` a head's rows
+    [Tq / block_q, block_q]; the outputs by kind
+    (``"q"``: like q; ``"stats"``; ``"kv"``: like k, and each with a float32
+    sum of its size in scratch). ``offsets`` (q's, k's) are prefetched
+    scalars."""
+    q = q_like[0]
+    b, h_q, t_q, d = q.shape
+    h_kv, t_k = kv_like[0].shape[1:3]
+    group = h_q // h_kv
+    vma = jax.typeof(q).vma  # under shard_map the outputs vary as q does
+    # A head is read where it lies in [B, T, H * D], a column slab a head:
+    # the caller's [B, T, H, D] -> [B, H, T, D] and this way back cancel in
+    # XLA, and no transposed copy of q, k, v, o or of a gradient is made.
+    # Compiled, a slab is whole lane tiles: `attention_tile` sees to that.
+
+    def blocked(t, heads, index):
+        return (pl.BlockSpec((None, t, d), lambda b, h, *_: (b, 0, index(h))),
+                (b, t, heads * d))
+
+    def lay(x):  # [B, H, T, D] as the kernel reads it
+        return x.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[2], -1)
+
+    def unlay(x):
+        return x.reshape(x.shape[0], x.shape[1], -1, d).transpose(0, 2, 1, 3)
+
+    q_spec, q_shape = blocked(t_q, h_q, lambda h: h)
+    kv_spec, kv_shape = blocked(t_k, h_kv, lambda h: h // group)
+    stats_shape = (b, h_q, t_q // block_q, block_q)
+    specs = {
+        "q": (q_spec, jax.ShapeDtypeStruct(q_shape, q.dtype, vma=vma)),
+        "stats": (pl.BlockSpec((None, None) + stats_shape[2:],
+                               lambda b, h, *_: (b, h, 0, 0)),
+                  jax.ShapeDtypeStruct(stats_shape, jnp.float32, vma=vma)),
+        "kv": (kv_spec, jax.ShapeDtypeStruct(
+            kv_shape, kv_like[0].dtype, vma=vma)),
+    }
+    out = pl.pallas_call(
+        functools.partial(kernel, block_q=block_q, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, h_q),
+            in_specs=[specs["q"][0]] * len(q_like)
+            + [specs["kv"][0]] * len(kv_like)
+            + [specs["stats"][0]] * len(stats),
+            out_specs=[specs[kind][0] for kind in out_kinds],
+            scratch_shapes=[pltpu.VMEM((t_k, d), jnp.float32)
+                            for kind in out_kinds if kind == "kv"],
+        ),
+        out_shape=[specs[kind][1] for kind in out_kinds],
+        compiler_params=pltpu.CompilerParams(
+            # a kv head's sums are added to by its query heads in turn
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=KERNEL_VMEM,
+        ),
+        interpret=interpret,
+        name=name,
+    )(*(jnp.reshape(x, (1,)) for x in offsets), *map(lay, q_like),
+      *map(lay, kv_like), *stats)
+    return [x if kind == "stats" else unlay(x)
+            for kind, x in zip(out_kinds, out)]
+
+
+def _kernel_forward(q, k, v, q_offset, k_offset, *, causal, scale, window,
+                    block_q, block_k, interpret):
+    """`_tiled_forward` as a kernel: ``o`` [B, Hq, Tq, D] and ``L``
+    [B, Hq, Tq] f32."""
+    b, h_q, t_q, _ = q.shape
+    t_k = k.shape[2]
+    block_q, block_k = _kernel_blocks(t_q, t_k, block_q, block_k, interpret)
+    o, big_l = _walk_call(
+        _walk_fwd_kernel, "attention_walk_fwd", (q_offset, k_offset),
+        (_pad_rows(q, block_q),),
+        (_pad_rows(k, block_k), _pad_rows(v, block_k)), (),
+        ("q", "stats"), interpret, block_q, causal=causal, scale=scale,
+        window=window, block_k=block_k, t_k=t_k)
+    return o[:, :, :t_q], big_l.reshape(b, h_q, -1)[:, :, :t_q]
+
+
+def _kernel_bwd(q, k, v, o, big_l, do, q_offset, k_offset, *, causal, scale,
+                window, block_q, block_k, interpret):
+    """`_tiled_bwd` as a kernel: the same five products a visible tile."""
+    b, h_q, t_q, _ = q.shape
+    t_k = k.shape[2]
+    block_q, block_k = _kernel_blocks(t_q, t_k, block_q, block_k, interpret)
+    d_term = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+
+    def rows(x):  # [B, Hq, Tq] -> [B, Hq, n_q, block_q]
+        return _pad_rows(x, block_q).reshape(b, h_q, -1, block_q)
+
+    dq, dk, dv = _walk_call(
+        _walk_bwd_kernel, "attention_walk_bwd", (q_offset, k_offset),
+        (_pad_rows(q, block_q), _pad_rows(do, block_q)),
+        (_pad_rows(k, block_k), _pad_rows(v, block_k)),
+        (rows(big_l), rows(d_term)), ("q", "kv", "kv"), interpret, block_q,
+        causal=causal, scale=scale, window=window, block_k=block_k, t_k=t_k,
+        group=h_q // k.shape[1])
+    return dq[:, :, :t_q], dk[:, :, :t_k], dv[:, :, :t_k]
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_vjp(causal, scale, window, block_q, block_k, interpret):
+    """The walk inside the kernels, one callable per static config."""
+    kw = dict(causal=causal, scale=scale, window=window, block_q=block_q,
+              block_k=block_k, interpret=interpret)
+    return _walk_vjp(functools.partial(_kernel_forward, **kw),
+                     functools.partial(_kernel_bwd, **kw))
 
 
 def _pad_and_flatten(q, k, v, block_q: int, block_k: int):

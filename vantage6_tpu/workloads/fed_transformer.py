@@ -57,11 +57,12 @@ class TransformerConfig:
     # "flash": the Pallas flash kernel (ops.flash_attention) — requires the
     # full sequence on each device (seq_devices == 1, enforced by
     # make_engine); `flash_interpret` runs it in interpret mode on CPU.
-    # "recompute": flash-memory attention WITHOUT pallas (ops.
-    # recompute_attention: forward and backward over tiles in jnp, only the
-    # key blocks a query block can see, the tile chosen from the shapes) —
-    # same seq_devices == 1 constraint. "flash" runs the kernel or raises;
-    # it never gives way to "recompute".
+    # "recompute": flash-memory attention (ops.recompute_attention: forward
+    # and backward over tiles, only the key blocks a query block can see;
+    # inside two Pallas kernels on a TPU, the same walk in jnp where
+    # `flash_interpret` says a kernel would be interpreted; path and blocks
+    # chosen from the shapes) — same seq_devices == 1 constraint. "flash"
+    # runs the kernel or raises; it never gives way to "recompute".
     attention: str = "ring"
     flash_interpret: bool = False
     # Rematerialization: drop every layer's activations on the forward pass
@@ -347,10 +348,7 @@ def _forward(
                 flash_attention if cfg.attention == "flash"
                 else recompute_attention
             )
-            kw = (
-                {"interpret": cfg.flash_interpret}
-                if cfg.attention == "flash" else {}
-            )
+            kw = {"interpret": cfg.flash_interpret}
             if window is not None:
                 kw["window"] = window
             with jax.named_scope("attention"):
@@ -652,9 +650,11 @@ class FedTransformer:
         return tuple(out)
 
     def attention_walk(self, t: int) -> dict[str, Any]:
-        """What says that `recompute_attention` walked visible tiles only at
-        sequence length ``t``: ``attention_tile`` (``"<block_q>x<block_k>"``,
-        the tile its shapes gave) and, summed over the layer applications
+        """What says how `recompute_attention` walks visible tiles at sequence
+        length ``t``: ``attention_path`` (``"kernel"``: inside the Pallas
+        kernels; ``"walk"``: in XLA), ``attention_tile``
+        (``"<block_q>x<block_k>"``, the blocks that path really uses: both
+        `attention_tile`'s) and, summed over the layer applications
         of one sequence and head (every layer once a walk of the stack),
         ``attention_tiles_visited`` of ``attention_tiles`` (a windowed layer
         visits fewer). Computed once a length; nothing for the other
@@ -663,13 +663,16 @@ class FedTransformer:
             return {}
         if t not in self._walks:
             cfg = self.cfg
-            tile = attention_tile(t, t)
+            path, *tile = attention_tile(
+                t, t, cfg.head_dim, cfg.n_heads // cfg.n_kv_heads, cfg.dtype,
+                cfg.flash_interpret)
             kinds = collections.Counter(
                 cfg.layer_window(i) for i in range(cfg.n_layers))
             counts = cfg.loops * sum(
                 n * np.array(tiles_visited(t, t, *tile, True, window))
                 for window, n in kinds.items())
             self._walks[t] = {
+                "attention_path": path,
                 "attention_tile": "{}x{}".format(*tile),
                 "attention_tiles_visited": int(counts[0]),
                 "attention_tiles": int(counts[1])}
