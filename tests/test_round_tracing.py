@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import importlib
 import os
 import re
@@ -109,8 +110,11 @@ def _fedavg_lowered(engine, params, data):
 
 
 # ------------------------------------------------------------------ scopes
-TRANSFORMER_SCOPES = {"local_train", "embed", "attention", "mlp",
-                      "lm_head_loss", "aggregate", "server_update"}
+TRANSFORMER_SCOPES = {"local_train", "embed", "qkv", "attention", "attn_out",
+                      "mlp", "norms", "lm_head_loss", "aggregate",
+                      "server_update"}
+# the block's scopes that only ever nest inside the others or sit beside them
+BLOCK_SCOPES = ("qkv", "rotary", "attn_out", "norms")
 FEDAVG_SCOPES = {"pack_table", "local_train", "gather", "loss_grad",
                  "learning_stats", "aggregate", "server_update"}
 PROGRAMS = {
@@ -120,9 +124,10 @@ PROGRAMS = {
     "transformer-flash": (lambda: _transformer("flash"), TRANSFORMER_SCOPES),
     "transformer-experts": (
         _transformer_experts,
-        TRANSFORMER_SCOPES - {"mlp"} | {"router", "experts"}),
+        TRANSFORMER_SCOPES - {"mlp"} | {"router", "experts", "rotary"}),
     "transformer-looped": (
-        _transformer_looped, TRANSFORMER_SCOPES | {"loop", "exit_gate"}),
+        _transformer_looped,
+        TRANSFORMER_SCOPES | {"loop", "exit_gate", "rotary"}),
     "fedavg-fused": (lambda: _fedavg(), FEDAVG_SCOPES),
     "fedavg-compressed-zero1": (
         lambda: _fedavg(
@@ -139,28 +144,53 @@ def _lowered(built):
     return _fedavg_lowered(*built)
 
 
-def _scopes_in(lowered) -> set[str]:
+def _op_names(lowered) -> list[str]:
+    return re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+
+
+@functools.cache
+def _program_op_names(program) -> tuple[str, ...]:
+    """The compiled program's operation paths, once a program a worker."""
+    return tuple(_op_names(_lowered(PROGRAMS[program][0]())))
+
+
+def _scopes_in(names) -> set[str]:
     """Scope names as the compiled operations' metadata carries them, which
     is what a device trace shows: an element of `op_name`'s path
     (`.../attention/...`) or, backward, `transpose(jvp(attention))`. An
     operation's own name (`.../gather`) ends the path and is not one."""
-    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
     return {s for s in DEVICE_SCOPES
             if any(re.search(rf"[/(]{s}[/)]", n) for n in names)}
 
 
 @pytest.mark.parametrize("program", PROGRAMS)
 def test_the_compiled_operations_carry_each_scope_name(program):
-    build, expected = PROGRAMS[program]
-    assert _scopes_in(_lowered(build())) == expected
+    assert _scopes_in(_program_op_names(program)) == PROGRAMS[program][1]
+
+
+@pytest.mark.parametrize(
+    "program", [p for p in PROGRAMS if p.startswith("transformer")])
+def test_the_block_scopes_never_enclose_another_scope(program):
+    """`qkv`, `rotary`, `attn_out` and `norms` nest inside the other scopes
+    or sit beside them: no other scope comes after one of them in a path, so
+    each metric of the other scopes reads what it read without them."""
+    def at(scope, name):
+        return [m.start() for m in re.finditer(rf"[/(]{scope}[/)]", name)]
+
+    others = [s for s in DEVICE_SCOPES if s not in BLOCK_SCOPES]
+    for name in _program_op_names(program):
+        first = min((i for s in BLOCK_SCOPES for i in at(s, name)),
+                    default=None)
+        if first is not None:
+            assert not [s for s in others
+                        if any(i > first for i in at(s, name))], name
 
 
 def test_the_pack_lies_outside_local_train_and_the_gather_inside_it():
     """`pack_table` is paid once per dispatch, `gather` once per local
     step: an operation's path holds one of them, never both, and only the
     gather's lies under `local_train`."""
-    names = re.findall(r'op_name="([^"]*)"',
-                       _lowered(PROGRAMS["fedavg-fused"][0]()).compile().as_text())
+    names = _program_op_names("fedavg-fused")
     packs = [n for n in names if "/pack_table/" in n]
     gathers = [n for n in names if re.search(r"/gather/.*gather", n)]
     assert packs and gathers
@@ -170,7 +200,7 @@ def test_the_pack_lies_outside_local_train_and_the_gather_inside_it():
 
 def test_with_labels_of_another_width_no_pack_is_on_the_device():
     lowered = _fedavg_lowered(*_fedavg(y_dtype=jnp.int8))
-    assert _scopes_in(lowered) == FEDAVG_SCOPES - {"pack_table"}
+    assert _scopes_in(_op_names(lowered)) == FEDAVG_SCOPES - {"pack_table"}
 
 
 def test_every_scope_of_the_tuple_is_opened_by_some_program():
@@ -180,6 +210,7 @@ def test_every_scope_of_the_tuple_is_opened_by_some_program():
 
 
 @pytest.mark.parametrize("program", ["transformer-recompute",
+                                     "transformer-looped",
                                      "fedavg-compressed-zero1"])
 def test_scopes_are_metadata_and_nothing_else(program, monkeypatch):
     """The program as XLA gets it (the text without locations, which is
@@ -190,7 +221,7 @@ def test_scopes_are_metadata_and_nothing_else(program, monkeypatch):
                         lambda name: contextlib.nullcontext())
     without = _lowered(build())
     assert with_scopes.as_text() == without.as_text()
-    assert not _scopes_in(without)
+    assert not _scopes_in(_op_names(without))
 
 
 # ------------------------------------------------------------------- spans

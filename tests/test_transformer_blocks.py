@@ -217,7 +217,7 @@ def test_the_router_and_experts_scopes_are_on_the_device_operations(inputs):
     ).compile().as_text()
     names = re.findall(r'op_name="([^"]*)"', text)
     for scope in ("router", "experts", "attention", "embed",
-                  "lm_head_loss"):
+                  "lm_head_loss", "qkv", "rotary", "attn_out", "norms"):
         assert any(re.search(rf"[/(]{scope}[/)]", n) for n in names), scope
     assert not any(re.search(r"[/(]mlp[/)]", n) for n in names)
 
@@ -543,7 +543,7 @@ def test_the_block_is_traced_and_lowered_once_a_kind(attention, remat):
     for name in blocks:  # forward, backward: each called once a layer
         assert len(re.findall(rf"call @{name}\(", text)) == 6
     names = re.findall(r'op_name="([^"]*)"', six.compile().as_text())
-    for scope in ("attention", "mlp"):
+    for scope in ("qkv", "attention", "attn_out", "mlp", "norms"):
         assert any(re.search(rf"/local_train/.*jit\(layer_block\).*/{scope}/", n)
                    for n in names), scope
     # the block's own name is no scope of the device's operations

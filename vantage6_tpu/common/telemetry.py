@@ -177,8 +177,6 @@ KNOWN_METRICS: list[tuple[str, str, str]] = [
     ("v6t_flight_dumps_total", "counter", "flight-recorder bundles written"),
     # device observatory (runtime.profiling — docs/observability.md
     # "device plane"): every jit entry point's compile/retrace economics
-    ("v6t_jit_dispatches_total", "counter",
-     "calls dispatched through observed jit functions"),
     ("v6t_jit_compiles_total", "counter",
      "XLA lower+compile events recorded by the device observatory"),
     ("v6t_jit_lower_seconds_total", "counter",

@@ -94,9 +94,14 @@ __all__ = [
 # workloads/fed_transformer.py local_train (with embed, attention, mlp and
 # lm_head_loss inside it; a block with experts opens router, before
 # attention, and experts where the dense one opens mlp) and server_update.
+# Between them the block opens qkv (the product and the split into heads),
+# rotary, attn_out (the output product, the norm after it, the residual add)
+# and, around every norm, norms: these four only ever nest inside the scopes
+# above or sit beside them, never around one, so what those read stays put.
 DEVICE_SCOPES = (
     "pack_table", "local_train", "gather", "loss_grad", "embed", "loop",
     "attention", "mlp", "router", "experts", "lm_head_loss", "exit_gate",
+    "qkv", "rotary", "attn_out", "norms",
     "compress", "learning_stats", "aggregate", "server_update",
 )
 
@@ -362,7 +367,6 @@ class ObservedFunction:
                 REGISTRY.counter("v6t_jit_fallbacks_total").inc()
                 return self._jit(*args, **kwargs)
             self.dispatches += 1
-            REGISTRY.counter("v6t_jit_dispatches_total").inc()
             with self._lock:
                 compiled = self._sigs.get(key)
             if compiled is None:

@@ -258,27 +258,31 @@ def _ln(x: jax.Array) -> jax.Array:
 
 def _norm(x: jax.Array, scale: jax.Array | None, cfg: TransformerConfig):
     """The configuration's norm: the parameter-free LayerNorm above, or
-    ``x / sqrt(mean(x^2) + eps) * g`` with the learned ``g`` (float32)."""
-    if cfg.norm == "layernorm":
-        return _ln(x)
-    xf = x.astype(jnp.float32)
-    ms = jnp.mean(xf * xf, -1, keepdims=True)
-    return (xf * lax.rsqrt(ms + cfg.norm_eps) * scale).astype(x.dtype)
+    ``x / sqrt(mean(x^2) + eps) * g`` with the learned ``g`` (float32).
+    Every norm opens the `norms` scope, nested in whatever scope calls it."""
+    with jax.named_scope("norms"):
+        if cfg.norm == "layernorm":
+            return _ln(x)
+        xf = x.astype(jnp.float32)
+        ms = jnp.mean(xf * xf, -1, keepdims=True)
+        return (xf * lax.rsqrt(ms + cfg.norm_eps) * scale).astype(x.dtype)
 
 
 def _rotate(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """Rotate-half RoPE of ``x`` [B, T, H, D] at global ``positions`` [T]:
-    pair ``(x[i], x[i + D/2])`` turns by ``positions * theta^(-2i/D)``."""
-    half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = positions.astype(jnp.float32)[:, None] * freq[None, :]
-    cos = jnp.cos(angle)[None, :, None, :]
-    sin = jnp.sin(angle)[None, :, None, :]
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    ).astype(x.dtype)
+    pair ``(x[i], x[i + D/2])`` turns by ``positions * theta^(-2i/D)``;
+    in the `rotary` scope."""
+    with jax.named_scope("rotary"):
+        half = x.shape[-1] // 2
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+        angle = positions.astype(jnp.float32)[:, None] * freq[None, :]
+        cos = jnp.cos(angle)[None, :, None, :]
+        sin = jnp.sin(angle)[None, :, None, :]
+        xf = x.astype(jnp.float32)
+        x1, x2 = xf[..., :half], xf[..., half:]
+        return jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        ).astype(x.dtype)
 
 
 def _head(params: dict[str, Any], cfg: TransformerConfig) -> jax.Array:
@@ -331,11 +335,12 @@ def _forward(
                     x.reshape(b * t_local, cfg.d_model), kept["router"],
                     cfg.top_k)
         h = _norm(x, kept.get("norm1"), cfg)
-        qkv = h @ layer["qkv"]
-        q, k, v = jnp.split(qkv, [n_q, n_q + n_kv], axis=-1)
-        q = q.reshape(b, t_local, cfg.n_heads, cfg.head_dim)
-        k = k.reshape(b, t_local, cfg.n_kv_heads, cfg.head_dim)
-        v = v.reshape(b, t_local, cfg.n_kv_heads, cfg.head_dim)
+        with jax.named_scope("qkv"):
+            qkv = h @ layer["qkv"]
+            q, k, v = jnp.split(qkv, [n_q, n_q + n_kv], axis=-1)
+            q = q.reshape(b, t_local, cfg.n_heads, cfg.head_dim)
+            k = k.reshape(b, t_local, cfg.n_kv_heads, cfg.head_dim)
+            v = v.reshape(b, t_local, cfg.n_kv_heads, cfg.head_dim)
         if rotates:
             positions = offset + jnp.arange(t_local)
             q = _rotate(q, positions, cfg.rope_theta)
@@ -364,10 +369,13 @@ def _forward(
         else:
             with jax.named_scope("attention"):
                 attn = ring_attention(q, k, v, axis_name, causal=True)
-        attn = attn.reshape(b, t_local, n_q) @ layer["proj"]
-        if cfg.norm_after:
-            attn = _norm(attn, kept.get("norm1_post"), cfg)
-        x = x + attn
+        # the residual add too: where XLA fuses it into the product's
+        # output, the fusion carries the add's path
+        with jax.named_scope("attn_out"):
+            attn = attn.reshape(b, t_local, n_q) @ layer["proj"]
+            if cfg.norm_after:
+                attn = _norm(attn, kept.get("norm1_post"), cfg)
+            x = x + attn
         if cfg.ffn == "experts":
             return x, routing  # the expert layer follows: `expert_half`
         with jax.named_scope("mlp"):
