@@ -280,3 +280,78 @@ def test_the_packed_table_must_fit_beside_the_table(mesh, monkeypatch):
     assert engine.gather_path(x, y) == "packed"
     monkeypatch.setattr(engine, "_bytes_limit", 4 * per_device - 2)
     assert engine.gather_path(x, y) == "separate"
+
+
+# ------------------------------------------ the streamed gather (ISSUE 40)
+# Where a step's batch is a large share of its table, a TPU program streams
+# the packed table through VMEM once a step (`ops.stream_gather`) and keeps
+# the rows its sorted indices name. The rule reads shapes and the devices'
+# platform; on the CPU it never streams, so every program above lowers to
+# the parent's text. A test steers the platform, or the path, on the engine
+# it built.
+ENGINE_CELL = (jax.ShapeDtypeStruct((32, 262144, 100), jnp.float32),
+               jax.ShapeDtypeStruct((32, 262144), jnp.float32))
+
+
+@pytest.mark.parametrize("platform,batch_size,y_dtype,path", [
+    ("tpu", 32768, jnp.float32, "streamed"),   # the engine cell
+    ("tpu", 16384, jnp.float32, "streamed"),   # an eighth: over break-even
+    ("tpu", 4096, jnp.float32, "packed"),      # a small batch, a large table
+    ("tpu", 32768, jnp.int8, "separate"),      # no pack at all
+    ("cpu", 32768, jnp.float32, "packed"),     # the kernel is not compiled
+])
+def test_the_cost_rule_streams_where_the_table_moves_faster_than_indices(
+        mesh, monkeypatch, platform, batch_size, y_dtype, path):
+    from vantage6_tpu.fed import fedavg as F
+
+    engine = F.FedAvg(mesh, F.FedAvgSpec(loss_fn=_nll, batch_size=batch_size))
+    assert engine._platform == "cpu"
+    monkeypatch.setattr(engine, "_platform", platform)
+    x, y = ENGINE_CELL
+    y = jax.ShapeDtypeStruct(y.shape, y_dtype)
+    assert engine.gather_path(x, y) == path
+    # the break-even: 262,144 rows of 128 words at 819 bytes a ns against
+    # 10.5 ns an index, about 15,600 indices
+    stream_ns = 262144 * 512 / F.HBM_BYTES_PER_NS
+    assert (batch_size * F.PER_INDEX_NS > stream_ns) == (batch_size > 15604)
+    attrs = engine._gather_attrs(x, y)
+    assert attrs == ({"gather": "streamed", "gather_block_rows": 16384,
+                      "gather_blocks": 16} if path == "streamed"
+                     else {"gather": path})
+
+
+@pytest.mark.parametrize("call", ["run_rounds", "round"])
+def test_streamed_rows_train_as_the_packed_rows_do(mesh, monkeypatch, call):
+    """Steered to the streamed path (its kernel interpreted on the CPU),
+    three rounds give the packed path's losses and parameters to the order
+    of a float32 sum: the same rows, fetched in table order."""
+    engine, *args = _logreg(mesh)
+    assert engine.gather_path(args[1], args[2]) == "packed"
+    packed = _three_rounds(engine, call, *args)
+    engine, *args = _logreg(mesh)
+    monkeypatch.setattr(engine, "gather_path", lambda x, y: "streamed")
+    streamed = _three_rounds(engine, call, *args)
+    la, lb = jax.tree.leaves(packed), jax.tree.leaves(streamed)
+    assert len(la) == len(lb) and la
+    for u, v in zip(la, lb):
+        np.testing.assert_allclose(np.asarray(v), np.asarray(u),
+                                   rtol=1e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("model", ["logreg-f32-labels", "cnn-int32-labels"])
+def test_the_streamed_take_is_the_packed_take_of_the_sorted_indices(
+        mesh, fed_data, monkeypatch, model):
+    """What `loss_fn` receives, bit for bit: a station's streamed rows and
+    labels are the packed path's for the same indices sorted (the CNN
+    computes in bfloat16, where another order of a sum is another loss)."""
+    engine, _, x, y, counts = (_logreg(mesh) if model.startswith("logreg")
+                               else _cnn(mesh, fed_data))
+    (packed,), take_packed = engine._minibatch_source(x, y)
+    monkeypatch.setattr(engine, "gather_path", lambda x, y: "streamed")
+    (streamed,), take_streamed = engine._minibatch_source(x, y)
+    assert streamed.shape[-1] % 128 == 0 and streamed.shape[:2] == packed.shape[:2]
+    rng = np.random.default_rng(40)
+    for s in range(x.shape[0]):
+        idx = jnp.asarray(rng.integers(0, int(counts[s]), 16), jnp.int32)
+        _same_bits(take_streamed(streamed[s], idx),
+                   take_packed(packed[s], jnp.sort(idx)))

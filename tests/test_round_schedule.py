@@ -9,6 +9,10 @@ The compiler's scheduler places every operation as late as it may and would
 leave all of them to the end of the backward pass (PERF.md section 6, PR 36);
 what stops it is `collectives.RingExchange`, and this is what guards that.
 
+The file holds the repository's other compiles for the described chip
+too (the attention kernels, PR 38; the streamed gather, PR 40), so that one
+worker of a test run loads the TPU's library.
+
 The topology is described inside a fixture, never while a module is
 imported: only one process may hold the TPU's library, and every worker of
 the test run imports every test file.
@@ -214,3 +218,39 @@ def test_the_attention_kernels_compile_at_a_cells_shapes(
     for name in ("attention_walk_fwd", "attention_walk_bwd"):
         assert sum("custom-call" in line and name in line
                    for line in text.splitlines()) == 1
+
+
+# ------------------------------------------------ the streamed gather (PR 40)
+def test_the_engine_cell_streams_its_table_on_the_chip(topo, uncached):
+    """On a mesh of a described v5e the cost rule streams the engine cell's
+    tables (32 stations x 262,144 rows of 100 float32 features and a label,
+    32,768 rows a step) and keeps XLA's gather for a small batch; the kernel
+    compiles at the cell's widths, the stations' `vmap` a grid axis (one
+    custom call a step, not one a station)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from vantage6_tpu.core.mesh import FederationMesh
+    from vantage6_tpu.fed.fedavg import STREAM_BLOCK_ROWS, FedAvg, FedAvgSpec
+    from vantage6_tpu.ops.stream_gather import stream_gather
+
+    mesh = FederationMesh(32, devices=list(topo.devices)[:1])
+    x = jax.ShapeDtypeStruct((32, 262144, 100), jnp.float32)
+    y = jax.ShapeDtypeStruct((32, 262144), jnp.float32)
+
+    def engine(batch_size):
+        return FedAvg(mesh, FedAvgSpec(loss_fn=None, batch_size=batch_size))
+
+    assert engine(32768).gather_path(x, y) == "streamed"
+    assert engine(32768)._gather_attrs(x, y) == {
+        "gather": "streamed", "gather_block_rows": STREAM_BLOCK_ROWS,
+        "gather_blocks": 262144 // STREAM_BLOCK_ROWS}
+    assert engine(1024).gather_path(x, y) == "packed"
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    table = jax.ShapeDtypeStruct((32, 262144, 128), jnp.uint32,
+                                 sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((32, 32768), jnp.int32, sharding=one_chip)
+    text = jax.jit(jax.vmap(lambda t, i: stream_gather(
+        t, jnp.sort(i), block_rows=STREAM_BLOCK_ROWS))
+    ).lower(table, idx).compile().as_text()
+    assert sum("custom-call" in line and "stream_gather" in line
+               for line in text.splitlines()) == 1

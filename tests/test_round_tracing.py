@@ -88,10 +88,14 @@ def _nll(p, x, y, w):
     return jnp.sum(w * (jnp.logaddexp(0.0, z) - y * z)) / jnp.sum(w)
 
 
-def _fedavg(y_dtype=jnp.float32, **spec):
+def _fedavg(y_dtype=jnp.float32, streamed=False, **spec):
+    """``streamed``: the rule steered to the streamed kernel, which the CPU
+    interprets (the rule itself streams on a TPU alone)."""
     mesh = FederationMesh(8)
     engine = FedAvg(mesh, FedAvgSpec(
         loss_fn=_nll, local_steps=2, batch_size=8, **spec))
+    if streamed:
+        engine.gather_path = lambda x, y: "streamed"
     rng = np.random.default_rng(0)
     x = mesh.shard_stacked(jnp.asarray(rng.normal(size=(8, 16, 5)),
                                        jnp.float32))
@@ -129,6 +133,7 @@ PROGRAMS = {
         _transformer_looped,
         TRANSFORMER_SCOPES | {"loop", "exit_gate", "rotary"}),
     "fedavg-fused": (lambda: _fedavg(), FEDAVG_SCOPES),
+    "fedavg-streamed": (lambda: _fedavg(streamed=True), FEDAVG_SCOPES),
     "fedavg-compressed-zero1": (
         lambda: _fedavg(
             compressor=CompressorSpec(topk_ratio=0.5, int8=True),
@@ -196,6 +201,20 @@ def test_the_pack_lies_outside_local_train_and_the_gather_inside_it():
     assert packs and gathers
     assert not [n for n in packs if "local_train" in n or "while" in n]
     assert all("/local_train/" in n and "/while/" in n for n in gathers)
+
+
+def test_the_streamed_kernel_and_its_sort_lie_inside_the_gather():
+    """Streamed, the step's sort and the kernel are the `gather` scope's,
+    under `local_train` and inside the loop over steps, as the gather
+    was: `gather_ms` reads them. (A sort's comparator carries a path
+    relative to its call, `vmap()/...`: its parameters take no time.)"""
+    names = [n for n in _program_op_names("fedavg-streamed")
+             if n.startswith("jit(")]
+    kernel = [n for n in names if "stream_gather" in n]
+    sort = [n for n in names if re.search(r"/gather/.*sort", n)]
+    assert kernel and sort
+    assert all("/local_train/" in n and "/gather/" in n and "/while/" in n
+               for n in kernel + sort)
 
 
 def test_with_labels_of_another_width_no_pack_is_on_the_device():
@@ -296,15 +315,21 @@ def test_one_engine_call_with_one_launch_under_it(engine, caller):
 
 
 @pytest.mark.parametrize("y_dtype,path", [(jnp.float32, "packed"),
-                                          (jnp.int8, "separate")])
+                                          (jnp.int8, "separate"),
+                                          (jnp.float32, "streamed")])
 @pytest.mark.parametrize("engine", ["fedavg.run_rounds", "fedavg.round"])
 def test_the_engine_call_says_which_gather_its_program_was_built_with(
         engine, y_dtype, path):
     """The counter that the packed gather engaged: a string on the span
-    that exists, the same rule the traced program read."""
-    ENGINES[engine](y_dtype=y_dtype)
+    that exists, the same rule the traced program read. Streamed, the span
+    also says the kernel's block of table rows and how many blocks a
+    station's table is streamed in: 16 rows are one block of 16."""
+    ENGINES[engine](y_dtype=y_dtype, streamed=path == "streamed")
     (call,) = _named(TRACER.drain(), "engine.call")
-    assert call["attrs"]["gather"] == path
+    said = {k: v for k, v in call["attrs"].items() if k.startswith("gather")}
+    assert said == ({"gather": path, "gather_block_rows": 16,
+                     "gather_blocks": 1} if path == "streamed"
+                    else {"gather": path})
 
 
 @pytest.mark.parametrize("attention", ["recompute", "flash", "ring"])

@@ -49,11 +49,22 @@ from vantage6_tpu.fed.compression import (
     compress_stacked,
     record_round_telemetry,
 )
+from vantage6_tpu.ops import stream_gather as SG
 from vantage6_tpu.runtime.profiling import engine_call, observed_jit
 
 Pytree = Any
 # loss_fn(params, batch_x, batch_y, example_weights) -> scalar mean loss
 LossFn = Callable[[Pytree, jax.Array, jax.Array, jax.Array], jax.Array]
+
+# What `gather_path` weighs a streamed table against the gather with: an XLA
+# gather costs about 10.5 ns an index on the v5e, whatever the row's width
+# (PERF.md section 6, PR 27), and a stream moves the table at the v5e's
+# published 819 GB/s of HBM, 819 bytes a nanosecond. The kernel's blocks of
+# table rows were read on the chip: 16,384 rows the fastest of 4,096, 8,192
+# and 16,384 (PERF.md section 6, PR 40).
+PER_INDEX_NS = 10.5
+HBM_BYTES_PER_NS = 819.0
+STREAM_BLOCK_ROWS = 16384
 
 
 def _viewable(dtype: Any) -> bool:
@@ -175,8 +186,10 @@ class FedAvg:
             spec.compressor is not None and not spec.compressor.identity
         )
         self.server_opt = spec.server_optimizer or optax.sgd(1.0)
-        # what gather_path() weighs the packed table against
+        # what gather_path() weighs the packed table against, and whether
+        # the program is compiled for a TPU (the streamed kernel's)
         self._bytes_limit = _device_bytes_limit(mesh.mesh.devices.flat[0])
+        self._platform = mesh.mesh.devices.flat[0].platform
         # optional learning-plane sink (attach_history): when set, every
         # round()/run_rounds() host-records its stats into it
         self.history: Any = None
@@ -214,17 +227,22 @@ class FedAvg:
     # ------------------------------------------------------------ local step
     def gather_path(self, stacked_x: Any, stacked_y: Any) -> str:
         """How a local step fetches its minibatch from these tables:
-        ``"packed"`` (one gather per step over rows that carry their label)
-        or ``"separate"`` (a gather of ``x`` and one of ``y``). Read from
-        what the program sees when it is traced, and from nothing else:
-        shapes, dtypes and, where the backend reports one, the device's
-        ``bytes_limit``. The packed table is a second copy of the data for
-        the length of a dispatch, so it is built only where table and copy
-        together take at most half of a device's memory (the other half is
-        the step's); and only where ``y``'s elements are as wide as
-        ``x``'s, so that labels and features ride side by side as unsigned
-        integers of that width, bit for bit. The gather costs per index,
-        not per byte (docs/device_speed.md "One gather per local step")."""
+        ``"packed"`` (one gather per step over rows that carry their label),
+        ``"streamed"`` (the same rows, the table streamed through VMEM once
+        a step) or ``"separate"`` (a gather of ``x`` and one of ``y``). Read
+        from what the program sees when it is traced, and from nothing
+        else: shapes, dtypes, the devices' platform and, where the backend
+        reports one, the device's ``bytes_limit``. The packed table is a
+        second copy of the data for the length of a dispatch, so it is built
+        only where table and copy together take at most half of a device's
+        memory (the other half is the step's); and only where ``y``'s
+        elements are as wide as ``x``'s, so that labels and features ride
+        side by side as unsigned integers of that width, bit for bit. The
+        gather costs per index, not per byte (docs/device_speed.md "One
+        gather per local step"); where the packed rows are 32-bit words,
+        the program is compiled for a TPU and streaming a station's table
+        costs less than a batch of indices (`_streams`), they are
+        streamed."""
         xd, yd = jnp.dtype(stacked_x.dtype), jnp.dtype(stacked_y.dtype)
         if not (_viewable(xd) and _viewable(yd) and xd.itemsize == yd.itemsize):
             return "separate"
@@ -233,7 +251,38 @@ class FedAvg:
             table = xd.itemsize * (stacked_x.size + stacked_y.size)
             if 2 * (table // self.mesh.station_axis_size) > self._bytes_limit // 2:
                 return "separate"
+        if self._streams(stacked_x, stacked_y):
+            return "streamed"
         return "packed"
+
+    def _streams(self, stacked_x: Any, stacked_y: Any) -> bool:
+        """Whether the packed rows are streamed: compiled for a TPU (the
+        kernel is not interpreted), rows of 32-bit words, a batch and its
+        indices that fit the kernel's VMEM and SMEM, and a station's table,
+        its rows padded to whole lane tiles, that moves at HBM's bandwidth
+        in less time than the gather takes for the batch's indices."""
+        if self._platform != "tpu" or jnp.dtype(stacked_x.dtype).itemsize != 4:
+            return False
+        n_pad = stacked_x.shape[1]
+        # a packed row: a row of x and its label
+        words = math.prod(stacked_x.shape[2:]) + math.prod(stacked_y.shape[2:])
+        batch = self.spec.batch_size
+        if not SG.fits(batch, words, STREAM_BLOCK_ROWS):
+            return False
+        stream_ns = n_pad * 4 * SG.padded_width(words) / HBM_BYTES_PER_NS
+        return stream_ns < batch * PER_INDEX_NS
+
+    def _gather_attrs(self, stacked_x: Any, stacked_y: Any) -> dict[str, Any]:
+        """What the ``engine.call`` span says of the minibatch path: the
+        path, and where it is streamed the kernel's block of table rows and
+        the blocks a station's table is streamed in (host integers)."""
+        path = self.gather_path(stacked_x, stacked_y)
+        if path != "streamed":
+            return {"gather": path}
+        n_pad = stacked_x.shape[1]
+        rows = SG.kernel_rows(n_pad, STREAM_BLOCK_ROWS)
+        return {"gather": path, "gather_block_rows": rows,
+                "gather_blocks": -(-n_pad // rows)}
 
     def _minibatch_source(
         self, stacked_x: jax.Array, stacked_y: jax.Array
@@ -244,8 +293,11 @@ class FedAvg:
         n_pad, width + label_width]`` built here (once per dispatch: the
         callers stand outside the scan over rounds) and one gather, whose
         rows are split and viewed back. The values ``loss_fn`` receives are
-        bit for bit those of the two gathers."""
-        if self.gather_path(stacked_x, stacked_y) == "separate":
+        bit for bit those of the two gathers. Streamed: the same table, its
+        rows padded to whole lane tiles, and a step's rows fetched in table
+        order by `ops.stream_gather`: the same rows, reordered."""
+        path = self.gather_path(stacked_x, stacked_y)
+        if path == "separate":
             return (stacked_x, stacked_y), lambda x, y, idx: (
                 jnp.take(x, idx, axis=0), jnp.take(y, idx, axis=0)
             )
@@ -259,16 +311,30 @@ class FedAvg:
         carrier = jnp.dtype(f"uint{8 * x_dtype.itemsize}")
         s, n_pad = stacked_x.shape[:2]
         width, label_width = math.prod(x_row), math.prod(y_row)
+        # streamed, the kernel's rows are whole lane tiles: the pack writes
+        # them so, zeros after the label
+        pad = (SG.padded_width(width + label_width) - width - label_width
+               if path == "streamed" else 0)
         with jax.named_scope("pack_table"):
             table = jnp.concatenate([
                 jax.lax.bitcast_convert_type(a, carrier).reshape(s, n_pad, -1)
                 for a in (stacked_x, stacked_y)
-            ], axis=-1)
+            ] + ([jnp.zeros((s, n_pad, pad), carrier)] if pad else []),
+                axis=-1)
+        interpret = self._platform != "tpu"
 
         def take(rows: jax.Array, idx: jax.Array):
-            # idx < safe_count <= n_pad by construction: no row can be out
-            # of range, so the gather is told not to guard against it
-            batch = jnp.take(rows, idx, axis=0, mode="clip")
+            if path == "streamed":
+                # The batch is a multiset to the loss (unit weights, a mean
+                # over rows): fetched in table order, only the order of a
+                # float sum changes.
+                batch = SG.stream_gather(rows, jnp.sort(idx),
+                                         block_rows=STREAM_BLOCK_ROWS,
+                                         interpret=interpret)
+            else:
+                # idx < safe_count <= n_pad by construction: no row can be
+                # out of range, so the gather is told not to guard against it
+                batch = jnp.take(rows, idx, axis=0, mode="clip")
             if label_width == 1:
                 # the one label column read as a reduction over the row,
                 # a pass like the loss's own (0.7 ms a step on the v5e):
@@ -277,7 +343,7 @@ class FedAvg:
                 lane = jax.lax.broadcasted_iota(jnp.int32, batch.shape, 1)
                 by = jnp.max(jnp.where(lane == width, batch, 0), axis=1)
             else:
-                by = batch[:, width:]
+                by = batch[:, width:width + label_width]
             return (
                 jax.lax.bitcast_convert_type(batch[:, :width], x_dtype)
                 .reshape(-1, *x_row),
@@ -568,7 +634,7 @@ class FedAvg:
         ``runtime.learning.RoundHistory`` to arm convergence tracking and
         the anomalous-station watchdog rules."""
         with engine_call(
-            "fedavg.round", 1, gather=self.gather_path(stacked_x, stacked_y)
+            "fedavg.round", 1, **self._gather_attrs(stacked_x, stacked_y)
         ):
             if mask is None:
                 mask = jnp.ones_like(counts)
@@ -682,7 +748,7 @@ class FedAvg:
         """
         with engine_call(
             "fedavg.run_rounds", n_rounds,
-            gather=self.gather_path(stacked_x, stacked_y),
+            **self._gather_attrs(stacked_x, stacked_y),
         ):
             if mask is None:
                 mask = jnp.ones_like(counts)
@@ -728,7 +794,7 @@ class FedAvg:
         spec.validate()
         with engine_call(
             "fedavg.run_rounds_async", n_rounds,
-            gather=self.gather_path(stacked_x, stacked_y),
+            **self._gather_attrs(stacked_x, stacked_y),
         ):
             if mask is None:
                 mask = jnp.ones_like(counts)
