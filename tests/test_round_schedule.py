@@ -10,8 +10,9 @@ leave all of them to the end of the backward pass (PERF.md section 6, PR 36);
 what stops it is `collectives.RingExchange`, and this is what guards that.
 
 The file holds the repository's other compiles for the described chip
-too (the attention kernels, PR 38; the streamed gather, PR 40), so that one
-worker of a test run loads the TPU's library.
+too (the attention kernels, the streamed gather and the sparse
+attention's indexer scores), so that one worker of a test run loads the
+TPU's library.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process may hold the TPU's library, and every worker of
@@ -253,4 +254,26 @@ def test_the_engine_cell_streams_its_table_on_the_chip(topo, uncached):
         t, jnp.sort(i), block_rows=STREAM_BLOCK_ROWS))
     ).lower(table, idx).compile().as_text()
     assert sum("custom-call" in line and "stream_gather" in line
+               for line in text.splitlines()) == 1
+
+
+# ---------------------------------------- the indexer's scores (sparse attention)
+@pytest.mark.parametrize("rows", [128, 512])
+def test_the_indexer_scores_kernel_compiles_at_the_cells_shapes(
+        rows, topo, uncached):
+    """The lightning indexer's scores at `keye.sparse16k-1chip`'s widths (16
+    heads of 64, 16,384 keys), for a block of `select`'s rows (128) and of
+    the loss walk's (512): Mosaic takes the tiles and a step fits its VMEM
+    limit, and the stations' `vmap` is a grid axis (one custom call)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from vantage6_tpu.ops.sparse_attention import _block_scores
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    q, k, w = (jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+               for shape in ((2, 1, rows, 16, 64), (2, 1, 16384, 64),
+                             (2, 1, rows, 16)))
+    text = jax.jit(jax.vmap(lambda q, k, w: _block_scores(
+        q, k, w, jnp.int32(rows), False))).lower(q, k, w).compile().as_text()
+    assert sum("custom-call" in line and "indexer_scores" in line
                for line in text.splitlines()) == 1

@@ -69,6 +69,22 @@ def _transformer_experts():
     return engine, (params, opt_state, tokens, jnp.ones(4))
 
 
+def _transformer_sparse():
+    """Learned sparse attention (an indexer of two heads keeping 4 keys a
+    query) beside SwiGLU experts routed on the normed stream."""
+    cfg = FT.TransformerConfig(
+        vocab=97, d_model=32, n_heads=4, n_layers=2, max_len=16,
+        attention="recompute", flash_interpret=True, remat=True,
+        norm="rmsnorm", n_kv_heads=2, positions="rotary", qk_norm=True,
+        ffn="experts", router_input="normed", expert_act="silu",
+        n_experts=4, top_k=2, d_expert=16, experts_held=(2, 3),
+        tie_head=False, sparse_top_k=4, indexer_heads=2, indexer_dim=8)
+    engine = FT.make_engine(4, 1, cfg, devices=jax.devices()[:1])
+    params, opt_state = engine.init(jax.random.key(0))
+    tokens = engine.shard_tokens(FT.make_federated_tokens(4, 2, 16, 97))
+    return engine, (params, opt_state, tokens, jnp.ones(4))
+
+
 def _transformer_looped(slots=1):
     """A stack of two layers walked three times over the same weights, with
     the exit gate, norms after each half too and the gated MLP."""
@@ -132,6 +148,10 @@ PROGRAMS = {
     "transformer-looped": (
         _transformer_looped,
         TRANSFORMER_SCOPES | {"loop", "exit_gate", "rotary"}),
+    "transformer-sparse": (
+        _transformer_sparse,
+        TRANSFORMER_SCOPES - {"mlp"} | {"router", "experts", "rotary",
+                                        "indexer", "select", "indexer_loss"}),
     "fedavg-fused": (lambda: _fedavg(), FEDAVG_SCOPES),
     "fedavg-streamed": (lambda: _fedavg(streamed=True), FEDAVG_SCOPES),
     "fedavg-compressed-zero1": (

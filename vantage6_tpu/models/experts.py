@@ -45,7 +45,9 @@ Assumed, where SmallThinker's config does not say (stated in
 perfbench/configs/smallthinker-21b-ep8-2st.json too): the router's product,
 softmax and choice run in float32 at ``Precision.HIGHEST`` so that a bf16
 rounding of the product rarely flips a choice; the expert is ReGLU,
-``W_down (relu(W_gate h) * (W_up h))``.
+``W_down (relu(W_gate h) * (W_up h))``. A caller that names another gate
+(``activation="silu"``: SwiGLU, Keye-VL-2.0's) gets ``W_down (silu(W_gate
+h) * (W_up h))`` through the same walk.
 """
 from __future__ import annotations
 
@@ -61,6 +63,7 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as _gmm, tgmm as _t
 
 ROW_TILE = 256  # rows of one grouped-product tile (the m tile)
 TOKEN_CHUNK = 2048  # tokens whose assignments share one row buffer
+GATES = {"relu": jax.nn.relu, "silu": jax.nn.silu}  # the experts' gates
 
 
 def route(
@@ -226,7 +229,7 @@ class _Walk:
         return lax.dynamic_update_slice_in_dim(x, rows.astype(x.dtype), lo, 0)
 
 
-def _products(h, walk, w, interpret):
+def _products(h, walk, w, interpret, activation):
     """A chunk's forward up to the experts' results in sorted order:
     ``rows`` [m_rows, d] (the tokens of the assignments), ``gate`` and ``up``
     [m_rows, f] float32, ``mid`` and ``out`` in ``h``'s dtype. Only the
@@ -244,17 +247,17 @@ def _products(h, walk, w, interpret):
     def act(b, mid):
         lo, _, _, carries = walk.at(b)
         return walk.put(mid, jnp.where(
-            carries, jax.nn.relu(walk.rows(gate, lo)) * walk.rows(up, lo), 0),
-            lo)
+            carries, GATES[activation](walk.rows(gate, lo))
+            * walk.rows(up, lo), 0), lo)
 
     mid = walk.each(act, jnp.zeros((m_rows, gate.shape[1]), h.dtype))
     out = _product(mid, w["w_down"], walk.sizes, h.dtype, interpret)
     return rows, gate, up, mid, out
 
 
-def _chunk_forward(h, walk, weights, w, interpret):
+def _chunk_forward(h, walk, weights, w, interpret, activation):
     """``y`` [n, d] and the chunk's `_products`."""
-    kept = _products(h, walk, w, interpret)
+    kept = _products(h, walk, w, interpret, activation)
     out = kept[-1]
     slots = weights.reshape(-1)  # float32: a weight is not rounded
 
@@ -267,11 +270,13 @@ def _chunk_forward(h, walk, weights, w, interpret):
     return y.astype(h.dtype), kept
 
 
-def _chunk_backward(h, walk, weights, w, kept, d_w, dy, interpret):
+def _chunk_backward(h, walk, weights, w, kept, d_w, dy, interpret,
+                    activation):
     """The chunk's cotangents from ``dy`` [n, d]: ``dh``, ``d_weights``, and
     the three matrices' added to ``d_w`` (float32). ``kept``: the chunk's
     `_products`, or None and they are computed again."""
-    rows, gate, up, mid, out = kept or _products(h, walk, w, interpret)
+    rows, gate, up, mid, out = kept or _products(
+        h, walk, w, interpret, activation)
     slots = weights.reshape(-1)
 
     def to_lhs(grad, w):  # a product's cotangent to its rows
@@ -298,10 +303,15 @@ def _chunk_backward(h, walk, weights, w, kept, d_w, dy, interpret):
         lo, _, _, carries = walk.at(b)
         g = walk.rows(gate, lo)
         d = walk.rows(d_mid, lo).astype(jnp.float32)
-        return (
-            walk.put(d_gate, jnp.where(
-                carries & (g > 0), d * walk.rows(up, lo), 0), lo),
-            walk.put(d_up, jnp.where(carries, d * jax.nn.relu(g), 0), lo))
+        if activation == "relu":
+            d_g = jnp.where(carries & (g > 0), d * walk.rows(up, lo), 0)
+        else:  # silu'(g) = sigmoid(g) (1 + g (1 - sigmoid(g)))
+            sig = jax.nn.sigmoid(g)
+            d_g = jnp.where(
+                carries, d * walk.rows(up, lo) * sig * (1 + g * (1 - sig)), 0)
+        return (walk.put(d_gate, d_g, lo),
+                walk.put(d_up, jnp.where(
+                    carries, d * GATES[activation](g), 0), lo))
 
     d_gate, d_up = walk.each(
         act_t, (jnp.zeros_like(mid), jnp.zeros_like(mid)))
@@ -334,6 +344,7 @@ def expert_layer(
     n_experts: int,
     interpret: bool = False,
     token_chunk: int = TOKEN_CHUNK,
+    activation: str = "relu",
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """This chip's part of the expert layer: for every token the weighted
     sum over its chosen experts that are ``held``. Returns ``y`` [N, d] and
@@ -349,14 +360,16 @@ def expert_layer(
     the worst-case row buffer that is live, and everything of its size, is
     one chunk's (``token_chunk * top_k`` rows) whatever the batch: the
     layer brings its own recomputation, and a caller that recomputes its
-    layers leaves this one out of that."""
-    part = _held_part(tuple(held), n_experts, interpret, token_chunk)
+    layers leaves this one out of that. ``activation``: the gate, one of
+    `GATES`."""
+    part = _held_part(tuple(held), n_experts, interpret, token_chunk,
+                      activation)
     return part(h, choice, weights,
                 {name: params[name] for name in ("w_gate", "w_up", "w_down")})
 
 
 @functools.lru_cache(maxsize=None)
-def _held_part(held, n_experts, interpret, token_chunk):
+def _held_part(held, n_experts, interpret, token_chunk, activation):
     """`expert_layer` for one static configuration: a `custom_vjp` whose
     forward and backward each walk one station at a time (`_one_at_a_time`),
     one chunk after another, and within a chunk the row blocks that carry
@@ -385,7 +398,8 @@ def _held_part(held, n_experts, interpret, token_chunk):
         def chunk(c):
             h, local, weights = c
             walk = _Walk.sort(local, n_held)
-            y, kept = _chunk_forward(h, walk, weights, w, interpret)
+            y, kept = _chunk_forward(h, walk, weights, w, interpret,
+                                     activation)
             return y, walk, kept
 
         if chunks(h).shape[0] == 1:
@@ -409,7 +423,7 @@ def _held_part(held, n_experts, interpret, token_chunk):
         def chunk(d_w, c):
             h, weights, walk, dy = c
             dh, d_weights, d_w = _chunk_backward(
-                h, walk, weights, w, kept, d_w, dy, interpret)
+                h, walk, weights, w, kept, d_w, dy, interpret, activation)
             return d_w, (dh, d_weights)
 
         d_w, (dh, d_weights) = lax.scan(

@@ -406,10 +406,13 @@ def _tiles(q, k, v, block_q, block_k):
 
 
 def _tiled_forward(q, k, v, q_offset, k_offset, *, causal, scale, window,
-                   block_q, block_k):
+                   block_q, block_k, keep=None):
     """Online-softmax forward over (query block, visible key block) tiles.
     Returns ``o`` [B, Hq, Tq, D] and the row statistics ``L = m + log l``
-    [B, Hq, Tq] (f32), which the backward reads instead of a first pass."""
+    [B, Hq, Tq] (f32), which the backward reads instead of a first pass.
+    ``keep``, where given, masks every tile further: ``keep(i, j)`` is the
+    [B, bq, bk] bool of the pairs of query block ``i`` and key block ``j``
+    that are attended (`ops/sparse_attention.py`)."""
     b, h_q, t_q, d = q.shape
     h_kv, t_k = k.shape[1], k.shape[2]
     g = h_q // h_kv
@@ -429,6 +432,8 @@ def _tiled_forward(q, k, v, q_offset, k_offset, *, causal, scale, window,
             s = _tile_scores(q_i, k_j, q_pos, j * block_k
                              + jnp.arange(block_k), k_off, t_k, causal,
                              window, scale)
+            if keep is not None:
+                s = jnp.where(keep(i, j)[:, None, None], s, NEG_INF)
             m_new = jnp.maximum(m, jnp.maximum(jnp.max(s, -1), -1e20))
             corr = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new[..., None])
@@ -486,12 +491,12 @@ def _add_at(axis: int):
 
 
 def _tiled_bwd(q, k, v, o, big_l, do, q_offset, k_offset, *, causal, scale,
-               window, block_q, block_k):
+               window, block_q, block_k, keep=None):
     """The softmax-attention VJP over the same tiles: for each query block
     the visible key blocks only, ``P = exp(S - L)`` recomputed per tile,
     ``dK``/``dV`` accumulated in place in f32 (`_add_at`; a kv head sums
     over its group of query heads inside the product), products in the
-    inputs' dtype with f32 accumulation."""
+    inputs' dtype with f32 accumulation; ``keep``: `_tiled_forward`'s."""
     b, h_q, t_q, d = q.shape
     h_kv, t_k = k.shape[1], k.shape[2]
     g = h_q // h_kv
@@ -523,6 +528,8 @@ def _tiled_bwd(q, k, v, o, big_l, do, q_offset, k_offset, *, causal, scale,
             s = _tile_scores(q_i, k_j, q_pos, j * block_k
                              + jnp.arange(block_k), k_off, t_k, causal,
                              window, scale)
+            if keep is not None:
+                s = jnp.where(keep(i, j)[:, None, None], s, NEG_INF)
             p = jnp.exp(s - l_i[..., None])
             dv_j = jnp.einsum(
                 "bhgqk,bhgqd->bhkd", p.astype(do_i.dtype), do_i,

@@ -98,9 +98,13 @@ __all__ = [
 # rotary, attn_out (the output product, the norm after it, the residual add)
 # and, around every norm, norms: these four only ever nest inside the scopes
 # above or sit beside them, never around one, so what those read stays put.
+# With learned sparse attention (ops/sparse_attention.py) the block opens
+# indexer (its projections and scores), select (each query's kept keys) and
+# indexer_loss beside attention.
 DEVICE_SCOPES = (
     "pack_table", "local_train", "gather", "loss_grad", "embed", "loop",
     "attention", "mlp", "router", "experts", "lm_head_loss", "exit_gate",
+    "indexer", "select", "indexer_loss",
     "qkv", "rotary", "attn_out", "norms",
     "compress", "learning_stats", "aggregate", "server_update",
 )

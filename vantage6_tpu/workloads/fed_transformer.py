@@ -29,6 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from vantage6_tpu.core.mesh import STATION_AXIS, _largest_divisor_leq
 from vantage6_tpu.fed import collectives
 from vantage6_tpu.models import experts
+from vantage6_tpu.ops import sparse_attention as sparse
 from vantage6_tpu.ops.flash_attention import (
     attention_tile,
     flash_attention,
@@ -122,6 +123,26 @@ class TransformerConfig:
     # times that distribution's entropy (`_exit_loss`)
     loops: int = 1
     exit_beta: float = 0.0
+    # per-head RMSNorm of q and of k over head_dim, before rotary (learned
+    # `q_norm` and `k_norm`, float32)
+    qk_norm: bool = False
+    # what the experts' router reads: "block", the block's input before
+    # attention; "normed", the normed stream after attention that the
+    # experts read too (`norm2`'s output)
+    router_input: str = "block"
+    # the experts' gate: "relu" (ReGLU) or "silu" (SwiGLU)
+    expert_act: str = "relu"
+    # learned sparse attention (ops/sparse_attention.py): with a
+    # `sparse_top_k`, a lightning indexer of `indexer_heads` heads of
+    # `indexer_dim` over one key head scores every (query, key) pair from
+    # the normed stream taken as a constant, each query attends to the
+    # `sparse_top_k` keys it scores highest, and the loss adds, per layer,
+    # the indexer's KL to the attention's head-averaged probabilities over
+    # those keys, which trains the indexer alone. Per layer `idx_q`,
+    # `idx_k`, `idx_w` and the key's LayerNorm scale `idx_norm`, float32
+    sparse_top_k: int = 0
+    indexer_heads: int = 0
+    indexer_dim: int = 0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -152,6 +173,19 @@ class TransformerConfig:
             raise ValueError(
                 "a block with experts is walked once (its counts are kept "
                 "per layer, not per walk) and takes no norm after its halves")
+        if self.router_input not in ("block", "normed"):
+            raise ValueError(f"no such router input: {self.router_input!r}")
+        if self.expert_act not in ("relu", "silu"):
+            raise ValueError(f"no such expert gate: {self.expert_act!r}")
+        if self.sparse_top_k and not (
+                self.indexer_heads and self.indexer_dim
+                and self.indexer_dim % 4 == 0 and self.positions == "rotary"
+                and self.attention == "recompute" and self.window is None
+                and self.loops == 1):
+            raise ValueError(
+                "sparse attention needs indexer_heads and an indexer_dim of "
+                "whole rotary pairs, rotary positions, attention='recompute', "
+                "no window and one walk of the stack")
         if self.n_heads % self.n_kv_heads:
             raise ValueError(
                 f"{self.n_heads} query heads do not divide over "
@@ -202,6 +236,9 @@ def _layer_shapes(cfg: TransformerConfig) -> dict[str, tuple[int, ...]]:
         e, f = len(cfg.experts_held), cfg.d_expert
         shapes.update(router=(d, cfg.n_experts), w_gate=(e, d, f),
                       w_up=(e, d, f), w_down=(e, f, d))
+    if cfg.sparse_top_k:
+        shapes.update(idx_q=(d, cfg.indexer_heads * cfg.indexer_dim),
+                      idx_k=(d, cfg.indexer_dim), idx_w=(d, cfg.indexer_heads))
     return shapes
 
 
@@ -213,8 +250,10 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict[str, Any]:
     ``qkv`` (the q, k and v projections side by side), ``proj``, then
     ``w_up``/``w_down`` (mlp), ``w_gate``/``w_up``/``w_down`` (swiglu) or
     ``router``/``w_gate``/``w_up``/``w_down`` (experts, the held ones
-    stacked), and with rmsnorm ``norm1``/``norm2`` (and
-    ``norm1_post``/``norm2_post`` where a norm follows each half too)."""
+    stacked), ``idx_q``/``idx_k``/``idx_w`` (sparse attention's indexer),
+    and with rmsnorm ``norm1``/``norm2`` (and ``norm1_post``/``norm2_post``
+    where a norm follows each half too); ``q_norm``/``k_norm`` [head_dim]
+    with the per-head norm; ``idx_norm`` [indexer_dim] with the indexer."""
     shapes = _layer_shapes(cfg)
     n = len(shapes)
     keys = jax.random.split(key, 2 + n * cfg.n_layers)
@@ -244,6 +283,11 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict[str, Any]:
                  for j, (name, shape) in enumerate(shapes.items())}
         if cfg.norm == "rmsnorm":
             layer.update({name: jnp.ones((cfg.d_model,)) for name in scales})
+        if cfg.qk_norm:
+            layer.update(q_norm=jnp.ones((cfg.head_dim,)),
+                         k_norm=jnp.ones((cfg.head_dim,)))
+        if cfg.sparse_top_k:
+            layer["idx_norm"] = jnp.ones((cfg.indexer_dim,))
         params["layers"].append(layer)
     return params
 
@@ -285,6 +329,34 @@ def _rotate(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
         ).astype(x.dtype)
 
 
+def _indexer(h: jax.Array, layer: dict[str, Any], positions: jax.Array,
+             cfg: TransformerConfig):
+    """The lightning indexer's queries [B, T, H_I, D_I], key [B, T, D_I] and
+    head weights [B, T, H_I] from the normed stream ``h``, float32 at
+    ``HIGHEST``: the key through a LayerNorm with a learned scale, the first
+    half of every query and key width rotated (rotate-half, `rope_theta`),
+    the weights scaled by ``1/sqrt(H_I)``."""
+    b, t, _ = h.shape
+    hf = h.astype(jnp.float32)
+
+    def project(name):
+        return jnp.matmul(hf, layer[name], precision=lax.Precision.HIGHEST)
+
+    def rotate_half_width(x):  # [B, T, H, D_I]
+        half = x.shape[-1] // 2
+        return jnp.concatenate(
+            [_rotate(x[..., :half], positions, cfg.rope_theta), x[..., half:]],
+            axis=-1)
+
+    q = project("idx_q").reshape(b, t, cfg.indexer_heads, cfg.indexer_dim)
+    k = project("idx_k")
+    mu = jnp.mean(k, -1, keepdims=True)
+    var = jnp.var(k, -1, keepdims=True)
+    k = (k - mu) * lax.rsqrt(var + cfg.norm_eps) * layer["idx_norm"]
+    w = project("idx_w") * (1.0 / np.sqrt(cfg.indexer_heads))
+    return rotate_half_width(q), rotate_half_width(k[:, :, None])[:, :, 0], w
+
+
 def _head(params: dict[str, Any], cfg: TransformerConfig) -> jax.Array:
     """The output head [d_model, vocab] in the compute dtype."""
     return (params["embed"].astype(cfg.dtype).T if cfg.tie_head
@@ -297,13 +369,16 @@ def _forward(
     cfg: TransformerConfig,
     axis_name: str,
     exchange: collectives.RingExchange | None = None,
-) -> tuple[list[jax.Array], list[Any]]:
+) -> tuple[list[jax.Array], list[Any], list[Any]]:
     """The stream after the final norm, once for every walk of the stack
-    (the head reads these: `forward_local`, `_loss_and_load`), and the
-    expert layers' load, one entry a layer: the assignments each held expert
+    (the head reads these: `forward_local`, `_loss_and_load`), the expert
+    layers' load, one entry a layer: the assignments each held expert
     received and the choices that named one (none for a block without
-    experts). With an ``exchange`` the stream and each group of layers
-    (`_layer_groups`) pass through it on their way into the group: nothing
+    experts), and with sparse attention, one entry a layer, the indexer's
+    loss summed over the positions and the count of the attention walk's
+    tiles that held a kept pair (none without). With an ``exchange`` the
+    stream and each group of layers (`_layer_groups`) pass through it on
+    their way into the group: nothing
     on the way forward, the cross-station mean of the group's gradient on
     the way back (`FedTransformer._round` on several slots)."""
     b, t_local = tokens_local.shape
@@ -323,12 +398,13 @@ def _forward(
     def layer_block(x, layer, window, rotates):
         # the router's matrix and the norms' scales stay float32
         kept = {name: layer[name] for name in (
-            "router", "norm1", "norm2", "norm1_post", "norm2_post")
+            "router", "norm1", "norm2", "norm1_post", "norm2_post",
+            "q_norm", "k_norm", "idx_q", "idx_k", "idx_w", "idx_norm")
             if name in layer}
         layer = jax.tree.map(
             cast, {k: v for k, v in layer.items() if k not in kept})
         routing = None
-        if cfg.ffn == "experts":
+        if cfg.ffn == "experts" and cfg.router_input == "block":
             with jax.named_scope("router"):
                 # before attention, on the block's input
                 routing = experts.route(
@@ -341,11 +417,17 @@ def _forward(
             q = q.reshape(b, t_local, cfg.n_heads, cfg.head_dim)
             k = k.reshape(b, t_local, cfg.n_kv_heads, cfg.head_dim)
             v = v.reshape(b, t_local, cfg.n_kv_heads, cfg.head_dim)
+        if cfg.qk_norm:
+            q = _norm(q, kept["q_norm"], cfg)
+            k = _norm(k, kept["k_norm"], cfg)
         if rotates:
             positions = offset + jnp.arange(t_local)
             q = _rotate(q, positions, cfg.rope_theta)
             k = _rotate(k, positions, cfg.rope_theta)
-        if cfg.attention in ("flash", "recompute"):
+        terms = None
+        if cfg.sparse_top_k:
+            attn, terms = sparse_half(h, q, k, v, kept)
+        elif cfg.attention in ("flash", "recompute"):
             # both want head-major [B, H, T, D]; offsets keep the causal
             # mask correct for any sequence shard (here the full sequence —
             # make_engine enforces seq_devices == 1 for these modes)
@@ -376,8 +458,11 @@ def _forward(
             if cfg.norm_after:
                 attn = _norm(attn, kept.get("norm1_post"), cfg)
             x = x + attn
+        # beside the stream: the router's choice where it reads the block's
+        # input (the expert layer follows: `expert_half`), and sparse
+        # attention's terms
         if cfg.ffn == "experts":
-            return x, routing  # the expert layer follows: `expert_half`
+            return x, routing, terms
         with jax.named_scope("mlp"):
             h = _norm(x, kept.get("norm2"), cfg)
             if cfg.ffn == "mlp":
@@ -387,18 +472,49 @@ def _forward(
                      * (h @ layer["w_up"])) @ layer["w_down"]
             if cfg.norm_after:
                 y = _norm(y, kept.get("norm2_post"), cfg)
-            return x + y, None
+            return x + y, routing, terms
+
+    def sparse_half(h, q, k, v, kept):
+        """Attention over each query's kept keys (ops/sparse_attention.py):
+        the indexer reads the normed stream as a constant; returns the
+        attention [B, T, Hq, D] and, per layer, the indexer's loss summed
+        over the positions and the count of tiles that held a kept pair."""
+        positions = offset + jnp.arange(t_local)
+        with jax.named_scope("indexer"):
+            q_idx, k_idx, w = _indexer(lax.stop_gradient(h), kept, positions,
+                                       cfg)
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+        walk = sparse.blocks(t_local, cfg.head_dim,
+                             cfg.n_heads // cfg.n_kv_heads, cfg.dtype)
+        keep, log_norm, tiles = sparse.select(
+            q_idx, k_idx, w, cfg.sparse_top_k, walk.block_q, walk.block_k,
+            cfg.flash_interpret)
+        with jax.named_scope("attention"):
+            attn, big_l = sparse.attend(q, k, v, keep, offset, offset,
+                                        walk.block_q, walk.block_k)
+        with jax.named_scope("indexer_loss"):
+            loss = sparse.indexer_loss(q, k, big_l, keep, log_norm, q_idx,
+                                       k_idx, w, walk.block_q, walk.block_k,
+                                       cfg.flash_interpret)
+        return attn.transpose(0, 2, 1, 3), (loss, tiles)
 
     def expert_half(x, layer, routing):
         """Outside the layer's checkpoint: the expert layer recomputes its
         own chunks (models/experts.py), and inside another recomputation it
-        would run forward three times."""
+        would run forward three times. With the router on the normed stream
+        it chooses here."""
         with jax.named_scope("experts"):
             h = _norm(x, layer.get("norm2"), cfg)
+        if routing is None:
+            with jax.named_scope("router"):
+                routing = experts.route(
+                    h.reshape(b * t_local, cfg.d_model), layer["router"],
+                    cfg.top_k)
+        with jax.named_scope("experts"):
             y, load = experts.expert_layer(
                 h.reshape(b * t_local, cfg.d_model), *routing, layer,
                 cfg.experts_held, cfg.n_experts,
-                interpret=cfg.flash_interpret)
+                interpret=cfg.flash_interpret, activation=cfg.expert_act)
             return x + y.reshape(x.shape), load
 
     # one traced block per kind of layer: the layers of a kind after the
@@ -412,7 +528,7 @@ def _forward(
         else layer_block, static_argnums=kind)
 
     def stack(x):
-        loads = []
+        loads, terms = [], []
         layers = params["layers"]
         if exchange is not None:
             layers = list(layers)
@@ -422,17 +538,19 @@ def _forward(
                 x, layers[i:entered[i][-1] + 1] = exchange(
                     x, [layers[j] for j in entered[i]])
             layer = layers[i]
-            x, routing = block(
+            x, routing, sparse_terms = block(
                 x, layer, cfg.layer_window(i), cfg.layer_rotates(i))
+            if cfg.sparse_top_k:
+                terms.append(sparse_terms)
             if cfg.ffn == "experts":
                 x, load = expert_half(x, layer, routing)
                 loads.append(load)
-        return x, loads
+        return x, loads, terms
 
     if cfg.loops == 1:
-        x, loads = stack(x)
+        x, loads, terms = stack(x)
         with jax.named_scope("lm_head_loss"):
-            return [_norm(x, params.get("final_norm"), cfg)], loads
+            return [_norm(x, params.get("final_norm"), cfg)], loads, terms
     # an unrolled walk, not a `lax.scan` over the walks: read on the chip
     # (PERF.md section 6, PR 35) the scan compiles in half the time to a
     # third of the code and its round is 2.6% slower
@@ -441,7 +559,7 @@ def _forward(
         for _ in range(cfg.loops):  # the same layers, the same positions
             x = _norm(stack(x)[0], params.get("final_norm"), cfg)
             states.append(x)
-    return states, []
+    return states, [], []
 
 
 def forward_local(
@@ -453,7 +571,7 @@ def forward_local(
     """Logits [B, T_local, V] for this shard (after the last walk, where the
     stack is walked more than once); attention spans the FULL sequence via
     the ring."""
-    states, _ = _forward(params, tokens_local, cfg, axis_name)
+    states = _forward(params, tokens_local, cfg, axis_name)[0]
     with jax.named_scope("lm_head_loss"):
         return states[-1] @ _head(params, cfg)
 
@@ -463,6 +581,11 @@ def forward_local(
 # fastest round (810 ms; 813 at 256, 822 at 1,024 and whole, 875 at 128),
 # and whole, a walk's logits made the round's temporaries 10.6 GB for 4.6
 HEAD_CHUNK = 512
+# a sequence's float32 logits above this many bytes are taken `HEAD_CHUNK`
+# positions at a time in a walk of the stack once too (`_loss_and_load`):
+# whole, [1, 16384] x 18,992 held 1.2 GB and its cotangent as much again
+# beside the activations of a 16k-token round
+HEAD_LOGITS_BYTES = 2**30
 
 
 def _token_nll(h: jax.Array, head: jax.Array, targets: jax.Array):
@@ -520,15 +643,27 @@ def _exit_loss(states, params, tokens_local, cfg):
 
 def _loss_and_load(params, tokens_local, cfg, axis_name, exchange=None):
     """The loss, and what a round leaves on the device beside it: the expert
-    layers' load stacked over the layers, and the exit distribution summed
-    over the predicted positions [R]; each ``None`` where the block has no
-    such thing. ``exchange``: `_forward`'s."""
-    states, loads = _forward(params, tokens_local, cfg, axis_name, exchange)
-    load = exits = None
+    layers' load stacked over the layers, the exit distribution summed over
+    the predicted positions [R], and the count of sparse attention's tiles
+    that held a kept pair [L]; each ``None`` where the block has no such
+    thing. With sparse attention the loss adds every layer's indexer loss,
+    a mean over the positions. ``exchange``: `_forward`'s."""
+    states, loads, terms = _forward(
+        params, tokens_local, cfg, axis_name, exchange)
+    load = exits = tiles = None
+    b, t_local = tokens_local.shape
     if cfg.loops > 1:
         local_sum, exits = _exit_loss(states, params, tokens_local, cfg)
-        b, t_local = tokens_local.shape
         local_cnt = jnp.asarray(b * (t_local - 1), jnp.float32)
+    elif b * t_local * cfg.vocab * 4 > HEAD_LOGITS_BYTES:
+        if loads:
+            load = jax.tree.map(lambda *xs: jnp.stack(xs), *loads)
+        with jax.named_scope("lm_head_loss"):
+            # the last position predicts nothing: computed with the rest
+            nll = _token_nll(states[0], _head(params, cfg),
+                             jnp.roll(tokens_local, -1, axis=1))[:, :-1]
+            local_sum = jnp.sum(nll)
+            local_cnt = jnp.asarray(nll.size, jnp.float32)
     else:
         with jax.named_scope("lm_head_loss"):
             logits = states[0] @ _head(params, cfg)
@@ -543,7 +678,12 @@ def _loss_and_load(params, tokens_local, cfg, axis_name, exchange=None):
             local_cnt = jnp.asarray(nll.size, jnp.float32)
     total = lax.psum(local_sum, axis_name)
     count = lax.psum(local_cnt, axis_name)
-    return total / count, (load, exits)
+    if not terms:
+        return total / count, (load, exits, tiles)
+    indexer_sum, tiles = (jnp.stack(x) for x in zip(*terms))
+    positions = lax.psum(jnp.asarray(b * t_local, jnp.float32), axis_name)
+    indexer = lax.psum(jnp.sum(indexer_sum), axis_name) / positions
+    return total / count + indexer, (load, exits, tiles)
 
 
 def loss_local(
@@ -593,6 +733,11 @@ class FedTransformer:
     _aggregation: dict | None = dataclasses.field(default=None, repr=False)
     # the shape of the tokens whose load `_expert_load` holds (`row_walk`)
     _load_shape: tuple | None = dataclasses.field(default=None, repr=False)
+    # sparse attention's counts of tiles that held a kept pair, still on
+    # the device (`record_sparse_tiles`), and the shape of their tokens
+    _sparse_tiles: collections.deque = dataclasses.field(
+        default_factory=lambda: collections.deque(maxlen=4096), repr=False)
+    _tiles_shape: tuple | None = dataclasses.field(default=None, repr=False)
 
     def init(self, key: jax.Array) -> tuple[Any, Any]:
         # params AND the whole optimizer state are committed to the mesh:
@@ -636,7 +781,8 @@ class FedTransformer:
         leaves handed over, ``n_donated`` = those of them the program may
         write its outputs into). With ``attention="recompute"`` the
         ``engine.call`` span also carries the walk the program was built
-        with (`attention_walk`), always how the cross-station mean is taken
+        with (`attention_walk`; with sparse attention ``sparse_topk`` and
+        ``indexer_heads`` too), always how the cross-station mean is taken
         (`aggregation`), and where the stack is walked more than once,
         ``loops`` and ``layer_applications``."""
         attrs = {**self.attention_walk(tokens.shape[-1]),
@@ -648,19 +794,23 @@ class FedTransformer:
             n_donated = len(jax.tree.leaves((params, opt_state)))
             n_buffers = n_donated + len(jax.tree.leaves((tokens, mask)))
             with device_launch("fed_transformer.round", n_buffers, n_donated):
-                *out, load, exits = self._round(
+                *out, load, exits, tiles = self._round(
                     params, opt_state, tokens, mask)
         if load is not None:  # stays on the device: record_expert_load
             self._expert_load.append(load)
             self._load_shape = tokens.shape
         if exits is not None:  # likewise: record_exit_distribution
             self._exits.append(exits)
+        if tiles is not None:  # likewise: record_sparse_tiles
+            self._sparse_tiles.append(tiles)
+            self._tiles_shape = tokens.shape
         return tuple(out)
 
     def attention_walk(self, t: int) -> dict[str, Any]:
         """What says how `recompute_attention` walks visible tiles at sequence
         length ``t``: ``attention_path`` (``"kernel"``: inside the Pallas
-        kernels; ``"walk"``: in XLA), ``attention_tile``
+        kernels; ``"walk"``: in XLA; ``"sparse"``: the XLA walk over each
+        query's kept keys, ops/sparse_attention.py), ``attention_tile``
         (``"<block_q>x<block_k>"``, the blocks that path really uses: both
         `attention_tile`'s) and, summed over the layer applications
         of one sequence and head (every layer once a walk of the stack),
@@ -674,6 +824,13 @@ class FedTransformer:
             path, *tile = attention_tile(
                 t, t, cfg.head_dim, cfg.n_heads // cfg.n_kv_heads, cfg.dtype,
                 cfg.flash_interpret)
+            extra = {}
+            if cfg.sparse_top_k:
+                path, *tile = sparse.blocks(
+                    t, cfg.head_dim, cfg.n_heads // cfg.n_kv_heads, cfg.dtype)
+                path = "sparse"
+                extra = {"sparse_topk": cfg.sparse_top_k,
+                         "indexer_heads": cfg.indexer_heads}
             kinds = collections.Counter(
                 cfg.layer_window(i) for i in range(cfg.n_layers))
             counts = cfg.loops * sum(
@@ -683,7 +840,7 @@ class FedTransformer:
                 "attention_path": path,
                 "attention_tile": "{}x{}".format(*tile),
                 "attention_tiles_visited": int(counts[0]),
-                "attention_tiles": int(counts[1])}
+                "attention_tiles": int(counts[1]), **extra}
         return self._walks[t]
 
     @property
@@ -774,6 +931,37 @@ class FedTransformer:
             pass
         return attrs
 
+    def record_sparse_tiles(self) -> dict[str, Any] | None:
+        """Read sparse attention's counts of the rounds since the last call
+        off the device and record them as ONE ``sparse.tiles`` span:
+        ``rounds``, ``tiles_selected_per_layer`` (the walk's tiles that held
+        a kept pair, summed over the sequences of a round, mean over the
+        rounds), ``tiles_visible_per_layer`` (the walk's visible tiles over
+        the same sequences: `attention_walk`), and ``selected_tile_share``,
+        the one over the other over all layers. Like `record_expert_load`:
+        call it OUTSIDE what is timed; None where there is nothing to record
+        (no sparse attention, no round since the last call)."""
+        pending = list(self._sparse_tiles)
+        self._sparse_tiles.clear()
+        if not pending:
+            return None
+        counts = np.stack(jax.device_get(pending)).astype(np.int64)  # [R, L]
+        stations, b, t = self._tiles_shape
+        walk = self.attention_walk(t)
+        visible = stations * b * walk["attention_tiles_visited"] // (
+            self.cfg.n_layers)
+        attrs = {
+            "rounds": len(pending),
+            "tiles_selected_per_layer": (
+                counts.sum(0) / len(pending)).tolist(),
+            "tiles_visible_per_layer": visible,
+            "selected_tile_share": float(
+                counts.sum() / (counts.size * visible)),
+        }
+        with TRACER.span("sparse.tiles", kind="engine", attrs=attrs):
+            pass
+        return attrs
+
     def record_exit_distribution(self) -> dict[str, Any] | None:
         """Read the exit distributions of the rounds since the last call off
         the device and record them as ONE ``exits.distribution`` span:
@@ -809,7 +997,7 @@ class FedTransformer:
     def _round(
         self, params: Any, opt_state: Any, tokens: jax.Array,
         mask: jax.Array,
-    ) -> tuple[Any, Any, jax.Array, Any, Any]:
+    ) -> tuple[Any, Any, jax.Array, Any, Any, Any]:
         rings = self._rings
         ring = collectives.station_ring(self.mesh.devices[:, 0])
 
@@ -822,13 +1010,13 @@ class FedTransformer:
                 exchange = collectives.RingExchange(
                     w_own, denom, STATION_AXIS, ring, PACKED_AXIS
                 ) if rings else None
-                (loss, (load, exits)), grads = jax.value_and_grad(
+                (loss, left), grads = jax.value_and_grad(
                     _loss_and_load, has_aux=True
                 )(params, tok, self.cfg, SEQ_AXIS, exchange)
                 # reduce over sequence shards WITHIN the station only
                 grads = lax.psum(grads, SEQ_AXIS)
                 loss = lax.pmean(loss, SEQ_AXIS)
-                return loss, grads, lax.psum((load, exits), SEQ_AXIS)
+                return loss, grads, lax.psum(left, SEQ_AXIS)
 
             with jax.named_scope("local_train"):
                 if not rings:
@@ -883,11 +1071,11 @@ class FedTransformer:
             )
             params = optax.apply_updates(params, updates)
         loss = collectives.fed_mean(losses, mask=mask)
-        # the expert layers' counts ([L, E_held] and [L]) or the exit
-        # distribution ([R]), summed over the stations; nothing for the
-        # plain block
-        load, exits = jax.tree.map(lambda x: jnp.sum(x, axis=0), left)
-        return params, opt_state, loss, load, exits
+        # the expert layers' counts ([L, E_held] and [L]), the exit
+        # distribution ([R]) or sparse attention's tiles ([L]), summed over
+        # the stations; nothing for the plain block
+        load, exits, tiles = jax.tree.map(lambda x: jnp.sum(x, axis=0), left)
+        return params, opt_state, loss, load, exits, tiles
 
 
 def make_engine(
