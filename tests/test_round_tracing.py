@@ -294,7 +294,8 @@ NO_RING = {"aggregate_overlap": "none", "aggregate_groups": 0,
 ENGINE_ATTRS = {"fed_transformer.round": {"attention_path": "walk",
                                           "attention_tile": "16x16",
                                           "attention_tiles_visited": 2,
-                                          "attention_tiles": 2, **NO_RING},
+                                          "attention_tiles": 2,
+                                          "remat_saved": [], **NO_RING},
                 "fedavg.run_rounds": {"gather": "packed"},
                 "fedavg.round": {"gather": "packed"}}
 
@@ -441,7 +442,7 @@ def test_a_looped_stack_says_its_walks_and_counts_tiles_over_applications(
         "attention_path": "walk", "attention_tile": "4x4",
         "attention_tiles_visited": 60,
         "attention_tiles": 96, "loops": 3, "layer_applications": 6,
-        **NO_RING}
+        "remat_saved": [], **NO_RING}
     TRACER.clear()
     engine, args = _transformer()
     engine.round(*args)

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -204,6 +205,58 @@ def test_each_loss_reaches_only_its_own_leaves(inputs):
                 assert not np.any(np.asarray(g)), name
             else:
                 assert np.any(np.asarray(g)), name
+
+
+def _selections(cfg, inputs) -> int:
+    """The selection's passes in the compiled gradient of the tiny loss
+    (`_program_parts`): the while loops of `_threshold`'s bit search, the
+    one loop under the `select` scope, once a pass."""
+    params, row = inputs["params"], inputs["tokens"][0, 0, :1]
+    parts = _program_parts(cfg)
+
+    def loss(p):
+        _, lm, indexer = _in_mesh(parts, cfg, p, row)
+        return lm + indexer
+
+    text = jax.jit(jax.grad(loss)).lower(params).compile().as_text()
+    names = re.findall(r' while\(.*op_name="([^"]*)"', text)
+    return sum(name.endswith("/select/while") for name in names)
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_the_selection_runs_once_a_layer(inputs, monkeypatch, remat):
+    """The layer's checkpoint keeps the selection (`FT.remat_saved`), so
+    the backward pass rebuilds neither the indexer's scores nor the top-k
+    search: one selection a layer, as without remat. The plain checkpoint
+    keeps nothing, and its backward pass selects again."""
+    cfg = _config(remat=remat)
+    assert FT.remat_saved(cfg) == (FT.SELECTION if remat else ())
+    assert _selections(cfg, inputs) == cfg.n_layers
+    if remat:
+        monkeypatch.setattr(FT, "remat_saved", lambda cfg: ())
+        assert _selections(cfg, inputs) == 2 * cfg.n_layers
+
+
+def test_the_kept_selection_gives_the_plain_checkpoints_bits(
+        inputs, monkeypatch):
+    """The selection kept across the checkpoint is the one the plain
+    checkpoint rebuilds, bit for bit: the tiny round's loss and every leaf
+    of its gradient are the plain checkpoint's exactly."""
+    cfg = _config()
+    params, row = inputs["params"], inputs["tokens"][0, 0, :1]
+
+    def loss_and_grad():
+        return jax.jit(jax.value_and_grad(lambda p: _in_mesh(
+            lambda p, t: FT._loss_and_load(p, t, cfg, FT.SEQ_AXIS)[0],
+            cfg, p, row)))(params)
+
+    kept = loss_and_grad()
+    monkeypatch.setattr(FT, "remat_saved", lambda cfg: ())
+    plain = loss_and_grad()
+    assert float(kept[0]).hex() == float(plain[0]).hex()
+    flat, _ = jax.tree_util.tree_flatten_with_path(plain[1])
+    for (path, want), got in zip(flat, jax.tree.leaves(kept[1])):
+        assert np.array_equal(got, want), compare.leaf_name(path)
 
 
 @pytest.mark.parametrize("router_input", ["block", "normed"])
@@ -403,6 +456,7 @@ def test_the_round_says_sparse_and_records_its_tiles(inputs):
     assert attrs["attention_path"] == "sparse"
     assert attrs["attention_tile"] == "8x8"
     assert (attrs["sparse_topk"], attrs["indexer_heads"]) == (8, 2)
+    assert attrs["remat_saved"] == ["keep", "log_norm", "tiles"]
     assert attrs["attention_tiles_visited"] == 2 * 10  # 2 layers, 4 x 4
     recorded = engine.record_sparse_tiles()
     span = [s for s in TRACER.drain() if s["name"] == "sparse.tiles"][-1]

@@ -512,8 +512,11 @@ def _smallthinker_round(inputs):
 def test_the_smallthinker_block_lowers_to_the_pinned_text(
         inputs, plain_block):
     """The configuration that ran the tiled walk before every call did: its
-    `_round` body, at the tiny sizes and the tiles of this file."""
-    text = _body_text(*_smallthinker_round(inputs))
+    `_round` body, at the tiny sizes and the tiles of this file. A block
+    under remat without sparse attention: its checkpoint keeps nothing."""
+    engine, args = _smallthinker_round(inputs)
+    assert engine.cfg.remat and FT.remat_saved(engine.cfg) == ()
+    text = _body_text(engine, args)
     assert hashlib.sha256(text.encode()).hexdigest() == PINNED_SMALLTHINKER
 
 

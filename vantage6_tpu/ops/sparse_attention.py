@@ -29,7 +29,10 @@ scores:
   compiler lays out; the log of the sum of ``exp(I)`` over each ``S_t``
   (the indexer's own softmax, for its loss); and how many of the attention
   walk's visible tiles hold a kept key. Nothing of it is differentiated:
-  top-k is piecewise constant.
+  top-k is piecewise constant. Under remat the layer's checkpoint keeps
+  the three (`fed_transformer.remat_saved`: 64 MiB a layer at 2 x 16k
+  tokens), so the backward pass runs neither the scores nor the search
+  again: one selection a layer and round.
 - `attend`: `recompute_attention`'s XLA walk over the visible tiles with
   ``keep`` masking each tile, forward and backward (its custom VJP); it
   returns the row statistics ``L`` beside the output.
@@ -212,7 +215,10 @@ def select(q_idx, k_idx, w, top_k: int, block_q: int, block_k: int,
     ``log_norm`` [B, T] (the log of the sum of ``exp(I)`` over ``S_t``) and
     the count of (``block_q`` x ``block_k``) tiles of the attention walk
     that hold a kept pair, over the batch. The scores run in the Pallas
-    kernel unless ``interpret`` (`_block_scores`)."""
+    kernel unless ``interpret`` (`_block_scores`). A layer under remat
+    keeps the three across its checkpoint rather than select again in the
+    backward pass (`fed_transformer.remat_saved`; ``keep`` is 64 MiB a
+    layer at two sequences of 16k)."""
     q_idx, k_idx, w = map(lax.stop_gradient, (q_idx, k_idx, w))
     b, t = k_idx.shape[:2]
     block_q, block_k = min(block_q, t), min(block_k, t)

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from vantage6_tpu.core.mesh import STATION_AXIS, _largest_divisor_leq
@@ -41,6 +42,9 @@ from vantage6_tpu.runtime.profiling import device_launch, engine_call
 from vantage6_tpu.runtime.tracing import TRACER
 
 SEQ_AXIS = "device"  # sequence parallelism rides the within-station axis
+# sparse attention's selection (`sparse.select`'s outputs), by the names the
+# layer's checkpoint keeps them under (`remat_saved`)
+SELECTION = ("keep", "log_norm", "tiles")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,7 +78,8 @@ class TransformerConfig:
     # attention already avoids the [T, T] residuals WITHIN a layer; remat
     # drops the per-layer residual stream BETWEEN layers). Exact in math;
     # numerically identical to f32 rounding (XLA may fuse differently
-    # across the checkpoint boundary — measured ~1 ULP on the loss).
+    # across the checkpoint boundary — measured ~1 ULP on the loss). With
+    # sparse attention the checkpoint keeps the selection (`remat_saved`).
     remat: bool = False
     # ---- the block, as data. The defaults are the block this file always
     # ran (parameter-free LayerNorm, a learned position table at the input,
@@ -363,6 +368,17 @@ def _head(params: dict[str, Any], cfg: TransformerConfig) -> jax.Array:
             else params["head"].astype(cfg.dtype))
 
 
+def remat_saved(cfg: TransformerConfig) -> tuple[str, ...]:
+    """The values the layer's checkpoint keeps for the backward pass, by
+    name; it recomputes the rest. Sparse attention's selection (`SELECTION`:
+    a bit a pair, 64 MiB a layer at two stations' 16k tokens): its backward
+    rules need ``keep`` and ``log_norm``, and rebuilt they would cost the
+    indexer's scores over every visible pair and the top-k search again, for
+    the same bits (nothing is differentiated through them). Nothing where
+    there is no checkpoint or no selection."""
+    return SELECTION if cfg.remat and cfg.sparse_top_k else ()
+
+
 def _forward(
     params: dict[str, Any],
     tokens_local: jax.Array,
@@ -486,9 +502,10 @@ def _forward(
         q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
         walk = sparse.blocks(t_local, cfg.head_dim,
                              cfg.n_heads // cfg.n_kv_heads, cfg.dtype)
-        keep, log_norm, tiles = sparse.select(
-            q_idx, k_idx, w, cfg.sparse_top_k, walk.block_q, walk.block_k,
-            cfg.flash_interpret)
+        keep, log_norm, tiles = (
+            checkpoint_name(x, name) for x, name in zip(sparse.select(
+                q_idx, k_idx, w, cfg.sparse_top_k, walk.block_q,
+                walk.block_k, cfg.flash_interpret), SELECTION))
         with jax.named_scope("attention"):
             attn, big_l = sparse.attend(q, k, v, keep, offset, offset,
                                         walk.block_q, walk.block_k)
@@ -523,9 +540,14 @@ def _forward(
     # it, however often the stack is walked. Its name in an operation's
     # path, `jit(layer_block)`, is no scope.
     kind = (2, 3)  # window, rotates
-    block = jax.jit(
-        jax.checkpoint(layer_block, static_argnums=kind) if cfg.remat
-        else layer_block, static_argnums=kind)
+    block = layer_block
+    if cfg.remat:
+        saved = remat_saved(cfg)
+        block = jax.checkpoint(
+            layer_block, static_argnums=kind,
+            policy=jax.checkpoint_policies.save_only_these_names(*saved)
+            if saved else None)
+    block = jax.jit(block, static_argnums=kind)
 
     def stack(x):
         loads, terms = [], []
@@ -783,10 +805,12 @@ class FedTransformer:
         ``engine.call`` span also carries the walk the program was built
         with (`attention_walk`; with sparse attention ``sparse_topk`` and
         ``indexer_heads`` too), always how the cross-station mean is taken
-        (`aggregation`), and where the stack is walked more than once,
-        ``loops`` and ``layer_applications``."""
+        (`aggregation`) and what the layer's checkpoint keeps
+        (``remat_saved``, `remat_saved`'s names), and where the stack is
+        walked more than once, ``loops`` and ``layer_applications``."""
         attrs = {**self.attention_walk(tokens.shape[-1]),
-                 **self.aggregation(params)}
+                 **self.aggregation(params),
+                 "remat_saved": list(remat_saved(self.cfg))}
         if self.cfg.loops > 1:
             attrs = {**attrs, "loops": self.cfg.loops,
                      "layer_applications": self.cfg.loops * self.cfg.n_layers}
