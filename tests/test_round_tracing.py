@@ -251,9 +251,12 @@ def test_every_scope_of_the_tuple_is_opened_by_some_program():
 @pytest.mark.parametrize("program", ["transformer-recompute",
                                      "transformer-looped",
                                      "fedavg-compressed-zero1"])
-def test_scopes_are_metadata_and_nothing_else(program, monkeypatch):
+def test_scopes_are_metadata_and_nothing_else(program, monkeypatch,
+                                             fresh_compiles):
     """The program as XLA gets it (the text without locations, which is
-    what the persistent cache keys on) is the same without the scopes."""
+    what the persistent cache keys on) is the same without the scopes. So
+    the build without them compiles with that cache off: loaded, it would
+    carry the names of the build with them."""
     build, _ = PROGRAMS[program]
     with_scopes = _lowered(build())
     monkeypatch.setattr(jax, "named_scope",
