@@ -207,10 +207,9 @@ def test_each_loss_reaches_only_its_own_leaves(inputs):
                 assert np.any(np.asarray(g)), name
 
 
-def _selections(cfg, inputs) -> int:
-    """The selection's passes in the compiled gradient of the tiny loss
-    (`_program_parts`): the while loops of `_threshold`'s bit search, the
-    one loop under the `select` scope, once a pass."""
+def _loops(cfg, inputs) -> list[str]:
+    """The paths of the while loops in the compiled loss and gradient of
+    the tiny loss (`_program_parts`), the value live as in a round."""
     params, row = inputs["params"], inputs["tokens"][0, 0, :1]
     parts = _program_parts(cfg)
 
@@ -218,9 +217,23 @@ def _selections(cfg, inputs) -> int:
         _, lm, indexer = _in_mesh(parts, cfg, p, row)
         return lm + indexer
 
-    text = jax.jit(jax.grad(loss)).lower(params).compile().as_text()
-    names = re.findall(r' while\(.*op_name="([^"]*)"', text)
-    return sum(name.endswith("/select/while") for name in names)
+    text = jax.jit(jax.value_and_grad(loss)).lower(params).compile().as_text()
+    return re.findall(r' while\(.*op_name="([^"]*)"', text)
+
+
+def _selections(cfg, inputs) -> int:
+    """The selection's passes in the compiled gradient of the tiny loss:
+    the while loops of `_threshold`'s bit search, the one loop under the
+    `select` scope, once a pass."""
+    return sum(name.endswith("/select/while") for name in _loops(cfg, inputs))
+
+
+def _loss_walks(cfg, inputs) -> int:
+    """The indexer loss's walks in the compiled gradient of the tiny loss:
+    the loops over the query blocks, the outer of the two loops under the
+    `indexer_loss` scope, once a walk."""
+    return sum(name.split("indexer_loss")[-1].count("while") == 1
+               for name in _loops(cfg, inputs) if "indexer_loss" in name)
 
 
 @pytest.mark.parametrize("remat", [True, False])
@@ -230,18 +243,34 @@ def test_the_selection_runs_once_a_layer(inputs, monkeypatch, remat):
     search: one selection a layer, as without remat. The plain checkpoint
     keeps nothing, and its backward pass selects again."""
     cfg = _config(remat=remat)
-    assert FT.remat_saved(cfg) == (FT.SELECTION if remat else ())
+    assert FT.remat_saved(cfg) == (
+        FT.SELECTION + FT.INDEXER_GRADS if remat else ())
     assert _selections(cfg, inputs) == cfg.n_layers
     if remat:
         monkeypatch.setattr(FT, "remat_saved", lambda cfg: ())
         assert _selections(cfg, inputs) == 2 * cfg.n_layers
 
 
+@pytest.mark.parametrize("remat", [True, False])
+def test_the_indexer_loss_walks_once_a_layer(inputs, monkeypatch, remat):
+    """The loss's one walk takes its value and its gradient in the forward
+    pass, and the layer's checkpoint keeps the gradient
+    (`FT.INDEXER_GRADS`): one walk a layer, as without remat. A checkpoint
+    that keeps the selection alone walks the loss again in the backward
+    pass."""
+    cfg = _config(remat=remat)
+    assert _loss_walks(cfg, inputs) == cfg.n_layers
+    if remat:
+        monkeypatch.setattr(FT, "remat_saved", lambda cfg: FT.SELECTION)
+        assert _loss_walks(cfg, inputs) == 2 * cfg.n_layers
+
+
 def test_the_kept_selection_gives_the_plain_checkpoints_bits(
         inputs, monkeypatch):
-    """The selection kept across the checkpoint is the one the plain
-    checkpoint rebuilds, bit for bit: the tiny round's loss and every leaf
-    of its gradient are the plain checkpoint's exactly."""
+    """The selection and the indexer loss's gradient kept across the
+    checkpoint, together or either alone, are what the plain checkpoint
+    rebuilds, bit for bit: the tiny round's loss and every leaf of its
+    gradient are the plain checkpoint's exactly."""
     cfg = _config()
     params, row = inputs["params"], inputs["tokens"][0, 0, :1]
 
@@ -250,13 +279,17 @@ def test_the_kept_selection_gives_the_plain_checkpoints_bits(
             lambda p, t: FT._loss_and_load(p, t, cfg, FT.SEQ_AXIS)[0],
             cfg, p, row)))(params)
 
-    kept = loss_and_grad()
-    monkeypatch.setattr(FT, "remat_saved", lambda cfg: ())
-    plain = loss_and_grad()
-    assert float(kept[0]).hex() == float(plain[0]).hex()
+    assert FT.remat_saved(cfg) == FT.SELECTION + FT.INDEXER_GRADS
+    kept = [loss_and_grad()]
+    for names in (FT.SELECTION, FT.INDEXER_GRADS, ()):
+        monkeypatch.setattr(FT, "remat_saved", lambda cfg, names=names: names)
+        kept.append(loss_and_grad())
+    *kept, plain = kept
     flat, _ = jax.tree_util.tree_flatten_with_path(plain[1])
-    for (path, want), got in zip(flat, jax.tree.leaves(kept[1])):
-        assert np.array_equal(got, want), compare.leaf_name(path)
+    for got in kept:
+        assert float(got[0]).hex() == float(plain[0]).hex()
+        for (path, want), leaf in zip(flat, jax.tree.leaves(got[1])):
+            assert np.array_equal(leaf, want), compare.leaf_name(path)
 
 
 @pytest.mark.parametrize("router_input", ["block", "normed"])
@@ -321,6 +354,41 @@ def test_keeping_every_key_is_causal_recompute_attention():
                               argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+
+
+def test_the_indexer_loss_and_its_gradient_are_the_dense_kl():
+    """`indexer_loss`'s one walk, its value and its gradient to ``q_idx``,
+    ``k_idx`` and ``w``: a plain [T, T] KL over the kept pairs, the
+    attention's probabilities and their row statistics taken densely, the
+    indexer's log-softmax over ``S_t`` differentiated through its norm."""
+    (q, k, _), (q_idx, k_idx, w), _ = _op_inputs()
+    keep, log_norm, _ = SA.select(q_idx, k_idx, w, 8, 8, 8, interpret=True)
+    kept = _unpack(keep, T)[:, None]                           # [B, 1, T, T]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, 2, axis=1),
+                   precision="highest") / np.sqrt(q.shape[-1])
+    big_l = jax.nn.logsumexp(jnp.where(kept, s, -jnp.inf), axis=-1)
+    p = jnp.mean(jnp.where(kept, jnp.exp(s - big_l[..., None]), 0.0), 1)
+
+    def dense(q_idx, k_idx, w):
+        scores = SA.indexer_scores(q_idx, k_idx, w)
+        log_q = scores - jax.nn.logsumexp(
+            jnp.where(kept[:, 0], scores, -jnp.inf), axis=-1, keepdims=True)
+        return jnp.sum(jnp.where(kept[:, 0] & (p > 0), p * (jnp.log(
+            jnp.where(p > 0, p, 1.0)) - log_q), 0.0))
+
+    def walked(q_idx, k_idx, w):
+        return SA.indexer_loss(q, k, big_l, keep, log_norm, q_idx, k_idx, w,
+                               8, 8)
+
+    got = jax.jit(jax.value_and_grad(walked, argnums=(0, 1, 2)))(
+        q_idx, k_idx, w)
+    want = jax.value_and_grad(dense, argnums=(0, 1, 2))(q_idx, k_idx, w)
+    assert float(got[0]) > 0
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+    for a, b in zip(got[1], want[1]):
+        assert np.any(np.asarray(b))
+        np.testing.assert_allclose(a, b, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(b))))
 
 
 @pytest.mark.parametrize("top", [1, 5, 8, 31])
@@ -456,7 +524,8 @@ def test_the_round_says_sparse_and_records_its_tiles(inputs):
     assert attrs["attention_path"] == "sparse"
     assert attrs["attention_tile"] == "8x8"
     assert (attrs["sparse_topk"], attrs["indexer_heads"]) == (8, 2)
-    assert attrs["remat_saved"] == ["keep", "log_norm", "tiles"]
+    assert attrs["remat_saved"] == ["keep", "log_norm", "tiles", "d_q_idx",
+                                    "d_k_idx", "d_w"]
     assert attrs["attention_tiles_visited"] == 2 * 10  # 2 layers, 4 x 4
     recorded = engine.record_sparse_tiles()
     span = [s for s in TRACER.drain() if s["name"] == "sparse.tiles"][-1]

@@ -38,11 +38,15 @@ scores:
   returns the row statistics ``L`` beside the output.
 - `indexer_loss`: ``sum_t KL(p_t || softmax_{s in S_t} I[t, s])`` with
   ``p_t`` the main attention's probabilities over ``S_t`` averaged over its
-  heads (rebuilt a tile at a time from q, k and ``L``; its value takes the
-  scores from `indexer_scores` on a TPU), a custom VJP whose
-  gradient reaches ``qI``, ``kI`` and ``w`` alone: ``dI = softmax(I) - p``
-  over ``S_t``. The LM loss reaches the indexer through nothing, and this
-  loss reaches nothing else (the `indexer_loss` scope).
+  heads (rebuilt a tile at a time from q, k and ``L``), whose gradient
+  reaches ``qI``, ``kI`` and ``w`` alone: ``dI = softmax(I) - p`` over
+  ``S_t``. It does not depend on the cotangent but as a scale, so one walk
+  in the forward pass takes the value and the gradient for a unit
+  cotangent together, and the backward pass only scales the gradient (a
+  custom VJP). Under remat the layer's checkpoint keeps that gradient too
+  (`GRAD_NAMES`), so the walk runs once a layer and round. The LM loss
+  reaches the indexer through nothing, and this loss reaches nothing else
+  (the `indexer_loss` scope).
 
 The walks visit every visible tile, whether or not it holds a kept key: under
 the packed stations' `vmap` a skip would be a select that runs both branches.
@@ -55,6 +59,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -317,16 +322,21 @@ def attend(q, k, v, keep, q_offset, k_offset, block_q: int, block_k: int):
 
 
 # ------------------------------------------------------------ indexer_loss
-def _loss_walk(q, k, big_l, keep, log_norm, q_idx, k_idx, w, g, *, scale,
-               block_q, block_k, interpret):
-    """The walk of `indexer_loss`: with ``g`` None its value (the sum over
-    queries of each KL; a query block's scores against every key from
-    `_block_scores`, once), else the gradient of ``g`` times it to
-    ``q_idx``, ``k_idx`` and ``w`` (each tile's per-head products again,
-    which the gradient needs). A tile's ``p`` is ``exp(S_h - L_h)`` over the
-    kept pairs, averaged over the query heads; its ``softmax(I)`` is ``exp(I
-    - log_norm)``. Every block is sliced where it lies: nothing is laid out
-    in blocks beforehand."""
+# the names under which `indexer_loss` hands its gradient to the backward
+# pass (``checkpoint_name``), for ``q_idx``, ``k_idx`` and ``w``: a layer's
+# checkpoint that keeps them does not walk the loss again
+GRAD_NAMES = ("d_q_idx", "d_k_idx", "d_w")
+
+
+def _loss_walk(q, k, big_l, keep, log_norm, q_idx, k_idx, w, *, scale,
+               block_q, block_k):
+    """The one walk of `indexer_loss`: its value (the sum over queries of
+    each KL) and its gradient to ``q_idx``, ``k_idx`` and ``w`` for a unit
+    cotangent, each tile's probabilities and per-head products taken once
+    for both. A tile's ``p`` is ``exp(S_h - L_h)`` over the kept pairs,
+    averaged over the query heads; its ``softmax(I)`` is ``exp(I -
+    log_norm)``, the scores at ``HIGHEST``. Every block is sliced where it
+    lies: nothing is laid out in blocks beforehand."""
     b, h_q, t, d = q.shape
     h_kv = k.shape[1]
     tile_kept = tile_of(keep, block_q, block_k)
@@ -335,58 +345,29 @@ def _loss_walk(q, k, big_l, keep, log_norm, q_idx, k_idx, w, g, *, scale,
     def rows(x, i, axis):
         return lax.dynamic_slice_in_dim(x, i * block_q, block_q, axis)
 
-    def query_block(i):
-        return (rows(q, i, 2).reshape(b, h_kv, h_q // h_kv, block_q, d),
-                rows(big_l, i, 2).reshape(b, h_kv, h_q // h_kv, block_q),
-                rows(log_norm, i, 1), rows(q_idx, i, 1), rows(w, i, 1))
-
-    def probabilities(blk, i, j):
-        """The kept pairs of the tile and their head-averaged ``p``."""
-        q_i, l_i = blk[:2]
-        k_j = lax.dynamic_slice_in_dim(k, j * block_k, block_k, 2)
-        kept = tile_kept(i, j)
-        s = jnp.einsum("bhgqd,bhkd->bhgqk", q_i, k_j,
-                       preferred_element_type=jnp.float32) * scale
-        p = jnp.mean(jnp.exp(s - l_i[..., None]), axis=(1, 2))
-        return kept, jnp.where(kept, p, 0.0)                     # [B, bq, bk]
-
-    def key_range(i):
-        return _key_block_range(i, block_q, block_k, t // block_k, t, 0, 0,
-                                True, None)
-
-    if g is None:
-        def one_query_block(i, total):
-            blk = query_block(i)
-            scores = _block_scores(blk[3], k_idx, blk[4], i * block_q,
-                                   interpret) - blk[2][..., None]
-
-            def step(j, total):
-                kept, p = probabilities(blk, i, j)
-                log_q = lax.dynamic_slice_in_dim(scores, j * block_k,
-                                                 block_k, 2)
-                kl = jnp.where(kept & (p > 0),
-                               p * (jnp.log(jnp.where(p > 0, p, 1.0)) - log_q),
-                               0.0)
-                return total + jnp.sum(kl)
-
-            return lax.fori_loop(*key_range(i), step, total)
-
-        return lax.fori_loop(0, t // block_q, one_query_block,
-                             jnp.float32(0))
-
-    def one_query_block(i, grads):
-        blk = query_block(i)
-        qi_i, w_i = blk[3], blk[4]
+    def one_query_block(i, carry):
+        total, dq_idx, dk_idx, dw = carry
+        q_i = rows(q, i, 2).reshape(b, h_kv, h_q // h_kv, block_q, d)
+        l_i = rows(big_l, i, 2).reshape(b, h_kv, h_q // h_kv, block_q)
+        norm_i, qi_i, w_i = (rows(x, i, 1) for x in (log_norm, q_idx, w))
 
         def step(j, carry):
-            dq_i, dw_i, dk_idx = carry
-            kept, p = probabilities(blk, i, j)
+            total, dq_i, dw_i, dk_idx = carry
+            kept = tile_kept(i, j)
+            k_j = lax.dynamic_slice_in_dim(k, j * block_k, block_k, 2)
+            s = jnp.einsum("bhgqd,bhkd->bhgqk", q_i, k_j,
+                           preferred_element_type=jnp.float32) * scale
+            p = jnp.where(kept, jnp.mean(jnp.exp(s - l_i[..., None]),
+                                         axis=(1, 2)), 0.0)  # [B, bq, bk]
             ki_j = lax.dynamic_slice_in_dim(k_idx, j * block_k, block_k, 1)
             a = jnp.einsum("bqjd,bsd->bqjs", qi_i, ki_j,
                            precision=HIGHEST) * inv
             log_q = jnp.einsum("bqjs,bqj->bqs", jax.nn.relu(a), w_i,
-                               precision=HIGHEST) - blk[2][..., None]
-            d_scores = g * jnp.where(kept, jnp.exp(log_q) - p, 0.0)
+                               precision=HIGHEST) - norm_i[..., None]
+            kl = jnp.where(kept & (p > 0),
+                           p * (jnp.log(jnp.where(p > 0, p, 1.0)) - log_q),
+                           0.0)
+            d_scores = jnp.where(kept, jnp.exp(log_q) - p, 0.0)
             dw_i = dw_i + jnp.einsum("bqs,bqjs->bqj", d_scores,
                                      jax.nn.relu(a), precision=HIGHEST)
             da = jnp.where(a > 0, d_scores[:, :, None] * w_i[..., None],
@@ -394,52 +375,56 @@ def _loss_walk(q, k, big_l, keep, log_norm, q_idx, k_idx, w, g, *, scale,
             dq_i = dq_i + jnp.einsum("bqjs,bsd->bqjd", da, ki_j,
                                      precision=HIGHEST)
             dk_j = jnp.einsum("bqjs,bqjd->bsd", da, qi_i, precision=HIGHEST)
-            return dq_i, dw_i, _add_at(1)(dk_idx, dk_j, j * block_k)
+            return (total + jnp.sum(kl), dq_i, dw_i,
+                    _add_at(1)(dk_idx, dk_j, j * block_k))
 
-        dq_idx, dk_idx, dw = grads
-        dq_i, dw_i, dk_idx = lax.fori_loop(*key_range(i), step, (
-            jnp.zeros_like(qi_i), jnp.zeros_like(w_i), dk_idx))
-        return (_add_at(1)(dq_idx, dq_i, i * block_q), dk_idx,
+        total, dq_i, dw_i, dk_idx = lax.fori_loop(
+            *_key_block_range(i, block_q, block_k, t // block_k, t, 0, 0,
+                              True, None),
+            step, (total, jnp.zeros_like(qi_i), jnp.zeros_like(w_i), dk_idx))
+        return (total, _add_at(1)(dq_idx, dq_i, i * block_q), dk_idx,
                 _add_at(1)(dw, dw_i, i * block_q))
 
-    return lax.fori_loop(0, t // block_q, one_query_block, (
-        jnp.zeros_like(q_idx), jnp.zeros_like(k_idx), jnp.zeros_like(w)))
+    total, *grads = lax.fori_loop(0, t // block_q, one_query_block, (
+        jnp.float32(0), jnp.zeros_like(q_idx), jnp.zeros_like(k_idx),
+        jnp.zeros_like(w)))
+    return total, tuple(grads)
 
 
-@functools.lru_cache(maxsize=None)
-def _indexer_loss_vjp(scale, block_q, block_k, interpret):
-    kw = dict(scale=scale, block_q=block_q, block_k=block_k,
-              interpret=interpret)
+@jax.custom_vjp
+def _with_grads(value, d_q_idx, d_k_idx, d_w, q_idx, k_idx, w):
+    """``value``, whose gradient to ``q_idx``, ``k_idx`` and ``w`` is its
+    cotangent times ``d_q_idx``, ``d_k_idx`` and ``d_w``."""
+    return value
 
-    @jax.custom_vjp
-    def loss(q, k, big_l, keep, log_norm, q_idx, k_idx, w):
-        with jax.named_scope("indexer_loss"):
-            return _loss_walk(q, k, big_l, keep, log_norm, q_idx, k_idx, w,
-                              None, **kw)
 
-    def fwd(*args):
-        return loss(*args), args
+def _with_grads_fwd(value, d_q_idx, d_k_idx, d_w, q_idx, k_idx, w):
+    return value, (d_q_idx, d_k_idx, d_w)
 
-    def bwd(args, g):
-        with jax.named_scope("indexer_loss"):
-            grads = _loss_walk(*args, g, **kw)
-        return (None,) * 5 + grads
 
-    loss.defvjp(fwd, bwd)
-    return loss
+def _with_grads_bwd(grads, g):
+    with jax.named_scope("indexer_loss"):
+        return (None,) * 4 + tuple(g * x for x in grads)
+
+
+_with_grads.defvjp(_with_grads_fwd, _with_grads_bwd)
 
 
 def indexer_loss(q, k, big_l, keep, log_norm, q_idx, k_idx, w,
-                 block_q: int, block_k: int, interpret: bool = False):
+                 block_q: int, block_k: int):
     """``sum_t KL(p_t || softmax_{s in S_t} I[t, s])`` over the batch's
     queries (positions from 0): ``p_t`` the attention's probabilities over
     ``S_t`` (``q`` [B, Hq, T, D], ``k`` [B, Hkv, T, D] and ``big_l`` from
     `attend`), averaged over the query heads; the indexer's scores from
     ``q_idx``, ``k_idx``, ``w`` as in `select`, which gave ``keep`` and
-    ``log_norm``. Differentiable in ``q_idx``, ``k_idx`` and ``w`` alone.
-    ``t`` is a whole number of blocks (`select` saw to that); the scores of
-    the value run in the Pallas kernel unless ``interpret``."""
-    fn = _indexer_loss_vjp(1.0 / math.sqrt(q.shape[-1]), block_q, block_k,
-                           interpret)
-    q, k, big_l, log_norm = map(lax.stop_gradient, (q, k, big_l, log_norm))
-    return fn(q, k, big_l, keep, log_norm, q_idx, k_idx, w)
+    ``log_norm``. Differentiable in ``q_idx``, ``k_idx`` and ``w`` alone:
+    ``dI = softmax(I) - p`` over ``S_t``. One walk (`_loss_walk`) gives the
+    value and the gradient for a unit cotangent, under `GRAD_NAMES`; the
+    backward pass scales the gradient by the cotangent. ``t`` is a whole
+    number of blocks (`select` saw to that)."""
+    args = map(lax.stop_gradient, (q, k, big_l, keep, log_norm, q_idx,
+                                   k_idx, w))
+    value, grads = _loss_walk(*args, scale=1.0 / math.sqrt(q.shape[-1]),
+                              block_q=block_q, block_k=block_k)
+    grads = (checkpoint_name(x, name) for x, name in zip(grads, GRAD_NAMES))
+    return _with_grads(value, *grads, q_idx, k_idx, w)

@@ -42,9 +42,11 @@ from vantage6_tpu.runtime.profiling import device_launch, engine_call
 from vantage6_tpu.runtime.tracing import TRACER
 
 SEQ_AXIS = "device"  # sequence parallelism rides the within-station axis
-# sparse attention's selection (`sparse.select`'s outputs), by the names the
-# layer's checkpoint keeps them under (`remat_saved`)
+# sparse attention's selection (`sparse.select`'s outputs) and the indexer
+# loss's gradient (`sparse.indexer_loss`'s), by the names the layer's
+# checkpoint keeps them under (`remat_saved`)
 SELECTION = ("keep", "log_norm", "tiles")
+INDEXER_GRADS = sparse.GRAD_NAMES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,7 +81,8 @@ class TransformerConfig:
     # drops the per-layer residual stream BETWEEN layers). Exact in math;
     # numerically identical to f32 rounding (XLA may fuse differently
     # across the checkpoint boundary — measured ~1 ULP on the loss). With
-    # sparse attention the checkpoint keeps the selection (`remat_saved`).
+    # sparse attention the checkpoint keeps the selection and the indexer
+    # loss's gradient (`remat_saved`).
     remat: bool = False
     # ---- the block, as data. The defaults are the block this file always
     # ran (parameter-free LayerNorm, a learned position table at the input,
@@ -371,12 +374,17 @@ def _head(params: dict[str, Any], cfg: TransformerConfig) -> jax.Array:
 def remat_saved(cfg: TransformerConfig) -> tuple[str, ...]:
     """The values the layer's checkpoint keeps for the backward pass, by
     name; it recomputes the rest. Sparse attention's selection (`SELECTION`:
-    a bit a pair, 64 MiB a layer at two stations' 16k tokens): its backward
-    rules need ``keep`` and ``log_norm``, and rebuilt they would cost the
+    a bit a pair, 64 MiB a layer at two stations' 16k tokens): the
+    attention's backward rule needs ``keep``, and rebuilt it would cost the
     indexer's scores over every visible pair and the top-k search again, for
-    the same bits (nothing is differentiated through them). Nothing where
-    there is no checkpoint or no selection."""
-    return SELECTION if cfg.remat and cfg.sparse_top_k else ()
+    the same bits (nothing is differentiated through them). The indexer
+    loss's gradient (`INDEXER_GRADS`, float32 the size of the indexer's
+    projections, 145 MB a layer at 2 x 16k): its one walk takes it beside
+    the value in the forward pass, and rebuilt it would cost that walk
+    again. Nothing where there is no checkpoint or no selection."""
+    if cfg.remat and cfg.sparse_top_k:
+        return SELECTION + INDEXER_GRADS
+    return ()
 
 
 def _forward(
@@ -511,8 +519,7 @@ def _forward(
                                         walk.block_q, walk.block_k)
         with jax.named_scope("indexer_loss"):
             loss = sparse.indexer_loss(q, k, big_l, keep, log_norm, q_idx,
-                                       k_idx, w, walk.block_q, walk.block_k,
-                                       cfg.flash_interpret)
+                                       k_idx, w, walk.block_q, walk.block_k)
         return attn.transpose(0, 2, 1, 3), (loss, tiles)
 
     def expert_half(x, layer, routing):
